@@ -108,7 +108,7 @@ def calibrate_cell(arch, shape_name):
     from repro.configs.shapes import SHAPES
     from repro.distributed.sharding import sharding_scope
     from repro.launch import dryrun as dr
-    from repro.launch.mesh import make_production_mesh, use_mesh
+    from repro.launch.mesh import make_production_mesh
 
     cfg0 = get_config(arch)
     depths = _variant_depths(cfg0)
@@ -128,7 +128,7 @@ def calibrate_cell(arch, shape_name):
             attn_chunk_q=S, attn_chunk_kv=S, loss_chunk=S,
         )
         ov = dr.cell_overrides(arch, shape_name)
-        with use_mesh(mesh), sharding_scope(mesh, **ov):
+        with jax.set_mesh(mesh), sharding_scope(mesh, **ov):
             # patch the registry-free path: build_cell reads get_config, so
             # construct the cell manually with the variant cfg
             fn, avals, in_sh, donate = _build_variant(cfg, shape_name)
@@ -138,8 +138,6 @@ def calibrate_cell(arch, shape_name):
                 .compile()
             )
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):  # older jax: one dict per program
-                ca = ca[0] if ca else {}
             coll, _ = dr.parse_collective_bytes(compiled.as_text())
         out[d] = {
             "flops": float(ca.get("flops", 0.0)),

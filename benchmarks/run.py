@@ -6,17 +6,15 @@ human-readable tables.
   PYTHONPATH=src python -m benchmarks.run           # full paper suite
   PYTHONPATH=src python -m benchmarks.run --quick   # CI-speed subset
 
-The roofline analysis (§Roofline) runs in a subprocess because it forces a
-512-device host platform; results land in results/roofline.{json,md}. If a
-cached results/roofline.json exists it is summarised instead of re-run.
+The roofline analysis (§Roofline) forces a 512-device host platform, so it
+runs on its own (``python -m benchmarks.roofline``) and never from here;
+this orchestrator only summarises a cached results/roofline.json.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
-import subprocess
-import sys
 
 from . import (
     core_distribution,
@@ -29,13 +27,7 @@ from . import (
 from .common import csv_line
 
 
-def roofline_lines(path="results/roofline.json", run_if_missing=False):
-    if not os.path.exists(path) and run_if_missing:
-        subprocess.run(
-            [sys.executable, "-m", "benchmarks.roofline"],
-            env={**os.environ, "PYTHONPATH": "src"},
-            check=False,
-        )
+def roofline_lines(path="results/roofline.json"):
     if not os.path.exists(path):
         return [csv_line("roofline", 0.0, "missing:run benchmarks.roofline")]
     with open(path) as f:

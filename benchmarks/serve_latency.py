@@ -51,12 +51,14 @@ import time
 import numpy as np
 
 from repro.graph import generators
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve_embed import build_service
 from repro.obs import device_profile, load_schema, record_memory, validate_or_raise
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs
 from repro.obs.history import SCHEMA_VERSION, append_record
 from repro.serve import ServiceStats
+from repro.serve.service import top_k_reference
 
 
 from .common import csv_line
@@ -183,10 +185,6 @@ def _topk_run(svc, *, seed: int, requests: int, batch: int, k: int = 10):
     with ``oracle_mismatches == 0`` — the blockwise kernel is exact, not
     approximate. Returns the JSON ``topk`` section.
     """
-    import jax.numpy as jnp
-
-    from repro.kernels import ops as kops
-
     rng = np.random.default_rng(seed + 5)
     n_now = svc.graph.n_nodes
     for _ in range(2):  # untimed warmup (top-k program compile)
@@ -205,26 +203,11 @@ def _topk_run(svc, *, seed: int, requests: int, batch: int, k: int = 10):
     st = svc.store
     q = rng.integers(0, n_now, size=batch)
     ids, scores = svc.top_k_neighbors(q, k)
-    tab = np.asarray(st.table())[: st.capacity]
-    valid = np.asarray(st.row_valid())[: st.capacity]
-    tn = np.asarray(kops.normalize_rows(jnp.asarray(tab)))
-    qn = np.asarray(kops.normalize_rows(jnp.asarray(svc.embed(q))))
-    sim = qn @ tn.T
-    sim[:, ~valid] = -np.inf
-    own = st.slots_of(np.asarray(q, np.int64))
-    mismatches = 0
-    hits = 0
-    total = 0
+    want = top_k_reference(st, q, svc.embed(q), k)
+    mismatches = int((ids != want).sum())
+    hits = total = 0
     for i in range(len(q)):
-        s = sim[i].copy()
-        if own[i] < st.capacity:
-            s[own[i]] = -np.inf
-        order = np.lexsort((np.arange(len(s)), -s))[:k]
-        live = s[order] > -np.inf
-        want = np.full(k, -1, np.int64)
-        want[: int(live.sum())] = st.node_of_slots(order[live])
-        mismatches += int((ids[i] != want).sum())
-        live_ids = want[want >= 0]
+        live_ids = want[i][want[i] >= 0]
         total += len(live_ids)
         hits += len(np.intersect1d(ids[i][ids[i] >= 0], live_ids))
     return {
@@ -298,7 +281,7 @@ def _retrain_run(g, *, seed: int, quick: bool, batch: int = 64):
         walk_length=16 if quick else 24,
         min_sgns_steps=200 if quick else 400,
         sgns=SGNSConfig(dim=svc.store.dim, epochs=0.25 if quick else 0.5,
-                        impl="ref", seed=seed),
+                        seed=seed),
         prop_iters=8,
         swap_chunk=128,  # more chunks -> more interleaved flush samples
         seed=seed,
@@ -469,8 +452,7 @@ def _attach_retrainer(seed: int):
 
         cfg = RetrainConfig(
             n_walks=6, walk_length=12, min_sgns_steps=60,
-            sgns=SGNSConfig(dim=svc.store.dim, epochs=0.1, impl="ref",
-                            seed=seed),
+            sgns=SGNSConfig(dim=svc.store.dim, epochs=0.1, seed=seed),
             prop_iters=6, swap_chunk=256, seed=seed,
         )
         svc.retrain_threshold = 0.02
@@ -1182,6 +1164,7 @@ def main(argv=None):
                     help="do not append this run to the history series")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     for line in run(quick=not args.full, seed=args.seed, shards=args.shards,
                     retrain=args.retrain, trace=args.trace,
                     metrics_out=args.metrics_out,

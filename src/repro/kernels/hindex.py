@@ -17,7 +17,11 @@ implementations share the search:
   bodies); the non-TPU execution path of ``ops.h_index_sweep`` and the
   operator inside the fused incremental-repair fixpoint.
 * ``h_index_pallas`` — the Pallas kernel (same ref / ``pallas_interpret`` /
-  tpu triple as ``ellmean``/``sgns``), gridded over row blocks.
+  tpu triple as ``ellmean``/``sgns``), gridded over row blocks. It takes the
+  rows **transposed**, ``(W, R)``: candidate rows sit on the 128 lanes, so
+  ``est``/``out`` are lane-dense ``(1, R)`` rows and each count is a
+  reduction over sublanes (elementwise adds across vregs). Every block and
+  value is 2-D, as the TPU's tiled layouts require.
 
 Invalid lanes are encoded as ``-1`` (strictly below every threshold the
 search probes), so padding the width costs nothing.
@@ -30,24 +34,30 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_R = 128
+# lanes (candidate rows) per grid step, and the VMEM bound on one vals block
+MAX_BLOCK_R = 1024
+BLOCK_BYTES = 2 << 20
 
 
-def _bisect_h(vals: jnp.ndarray, est: jnp.ndarray, n_iters: int) -> jnp.ndarray:
+def _bisect_h(vals: jnp.ndarray, est: jnp.ndarray, n_iters: int,
+              axis: int) -> jnp.ndarray:
     """Shared branchless search: max h <= est with count(vals >= h) >= h.
 
-    ``vals``: (R, W) int32 with invalid lanes already set to -1; ``est``:
-    (R,) int32 non-negative upper bound. The invariant is pred(lo) true /
-    answer in [lo, hi]; pred(0) holds trivially, and the range halves every
-    step, so ``n_iters = W.bit_length()`` pins the answer exactly.
+    ``vals``: int32 with invalid lanes already set to -1, its neighbour
+    entries along ``axis``; ``est``: the non-negative upper bound, shaped
+    like ``vals`` reduced along ``axis`` with keepdims. The invariant is
+    pred(lo) true / answer in [lo, hi]; pred(0) holds trivially, and the
+    range halves every step, so ``n_iters = W.bit_length()`` pins the
+    answer exactly.
     """
     lo = jnp.zeros_like(est)
-    hi = jnp.minimum(est, vals.shape[-1])
+    hi = jnp.minimum(est, vals.shape[axis])
 
     def body(_, lohi):
         lo, hi = lohi
-        mid = (lo + hi + 1) // 2
-        cnt = jnp.sum((vals >= mid[:, None]).astype(jnp.int32), axis=-1)
+        mid = jnp.right_shift(lo + hi + 1, 1)  # both halves are >= 0
+        cnt = jnp.sum((vals >= mid).astype(jnp.int32), axis=axis,
+                      keepdims=True)
         ok = cnt >= mid
         return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)
 
@@ -62,37 +72,48 @@ def h_index_count(values: jnp.ndarray, valid: jnp.ndarray,
     values: (R, W) int; valid: (R, W) bool; est: (R,) int. Returns (R,) int32.
     """
     vals = jnp.where(valid, values.astype(jnp.int32), -1)
-    est = jnp.maximum(est.astype(jnp.int32), 0)
+    est = jnp.maximum(est.astype(jnp.int32), 0)[:, None]
     n_iters = max(1, int(values.shape[-1]).bit_length())
-    return _bisect_h(vals, est, n_iters)
+    return _bisect_h(vals, est, n_iters, axis=-1)[:, 0]
+
+
+def block_rows(R: int, W: int) -> int:
+    """Rows per grid step for a ``(W, R)`` transposed block.
+
+    A multiple of 128 lanes (or all of ``R`` when it is smaller) and at most
+    :data:`MAX_BLOCK_R`, shrunk so one ``(W, rb)`` int32 block stays within
+    :data:`BLOCK_BYTES` of VMEM.
+    """
+    if R <= 128:
+        return R
+    fit = BLOCK_BYTES // (4 * max(W, 1)) // 128 * 128
+    return max(128, min(MAX_BLOCK_R, fit, -(-R // 128) * 128))
 
 
 def _hindex_kernel(n_iters, vals_ref, est_ref, out_ref):
-    vals = vals_ref[...]  # (RB, W) int32, invalid lanes = -1
-    est = est_ref[...]  # (RB,) int32
-    out_ref[...] = _bisect_h(vals, est, n_iters)
+    # vals (W, RB) int32, invalid lanes = -1; est / out (1, RB) int32
+    out_ref[...] = _bisect_h(vals_ref[...], est_ref[...], n_iters, axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def h_index_pallas(vals, est, *, block_r: int = DEFAULT_BLOCK_R,
-                   interpret: bool = False):
-    """Blocked h-index search: out[i] = max h <= est[i] with cnt(row >= h) >= h.
+def h_index_pallas(vals_t, est, *, block_r: int, interpret: bool = False):
+    """Blocked h-index search: out[i] = max h <= est[i] with cnt >= h.
 
-    vals: (R, W) int32, invalid lanes = -1, W ideally a lane multiple;
-    est: (R,) int32 non-negative. R must divide into ``block_r`` blocks.
+    vals_t: (W, R) int32, row i's neighbour estimates in column i, invalid
+    entries -1, W a multiple of 8; est: (1, R) int32 non-negative. R must
+    divide into ``block_r`` blocks. Returns (1, R) int32.
     """
-    R, W = vals.shape
-    rb = min(block_r, R)
-    assert R % rb == 0, f"rows {R} not divisible by block {rb}"
+    W, R = vals_t.shape
+    assert R % block_r == 0, f"rows {R} not divisible by block {block_r}"
     n_iters = max(1, int(W).bit_length())
     return pl.pallas_call(
         functools.partial(_hindex_kernel, n_iters),
-        grid=(R // rb,),
+        grid=(R // block_r,),
         in_specs=[
-            pl.BlockSpec((rb, W), lambda i: (i, 0)),
-            pl.BlockSpec((rb,), lambda i: (i,)),
+            pl.BlockSpec((W, block_r), lambda i: (0, i)),
+            pl.BlockSpec((1, block_r), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((rb,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((R,), jnp.int32),
+        out_specs=pl.BlockSpec((1, block_r), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, R), jnp.int32),
         interpret=interpret,
-    )(vals, est)
+    )(vals_t, est)

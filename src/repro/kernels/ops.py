@@ -147,17 +147,17 @@ def h_index_sweep(values, valid, est, *, impl: str = "auto"):
         return _hx.h_index_count(values, valid, est)
     interpret = impl == "pallas_interpret"
     R, W = values.shape
-    vals = jnp.where(valid, values.astype(jnp.int32), -1)
-    if W % 128:  # pad lanes with -1 (never counted by any probed threshold)
-        vals = jnp.pad(vals, ((0, 0), (0, 128 - W % 128)), constant_values=-1)
-    rb = min(_hx.DEFAULT_BLOCK_R, 1 << max(R - 1, 0).bit_length())
+    # the kernel takes rows on lanes: (W, R), W padded to a sublane multiple
+    # and R to the row block, both with -1 (never counted by any threshold)
+    vals_t = jnp.where(valid, values.astype(jnp.int32), -1).T
+    rb = _hx.block_rows(R, W)
     r_pad = -(-R // rb) * rb
-    if r_pad != R:
-        vals = jnp.pad(vals, ((0, r_pad - R), (0, 0)), constant_values=-1)
-    est_p = jnp.maximum(est.astype(jnp.int32), 0)
-    if r_pad != R:
-        est_p = jnp.pad(est_p, (0, r_pad - R))
-    return _hx.h_index_pallas(vals, est_p, block_r=rb, interpret=interpret)[:R]
+    vals_t = jnp.pad(vals_t, ((0, -W % 8), (0, r_pad - R)), constant_values=-1)
+    est_p = jnp.pad(jnp.maximum(est.astype(jnp.int32), 0), (0, r_pad - R))
+    out = _hx.h_index_pallas(
+        vals_t, est_p[None, :], block_r=rb, interpret=interpret
+    )
+    return out[0, :R]
 
 
 # ----------------------------------------------------------------- top-k ----

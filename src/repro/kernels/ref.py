@@ -91,7 +91,7 @@ def h_index_ref(values: jnp.ndarray, valid: jnp.ndarray,
 
 
 def topk_ref(q: jnp.ndarray, table: jnp.ndarray, k: int,
-             valid: jnp.ndarray = None) -> tuple:
+             valid: jnp.ndarray = None, block_n: int = 512) -> tuple:
     """Dense top-k by dot-product score — the semantics of record.
 
     q: (Q, D); table: (N, D); valid: optional (N,) bool row mask. Returns
@@ -102,10 +102,20 @@ def topk_ref(q: jnp.ndarray, table: jnp.ndarray, k: int,
 
     Materialises the full (Q, N) score matrix; the Pallas kernel
     (``kernels.topk``) streams it blockwise and must match this exactly.
+    Like the kernel, it scores ``block_n``-row tiles that all have one
+    shape, so a row's score depends on its values alone and never on where
+    the table is cut (its offset, a shard boundary).
     """
-    scores = jnp.einsum(
-        "qd,nd->qn", q.astype(jnp.float32), table.astype(jnp.float32)
-    )
+    q = q.astype(jnp.float32)
+    N, D = table.shape
+    n_blk = -(-N // block_n)
+    tiles = jnp.pad(table.astype(jnp.float32), ((0, n_blk * block_n - N),
+                                                (0, 0)))
+    scores = jax.lax.map(
+        lambda t: jnp.dot(q, t.T, precision=jax.lax.Precision.HIGHEST),
+        tiles.reshape(n_blk, block_n, D),
+    )  # (n_blk, Q, block_n)
+    scores = jnp.moveaxis(scores, 0, 1).reshape(q.shape[0], -1)[:, :N]
     if valid is not None:
         scores = jnp.where(valid[None, :], scores, -jnp.inf)
     Q, N = scores.shape

@@ -17,7 +17,7 @@ cache axis):
    an add, not a gather;
 2. k rounds of extract-max / replace-worst tournament against the running
    accumulator. Each round pulls the block's best remaining candidate
-   (``argmax`` takes the *first* hit, so ties break toward the lower index)
+   (the lowest column at the max, so ties break toward the lower index)
    and replaces the accumulator's worst entry when the candidate wins under
    the total order (score desc, index asc). A candidate that loses implies
    every remaining one loses too, so correctness needs no early exit.
@@ -62,18 +62,21 @@ def _topk_kernel(q_ref, t_ref, b_ref, ov_ref, oi_ref, vals_ref, idx_ref,
     q = q_ref[...]  # (Q, D)
     t = t_ref[...]  # (BN, D)
     scores = jax.lax.dot_general(
-        q, t, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, t, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     ) + b_ref[...]  # (Q, BN); bias = -inf on dead/padding rows
     col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
 
     for _ in range(k):
-        # block's best remaining candidate; first-hit argmax -> on ties the
-        # lower (local, hence global: blocks stream in ascending row order)
-        # index wins, matching the (score desc, index asc) total order
+        # block's best remaining candidate: the lowest column at the max, a
+        # min over an int iota (Mosaic lowers no argmax over bool/int). On
+        # ties the lower local, hence global, index wins (blocks stream in
+        # ascending row order), matching the (score desc, index asc) order
         c_val = jnp.max(scores, axis=1, keepdims=True)  # (Q, 1)
-        c_arg = jnp.argmax(scores, axis=1)  # (Q,)
-        c_idx = (s * block_n + c_arg).astype(jnp.int32)[:, None]  # (Q, 1)
-        scores = jnp.where(col == c_arg[:, None], NEG_INF, scores)
+        c_arg = jnp.min(jnp.where(scores == c_val, col, block_n), axis=1,
+                        keepdims=True)  # (Q, 1)
+        c_idx = s * block_n + c_arg
+        scores = jnp.where(col == c_arg, NEG_INF, scores)
 
         # accumulator's worst entry: min value, ties -> largest index
         vals = vals_ref[...]
@@ -81,11 +84,12 @@ def _topk_kernel(q_ref, t_ref, b_ref, ov_ref, oi_ref, vals_ref, idx_ref,
         w_val = jnp.min(vals, axis=1, keepdims=True)  # (Q, 1)
         at_w = vals == w_val
         w_idx = jnp.max(jnp.where(at_w, idx, -1), axis=1, keepdims=True)
-        w_pos = jnp.argmax(at_w & (idx == w_idx), axis=1)  # (Q,)
+        w_pos = jnp.min(jnp.where(at_w & (idx == w_idx), lane, Kp), axis=1,
+                        keepdims=True)  # (Q, 1)
 
         better = (c_val > w_val) | ((c_val == w_val) & (c_idx < w_idx))
         better = better & (c_val > NEG_INF)  # masked lanes never enter
-        write = better & (lane == w_pos[:, None])
+        write = better & (lane == w_pos)
         vals_ref[...] = jnp.where(write, c_val, vals)
         idx_ref[...] = jnp.where(write, c_idx, idx)
 

@@ -37,7 +37,7 @@ from repro.configs.shapes import (
     shape_supported,
 )
 from repro.distributed.sharding import sharding_scope, tree_shardings
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models.steps import make_decode_step, make_prefill_step, make_train_step
 from repro.models.transformer import cache_specs, init_model, model_specs
 from repro.train import optim
@@ -207,15 +207,13 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out):
     try:
         mesh = make_production_mesh(multi_pod=multi_pod)
         overrides = cell_overrides(arch, shape_name)
-        with use_mesh(mesh), sharding_scope(mesh, **overrides):
+        with jax.set_mesh(mesh), sharding_scope(mesh, **overrides):
             fn, avals, in_sh, donate = build_cell(arch, shape_name, mesh)
             jitted = jax.jit(fn, in_shardings=in_sh, donate_argnums=donate)
             lowered = jitted.lower(*avals)
             compiled = lowered.compile()
             ma = compiled.memory_analysis()
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):  # older jax: one dict per program
-                ca = ca[0] if ca else {}
             hlo = compiled.as_text()
         coll_bytes, coll_counts = parse_collective_bytes(hlo)
         rec.update(

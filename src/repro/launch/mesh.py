@@ -4,45 +4,24 @@ Per-pod mesh is 16x16 = 256 chips (v5e pod), axes (data, model); the
 multi-pod mesh prepends a pure-DP "pod" axis: (2, 16, 16) = 512 chips.
 A FUNCTION, not a module constant — importing this module must never touch
 jax device state (smoke tests see 1 CPU device; only dryrun.py forces 512).
-
-``jax.sharding.AxisType`` only exists on newer jax; on older installs
-``jax.make_mesh`` simply takes no ``axis_types`` and every axis is the
-implicit default, so the kwarg is version-gated rather than required.
+Every axis is ``AxisType.Auto``; enter a mesh with ``jax.set_mesh(mesh)``.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_mesh", "use_mesh"]
-
-
-def _axis_kwargs(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:  # older jax: no AxisType, no axis_types kwarg
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+__all__ = ["make_production_mesh", "make_mesh"]
 
 
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` for jit/sharding, version-gated.
-
-    Newer jax spells this ``jax.set_mesh(mesh)``; on older installs the
-    ``Mesh`` object itself is the (legacy global-context) context manager.
-    Every launcher/benchmark/test should enter meshes through this helper
-    rather than naming either API directly.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+def make_mesh(shape, axes):
+    """Arbitrary mesh for elastic restarts / tests (e.g. (2, 4) on 8 CPUs)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh for elastic restarts / tests (e.g. (2, 4) on 8 CPUs)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_kwargs(len(axes)))
+    return make_mesh(shape, axes)

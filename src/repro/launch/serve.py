@@ -18,7 +18,7 @@ import numpy as np
 
 from repro.configs import get_config, sharding_overrides
 from repro.distributed.sharding import sharding_scope
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.steps import make_decode_step, make_prefill_step
 from repro.models.transformer import init_model
 
@@ -70,7 +70,7 @@ def main(argv=None):
         for _ in range(args.requests)
     ]
 
-    with use_mesh(mesh), sharding_scope(mesh, **sharding_overrides(cfg.name)):
+    with jax.set_mesh(mesh), sharding_scope(mesh, **sharding_overrides(cfg.name)):
         params = init_model(jax.random.PRNGKey(args.seed), cfg)
         prefill = jax.jit(make_prefill_step(cfg, max_len=max_len))
         decode = jax.jit(make_decode_step(cfg), donate_argnums=(1,))

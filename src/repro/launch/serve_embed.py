@@ -28,6 +28,7 @@ import numpy as np
 from repro.core.kcore import core_numbers_host, degeneracy
 from repro.core.propagation import propagate
 from repro.graph import datasets, generators
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import device_profile, metrics, record_memory
 from repro.obs import trace as obs
 from repro.serve import (
@@ -119,7 +120,7 @@ def build_service(
             EmbedConfig(
                 method="corewalk",
                 k0=k0,
-                sgns=SGNSConfig(dim=dim, impl="ref", seed=seed),
+                sgns=SGNSConfig(dim=dim, seed=seed),
                 prop_iters=prop_iters,
                 seed=seed,
             ),
@@ -159,7 +160,7 @@ def build_service(
         cfg = RetrainConfig(
             n_walks=8,
             walk_length=16,
-            sgns=SGNSConfig(dim=dim, epochs=0.25, impl="ref", seed=seed),
+            sgns=SGNSConfig(dim=dim, epochs=0.25, seed=seed),
             prop_iters=prop_iters,
             seed=seed,
         )
@@ -251,6 +252,7 @@ def main(argv=None):
                          + ", ".join(faults.POINTS))
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace:
         obs.enable()
@@ -300,8 +302,7 @@ def main(argv=None):
 
             cfg = RetrainConfig(
                 n_walks=8, walk_length=16,
-                sgns=SGNSConfig(dim=args.dim, epochs=0.25, impl="ref",
-                                seed=args.seed),
+                sgns=SGNSConfig(dim=args.dim, epochs=0.25, seed=args.seed),
                 seed=args.seed,
             )
             s.set_retrainer(Retrainer(s, cfg), auto=True,
@@ -312,7 +313,7 @@ def main(argv=None):
     t0 = time.perf_counter()
     crashed = False
     try:
-        with device_profile(args.jax_profile) as prof:
+        with device_profile(args.jax_profile):
             n_in, n_out = svc.stream_with_churn(
                 stream_edges,
                 block_size=args.block_size,
@@ -341,8 +342,7 @@ def main(argv=None):
         n_out = svc.stats.edges_removed
     t_ingest = time.perf_counter() - t0
     if args.jax_profile and not crashed:
-        print(f"[serve-embed] jax profile: "
-              f"{'captured to ' + prof['logdir'] if prof['active'] else 'unavailable (' + str(prof.get('error')) + ')'}")
+        print(f"[serve-embed] jax profile: captured to {args.jax_profile}")
     mismatches = svc.cores.resync()  # oracle check (exactness expected)
     eps = (n_in + n_out) / max(t_ingest, 1e-9)
     print(f"[serve-embed] ingested {n_in} edges (+{n_out} retracted) in "

@@ -30,7 +30,7 @@ from repro.distributed.checkpoint import CheckpointManager
 from repro.distributed.compression import ErrorFeedbackInt8
 from repro.distributed.sharding import sharding_scope, tree_shardings
 from repro.distributed.watchdog import HangWatchdog, StragglerMonitor
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.steps import make_train_step
 from repro.models.transformer import init_model, model_specs
 from repro.train import optim
@@ -90,7 +90,7 @@ def main(argv=None):
     stop = {"now": False}
     signal.signal(signal.SIGTERM, lambda *_: stop.update(now=True))
 
-    with use_mesh(mesh), sharding_scope(mesh, **sharding_overrides(cfg.name)):
+    with jax.set_mesh(mesh), sharding_scope(mesh, **sharding_overrides(cfg.name)):
         p_specs = model_specs(cfg)
         params_avals = jax.eval_shape(lambda: init_model(jax.random.PRNGKey(args.seed), cfg))
         params_sh = tree_shardings(params_avals, p_specs)

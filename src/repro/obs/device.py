@@ -1,14 +1,13 @@
 """Device-side observability hooks: profiler traces, dispatch costs, memory.
 
-Three independent hooks, each tolerant of backends that do not support it
-(CPU has no ``memory_stats``; some jax builds lack pieces of the profiler
-API) — observability must never take the serving path down:
+Three independent hooks. The cost and memory hooks tolerate backends that
+report nothing (CPU has no ``memory_stats``); a requested profile does not:
 
 * :func:`device_profile` — context manager around
   ``jax.profiler.start_trace`` / ``stop_trace``, so an ingest sweep or
   query replay can be captured as a full XLA device profile (open the
-  resulting directory with TensorBoard or Perfetto). No-ops, recording why,
-  when the profiler is unavailable.
+  resulting directory with TensorBoard or Perfetto). A profile that cannot
+  start raises.
 * :func:`compiled_cost` — per-dispatch cost of a jitted function on
   concrete arguments via AOT ``lower().compile().cost_analysis()`` (flops
   and bytes accessed, the roofline inputs) plus ``memory_analysis`` byte
@@ -34,28 +33,18 @@ __all__ = ["device_profile", "compiled_cost", "record_memory"]
 def device_profile(logdir: Optional[str]):
     """Capture a ``jax.profiler`` trace of the enclosed block into ``logdir``.
 
-    Yields a status dict: ``{"active": bool, "logdir": ..., "error": ...}``.
-    A ``None``/empty ``logdir`` or an unavailable profiler yields inactive
-    instead of raising — callers wrap hot serving loops with this.
+    A ``None``/empty ``logdir`` profiles nothing. A requested trace that
+    cannot start or be written raises: a run asked to profile the device
+    must not pass without the profile.
     """
-    status: Dict[str, Any] = {"active": False, "logdir": logdir}
     if not logdir:
-        yield status
+        yield
         return
+    jax.profiler.start_trace(logdir)
     try:
-        jax.profiler.start_trace(logdir)
-        status["active"] = True
-    except Exception as e:  # pragma: no cover - backend/build specific
-        status["error"] = f"{type(e).__name__}: {e}"
-        yield status
-        return
-    try:
-        yield status
+        yield
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:  # pragma: no cover
-            status["error"] = f"{type(e).__name__}: {e}"
+        jax.profiler.stop_trace()
 
 
 def compiled_cost(fn, *args, **kwargs) -> Dict[str, Any]:
@@ -74,8 +63,6 @@ def compiled_cost(fn, *args, **kwargs) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
     try:
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # older jax: one dict per program
-            ca = ca[0] if ca else {}
         out["flops"] = float(ca.get("flops", 0.0))
         out["bytes_accessed"] = float(ca.get("bytes accessed", 0.0))
     except Exception:

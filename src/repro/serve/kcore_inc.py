@@ -124,6 +124,10 @@ _EMPTY = np.zeros((0, 2), np.int64)
 # two-tier descent split: rows with degree <= this go in the narrow matrix
 _W_SMALL = 32
 
+# smallest padded side table of the device region traversal: padding arcs
+# are masked and near-free, and every distinct size is one more compile
+_SIDE_FLOOR = 1024
+
 # size-distribution buckets (region node counts): powers of 4 up to ~4M
 _COUNT_BUCKETS = 4.0 ** np.arange(12)
 
@@ -216,8 +220,8 @@ def _fused_descent_two(idx_s, valid_s, idx_b, valid_b, cand, seed, old,
 
 
 @jax.jit
-def _region_fixpoint(nbr, deg, core, ends, side_src, side_dst, side_valid,
-                     lo, hi, cap):
+def _region_fixpoint(nbr, deg, hubs, core, ends, side_src, side_dst,
+                     side_valid, lo, hi, cap):
     """Frontier-masked union-subcore traversal, one jitted while-loop.
 
     ``nbr``/``deg`` are the device ELL mirror; ``side_*`` is the padded side
@@ -227,9 +231,21 @@ def _region_fixpoint(nbr, deg, core, ends, side_src, side_dst, side_valid,
     endpoints are pre-seeded regardless of their level. Aborts early once
     the visited count exceeds ``cap`` (the caller falls back to a full
     recompute, so a partial region is never used).
+
+    The mirror is as wide as its largest row, and on a power-law graph
+    nearly all of it is padding. So each level sweeps two tiers, as the
+    descent does: the first ``_W_SMALL`` columns of every row, and the whole
+    rows of ``hubs`` (the rows with more entries; padding ids point at the
+    empty sentinel row). Together they hold every arc of the mirror.
     """
     n1, width = nbr.shape
-    valid = jnp.arange(width, dtype=jnp.int32)[None, :] < deg[:, None]
+    w = min(width, _W_SMALL)
+    narrow = nbr[:, :w]
+    valid = jnp.arange(w, dtype=jnp.int32)[None, :] < deg[:, None]
+    hub_nbr = nbr[hubs]
+    hub_valid = (
+        jnp.arange(width, dtype=jnp.int32)[None, :] < deg[hubs][:, None]
+    )
     eligible = (core >= lo) & (core <= hi)
 
     def cond(state):
@@ -238,8 +254,8 @@ def _region_fixpoint(nbr, deg, core, ends, side_src, side_dst, side_valid,
 
     def body(state):
         frontier, visited, _ = state
-        contrib = frontier[:, None] & valid
-        nxt = jnp.zeros(n1, bool).at[nbr].max(contrib)
+        nxt = jnp.zeros(n1, bool).at[narrow].max(frontier[:, None] & valid)
+        nxt = nxt.at[hub_nbr].max(frontier[hubs][:, None] & hub_valid)
         nxt = nxt.at[side_dst].max(frontier[side_src] & side_valid)
         newf = nxt & eligible & ~visited
         visited = visited | newf
@@ -549,8 +565,23 @@ class IncrementalCore:
         self._inflight: Optional[_RepairTicket] = None
         self.phase_seconds: dict = {}
         self.phase_impl: dict = {}
+        self._executables: dict = {}  # fused descents compiled per shape
 
     # ---------------------------------------------------------- dispatch
+
+    def _compiled(self, fn, args, **static):
+        """``fn`` compiled ahead of time for these arguments' shapes.
+
+        The repair path compiles here, before its retry wrapper and host
+        fallback, so a lowering or compile error raises to the caller
+        instead of being absorbed as a failed dispatch.
+        """
+        key = (fn, tuple(sorted(static.items())),
+               tuple((a.shape, a.dtype, a.sharding) for a in args))
+        exe = self._executables.get(key)
+        if exe is None:
+            exe = self._executables[key] = fn.lower(*args, **static).compile()
+        return exe
 
     def _device(self) -> bool:
         return self.impl != "ref"
@@ -737,17 +768,20 @@ class IncrementalCore:
         ends_mask[ends] = True
         core = np.zeros(n1, np.int32)
         core[:n] = self._core[:n]
-        s_pad = pow2(max(len(side_src), 1))
+        s_pad = max(pow2(len(side_src)), _SIDE_FLOOR)
         ss = np.zeros(s_pad, np.int32)
         sd = np.zeros(s_pad, np.int32)
         sv = np.zeros(s_pad, bool)
         ss[: len(side_src)] = side_src
         sd[: len(side_dst)] = side_dst
         sv[: len(side_src)] = True
+        big = np.where(g._deg > _W_SMALL)[0]
+        hubs = np.full(max(pow2(len(big)), 64), g.node_cap, np.int32)
+        hubs[: len(big)] = big
         plan = g.plan
         rep = jnp.asarray if plan is None else plan.replicate
         visited, count = _region_fixpoint(
-            ell.neighbours, ell.degrees, rep(core),
+            ell.neighbours, ell.degrees, rep(hubs), rep(core),
             rep(ends_mask), rep(ss), rep(sd),
             rep(sv), lo, hi, cap,
         )
@@ -819,10 +853,10 @@ class IncrementalCore:
                 k: self.phase_seconds.get(k)
                 for k in ("candidates", "descend")
             }
-            pending = self._descend_dispatch(
+            pending = self._launch(self._stage(
                 np.arange(n, dtype=np.int64), seed, old, 0, 1 << 30,
                 cand_deg=deg,
-            )
+            ))
             res = self._descend_read(pending)
             for k, b in before.items():
                 if b is None:
@@ -897,17 +931,12 @@ class IncrementalCore:
             return r_pad, 0, w_pad, 0, cells
         return r_small, r_big, w_pad, n_big, split_cells
 
-    def _descend_dispatch(self, cand, seed, old_cand, lo, hi, *, cand_deg):
-        """Gather/pad the candidate matrix and *launch* the fused descent.
-
-        Returns the pending dispatch (in-flight device arrays plus readback
-        bookkeeping) without blocking: jax dispatch is asynchronous, so the
-        host is free until ``_descend_read`` — the pipelined ingest stages
-        the next block's dedup/scatter in that gap.
-        """
+    def _stage(self, cand, seed, old_cand, lo, hi, *, cand_deg):
+        """Host half of a descent: gather/pad the candidate matrix, place
+        it, and compile the fused descent for its shapes. Nothing here is
+        retried or caught on the repair path: a compile error propagates."""
         g = self.g
         node_cap = g.node_cap
-        faults.check("device_dispatch")
         t0 = time.perf_counter()
         n_rows = len(cand)
         r_small, r_big, w_big, n_big, cells = self._tier_plan(
@@ -955,33 +984,41 @@ class IncrementalCore:
             old_p = vec(old_cand, 0, np.int32)
         self._tick("candidates", "gather", t0)
 
-        t0 = time.perf_counter()
         # under a ShardPlan (and a GSPMD-partitionable kernel impl) the
         # candidate matrix rows are split across the mesh: each shard sweeps
-        # its own rows and the frozen-boundary estimate stays replicated
+        # its own rows and the frozen-boundary estimate stays replicated. A
+        # Pallas kernel cannot be partitioned, so its descent runs on one
+        # device.
         plan = g.plan if self._kernel_mode() in ("count", "ref") else None
         row = jnp.asarray if plan is None else plan.place_rows
         rep = jnp.asarray if plan is None else plan.replicate
-        if r_big:
-            out = _fused_descent_two(
-                row(idx_s), row(valid_s), row(idx_b), row(valid_b),
-                row(np.asarray(cand_p, np.int32)),
-                row(np.asarray(seed_p, np.int32)),
-                row(np.asarray(old_p, np.int32)),
-                rep(est_full), lo, hi,
-                impl=self._kernel_mode(), max_sweeps=self.max_sweeps,
-            )
-        else:
-            out = _fused_descent(
-                row(idx), row(valid),
-                row(np.asarray(cand_p, np.int32)),
-                row(np.asarray(seed_p, np.int32)),
-                row(np.asarray(old_p, np.int32)),
-                rep(est_full), lo, hi,
-                impl=self._kernel_mode(), max_sweeps=self.max_sweeps,
-            )
-        return {"out": out, "n_rows": n_rows, "t0": t0, "cells": cells,
+        mats = (idx_s, valid_s, idx_b, valid_b) if r_big else (idx, valid)
+        args = tuple(row(m) for m in mats) + (
+            row(np.asarray(cand_p, np.int32)),
+            row(np.asarray(seed_p, np.int32)),
+            row(np.asarray(old_p, np.int32)),
+            rep(est_full), rep(np.int32(lo)), rep(np.int32(hi)),
+        )
+        exe = self._compiled(
+            _fused_descent_two if r_big else _fused_descent, args,
+            impl=self._kernel_mode(), max_sweeps=self.max_sweeps,
+        )
+        return {"exe": exe, "args": args, "n_rows": n_rows, "cells": cells,
                 "cand": cand_out, "keep": keep}
+
+    @staticmethod
+    def _launch(staged):
+        """Device half of a descent: one asynchronous dispatch.
+
+        Returns the pending dispatch (in-flight device arrays plus readback
+        bookkeeping) without blocking, so the host is free until
+        ``_descend_read`` — the pipelined ingest stages the next block's
+        dedup/scatter in that gap.
+        """
+        faults.check("device_dispatch")
+        t0 = time.perf_counter()
+        out = staged["exe"](*staged["args"])
+        return {**staged, "out": out, "t0": t0}
 
     def _descend_read(self, pending, *, full_interval: bool = True):
         """Block on a pending descent and pull the result back.
@@ -1045,18 +1082,16 @@ class IncrementalCore:
         self.repairs += 1
         return _RepairTicket(changed=changed)
 
-    def _dispatch_with_retry(self, cand, seed, old_cand, lo, hi, *,
-                             cand_deg):
-        """Bounded retry-with-backoff around the fused-descent dispatch.
+    def _dispatch_with_retry(self, staged):
+        """Bounded retry-with-backoff around the fused-descent dispatch of a
+        staged (already compiled) descent.
 
         Re-raises the last error after ``dispatch_retries`` retries; the
         callers then fall back to :meth:`_recover_ref`.
         """
         for attempt in range(self.dispatch_retries + 1):
             try:
-                return self._descend_dispatch(
-                    cand, seed, old_cand, lo, hi, cand_deg=cand_deg
-                )
+                return self._launch(staged)
             except Exception:
                 self.dispatch_failures += 1
                 metrics().counter("repair_dispatch_failures_total").inc()
@@ -1180,10 +1215,11 @@ class IncrementalCore:
                 # legacy static trigger: a huge candidate matrix costs
                 # more to sweep than one exact vectorized re-peel
                 return self._finish_repeel(ctx, hi)
+            staged = self._stage(
+                cand, seed, old[cand], lo, hi, cand_deg=cand_deg
+            )
             try:
-                pending = self._dispatch_with_retry(
-                    cand, seed, old[cand], lo, hi, cand_deg=cand_deg
-                )
+                pending = self._dispatch_with_retry(staged)
             except Exception:
                 return self._recover_ref(ctx, hi)
             # the dispatch may tier-reorder the rows: resolve/commit against

@@ -96,7 +96,7 @@ class RetrainConfig:
     n_walks: int = 10
     walk_length: int = 20
     sgns: SGNSConfig = dataclasses.field(
-        default_factory=lambda: SGNSConfig(dim=64, epochs=0.5, impl="ref")
+        default_factory=lambda: SGNSConfig(dim=64, epochs=0.5)
     )
     warm_start: bool = True  # seed emb_in from the previous vectors
     # epoch accounting scales steps with the (small) subcore corpus; the

@@ -49,7 +49,10 @@ from .recovery import KIND_INGEST, KIND_RETRACT
 from .store import EmbeddingStore
 from .stream import DynamicGraph
 
-__all__ = ["EmbeddingService", "ServiceStats"]
+# failures a retrain rolls back and survives; any other error propagates
+_RECOVERABLE = (faults.InjectedFault, jax.errors.JaxRuntimeError)
+
+__all__ = ["EmbeddingService", "ServiceStats", "top_k_reference"]
 
 # exact-percentile retention: latency percentiles describe the most recent
 # FLUSH_WINDOW flushes / RETRAIN_WINDOW retrains (steady state, bounded
@@ -155,6 +158,9 @@ class EmbeddingService:
 
             self._watchdog = HangWatchdog(float(hang_timeout), self._on_hang)
 
+        plan = store.plan
+        ell_mean = ops.ell_mean if plan is None else plan.ell_mean
+
         def _cold(nodes, nbr, slot_of, table, sentinel, cap, found):
             # sentinel / cap arrive as data: under a ShardPlan both the ELL
             # mirror and the store table carry shard-padding rows, so the
@@ -162,7 +168,7 @@ class EmbeddingService:
             idx = nbr[nodes]  # (B, W) neighbour node ids
             slots = slot_of[idx]  # (B, W) store slots (sentinel = capacity)
             valid = (idx != sentinel) & (slots < cap)
-            cold = ops.ell_mean(slots, valid, table, impl=impl)
+            cold = ell_mean(slots, valid, table, impl=impl)
             resolved = valid.any(axis=1)
             # slot gather of the found rows + select against the cold-start
             # means — spill-tier rows carry found=True with a sentinel slot
@@ -189,7 +195,6 @@ class EmbeddingService:
 
         # recompile only when ELL width / table capacity / node_cap change;
         # under a ShardPlan the scattered table must come back row-sharded
-        plan = store.plan
         if plan is None:
             self._fused_ro_fn = jax.jit(_cold)
             self._fused_wb_fn = jax.jit(_fused_wb)
@@ -474,6 +479,37 @@ class EmbeddingService:
             core[np.minimum(wb_nodes, max(len(core) - 1, 0))], 0
         )
 
+    def _compile_fused(self, sentinel: int) -> None:
+        """Compile both flush dispatch variants when a shape changes.
+
+        Which variant a batch takes depends on its cold/warm mix, and a
+        steady-state flush must never eat the other variant's cold compile
+        mid-run. This runs before the flush's retry loop, so a lowering or
+        compile error propagates instead of degrading the batch. The warm
+        call runs on an all-padding batch: its scatter targets only the zero
+        sentinel row (all slots == capacity, values 0) and both outputs are
+        discarded.
+        """
+        st = self.store
+        ell = self.graph.ell()
+        key = (int(st.capacity), ell.neighbours.shape, int(sentinel))
+        if key == self._fused_key:
+            return
+        args = (
+            jnp.asarray(np.full(self.batch, sentinel, np.int64)),
+            ell.neighbours,
+            st.slot_table_dev(),
+            st.table(),
+            jnp.int32(sentinel),
+            jnp.int32(st.capacity),
+            jnp.asarray(np.zeros(self.batch, bool)),
+        )
+        self._fused_ro_fn(*args)
+        self._fused_wb_fn(
+            *args, jnp.asarray(np.full(self.batch, st.capacity, np.int32))
+        )
+        self._fused_key = key
+
     def _flush_batch(self, nodes: np.ndarray) -> np.ndarray:
         """One static-shaped batch (len == self.batch, padded with -1).
 
@@ -494,6 +530,7 @@ class EmbeddingService:
         st.ensure_nodes(sentinel)
         real = (nodes >= 0) & (nodes < sentinel)
         nodes_c = np.where(real, nodes, sentinel)
+        self._compile_fused(sentinel)
         degraded_batch = False
         wb_slots_u = None
         for attempt in range(self.flush_retries + 1):
@@ -549,24 +586,6 @@ class EmbeddingService:
                     jnp.int32(st.capacity),
                     jnp.asarray(found),
                 )
-                key = (int(st.capacity), ell.neighbours.shape,
-                       int(sentinel))
-                if key != self._fused_key:
-                    # compile BOTH dispatch variants at every shape change:
-                    # which one a batch takes depends on its cold/warm mix,
-                    # and a steady-state flush must never eat the other
-                    # variant's cold compile mid-run. The warmup scatter
-                    # targets only the zero sentinel row (all slots ==
-                    # capacity, wvals 0), so it is a no-op on real rows and
-                    # both outputs are discarded.
-                    self._fused_ro_fn(*args)
-                    self._fused_wb_fn(
-                        *args,
-                        jnp.asarray(
-                            np.full(len(nodes), st.capacity, np.int32)
-                        ),
-                    )
-                    self._fused_key = key
                 if wb_slots_u is not None:
                     out, resolved, table2 = self._fused_wb_fn(
                         *args, jnp.asarray(wb_slots)
@@ -754,7 +773,7 @@ class EmbeddingService:
                 idx = np.asarray(idx, np.int64)
             else:
                 pv, pi = st.plan.partial_topk_fn(
-                    qn, tn, jnp.asarray(st.candidate_bias()), kk
+                    qn, tn, jnp.asarray(st.row_valid()), kk, self.impl,
                 )
                 vals, idx = st.plan.merge_topk(pv, pi, kk)
             own = st.slots_of(nodes).astype(np.int64)  # capacity = absent
@@ -820,7 +839,9 @@ class EmbeddingService:
         # mid-VersionRollout must not leave mixed-version rows. The core
         # baseline needs no rollback: mark_refresh only runs after a
         # successful swap. InjectedCrash (simulated process death) is a
-        # BaseException and deliberately passes through.
+        # BaseException and deliberately passes through. Only a device error
+        # or an injected fault is absorbed; anything else, such as a kernel
+        # the compiler refuses, is rolled back and re-raised.
         pre = self.store.state_dict() if self.transactional_retrain else None
         wd = self._watchdog
         if wd is not None:
@@ -828,12 +849,13 @@ class EmbeddingService:
         try:
             with obs.span("serve.retrain") as sp:
                 report = self.retrainer.run(between=between)
-        except Exception:
+        except Exception as e:
             self.stats.retrain_failures += 1
             metrics().counter("serve_retrain_failures_total").inc()
             if pre is not None:
                 self.store.load_state_dict(pre)
-                return None
+                if isinstance(e, _RECOVERABLE):
+                    return None
             raise
         finally:
             if wd is not None:
@@ -957,3 +979,31 @@ class EmbeddingService:
             jnp.asarray(np.zeros(self.batch, bool)),
             jnp.asarray(np.full(self.batch, self.store.capacity, np.int32)),
         )
+
+
+def top_k_reference(store: EmbeddingStore, nodes: np.ndarray,
+                    queries: np.ndarray, k: int) -> np.ndarray:
+    """Numpy all-pairs oracle for :meth:`EmbeddingService.top_k_neighbors`.
+
+    ``queries`` are the embeddings served for ``nodes`` (call after the
+    top-k call, whose flush wrote cold rows back). Scores every live store
+    row by cosine (the same ``normalize_rows`` prep), drops each query's
+    own row, and orders by (score desc, slot asc). Returns (Q, k) node ids,
+    -1 padded.
+    """
+    cap = store.capacity
+    table = np.asarray(store.table())[:cap]
+    tn = np.asarray(ops.normalize_rows(jnp.asarray(table)))
+    qn = np.asarray(ops.normalize_rows(jnp.asarray(queries)))
+    sim = qn @ tn.T
+    sim[:, ~np.asarray(store.row_valid())[:cap]] = -np.inf
+    own = store.slots_of(np.asarray(nodes, np.int64))
+    want = np.full((len(nodes), k), -1, np.int64)
+    for i in range(len(nodes)):
+        s = sim[i]
+        if own[i] < cap:
+            s[own[i]] = -np.inf
+        order = np.lexsort((np.arange(len(s)), -s))[:k]
+        live = order[s[order] > -np.inf]
+        want[i, : len(live)] = store.node_of_slots(live)
+    return want

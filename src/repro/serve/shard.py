@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.kernels import ops
 from repro.launch.mesh import make_mesh
 from repro.obs import metrics
 
@@ -159,48 +160,55 @@ class ShardPlan:
             out_shardings=self.row_sharding(1),
         )
 
+    def ell_mean(self, idx, valid, table, *, impl: str):
+        """``ops.ell_mean`` over a row-sharded ``table``, traced inside jit.
+
+        GSPMD cannot partition a Pallas kernel. So the (B, L) neighbour rows
+        are gathered first (a partitioned gather with a replicated result)
+        and every device runs the kernel over that small block: the same
+        rows in the same order as on one device, hence the same sums.
+        """
+        B, L = idx.shape
+        rows = jax.lax.with_sharding_constraint(
+            table[idx.reshape(-1)], self.replicated()
+        )
+        local = jnp.arange(B * L, dtype=jnp.int32).reshape(B, L)
+        return jax.shard_map(
+            functools.partial(ops.ell_mean, impl=impl), mesh=self.mesh,
+            in_specs=(P(), P(), P()), out_specs=P(), check_vma=False,
+        )(local, valid, rows)
+
     @functools.cached_property
     def partial_topk_fn(self):
         """jit: shard-local partial top-k over a row-sharded score table.
 
-        ``(q (Q, D) replicated, table (rows, D) row-sharded, bias (rows,)
-        additive validity mask) -> ((S, Q, kl) values, (S, Q, kl) global
-        row indices)``, kl = min(k, rows per shard). Each shard scores the
-        queries against only its own row chunk and reduces its local top-k
-        under the (score desc, index asc) total order — the (Q, rows)
-        score matrix never crosses shards; only the (S, Q, kl) candidate
-        lists do, and :meth:`merge_topk` stitches them on the host. Any
-        global top-k row is necessarily in its owner's local top-k, so the
-        stitch is exact.
+        ``(q (Q, D) replicated, table (rows, D) row-sharded, valid (rows,)
+        row mask) -> ((S, Q, kl) values, (S, Q, kl) global row indices)``,
+        kl = min(k, rows per shard). Each shard runs the single-device
+        scorer (``ops.top_k_scores``: the Pallas kernel on TPU) on its own
+        row chunk under the (score desc, index asc) total order, so every
+        row is scored exactly as on one device — the (Q, rows) score matrix
+        never crosses shards; only the (S, Q, kl) candidate lists do, and
+        :meth:`merge_topk` stitches them on the host. Any global top-k row
+        is necessarily in its owner's local top-k, so the stitch is exact.
         """
+        def local(q, t, valid, *, k, impl):
+            kl = min(k, t.shape[0])
+            vals, idx = ops.top_k_scores(q, t, kl, valid=valid, impl=impl)
+            off = jax.lax.axis_index(self.axis) * t.shape[0]
+            return vals[None], jnp.where(idx >= 0, idx + off, -1)[None]
 
-        def fn(q, table, bias, k):
-            S = self.n_shards
-            chunk = table.shape[0] // S
-            kl = min(int(k), chunk)
-            tb = table.reshape(S, chunk, table.shape[1])
-            bb = bias.reshape(S, chunk)
-            off = jnp.arange(S, dtype=jnp.int32) * chunk
-
-            def one(t, b, o):
-                scores = jnp.einsum(
-                    "qd,nd->qn", q.astype(jnp.float32),
-                    t.astype(jnp.float32),
-                ) + b[None, :]
-                idx = jnp.broadcast_to(
-                    jnp.arange(chunk, dtype=jnp.int32)[None, :], scores.shape
-                )
-                neg, sidx = jax.lax.sort(
-                    (-scores, idx), dimension=1, num_keys=2
-                )
-                vals = -neg[:, :kl]
-                gidx = jnp.where(vals > -jnp.inf, sidx[:, :kl] + o, -1)
-                return vals, gidx
-
-            return jax.vmap(one)(tb, bb, off)
+        def fn(q, table, valid, k, impl):
+            return jax.shard_map(
+                functools.partial(local, k=int(k), impl=impl),
+                mesh=self.mesh,
+                in_specs=(P(), P(self.axis, None), P(self.axis)),
+                out_specs=(P(self.axis), P(self.axis)),
+                check_vma=False,  # a Pallas kernel's outputs carry no vma
+            )(q, table, valid)
 
         return jax.jit(
-            fn, static_argnames="k",
+            fn, static_argnames=("k", "impl"),
             out_shardings=(self.replicated(), self.replicated()),
         )
 

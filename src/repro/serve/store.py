@@ -140,12 +140,6 @@ class EmbeddingStore:
         valid[: self.capacity] = self._node_at >= 0
         return valid
 
-    def candidate_bias(self) -> np.ndarray:
-        """(rows,) float32 additive retrieval mask: 0 on live rows, -inf on
-        dead/sentinel/padding rows — the top-k kernels add it to scores so
-        dead rows can never enter a result."""
-        return np.where(self.row_valid(), 0.0, -np.inf).astype(np.float32)
-
     # ------------------------------------------------------------- writes
 
     def _tick(self) -> int:

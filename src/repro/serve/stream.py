@@ -382,7 +382,10 @@ class DynamicGraph:
         ) as sp:
             deg = self.degrees()
             max_deg = int(deg.max()) if deg.size else 0
-            width = max(int(np.ceil(max_deg * self.slack)), min_width, 1)
+            # a power of two: the device programs over the mirror recompile
+            # per width, so widths that track the max degree exactly would
+            # recompile on nearly every compaction
+            width = pow2(max(int(np.ceil(max_deg * self.slack)), min_width))
             nbr = np.full(
                 (self.node_cap + 1, width), self.node_cap, np.int32
             )

@@ -130,7 +130,7 @@ MULTIDEV_SCRIPT = textwrap.dedent(
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import make_mesh, use_mesh
+    from repro.launch.mesh import make_mesh
     from repro.distributed.sharding import sharding_scope, constrain, named_sharding
     from repro.distributed.checkpoint import CheckpointManager
 
@@ -138,7 +138,7 @@ MULTIDEV_SCRIPT = textwrap.dedent(
 
     # --- logical rules end-to-end: constrain inside jit on a (4,2) mesh ---
     mesh = make_mesh((4, 2), ("data", "model"))
-    with use_mesh(mesh), sharding_scope(mesh):
+    with jax.set_mesh(mesh), sharding_scope(mesh):
         x = jnp.arange(8 * 6, dtype=jnp.float32).reshape(8, 6)
 
         @jax.jit
@@ -167,7 +167,7 @@ MULTIDEV_SCRIPT = textwrap.dedent(
         assert mgr.latest_step() == 100
 
     mesh2 = make_mesh((2, 4), ("data", "model"))
-    with use_mesh(mesh2), sharding_scope(mesh2):
+    with jax.set_mesh(mesh2), sharding_scope(mesh2):
         sh2 = {"w": named_sharding((8, 6), ("batch", None)),
                "step": named_sharding((), ())}
         target = {"w": jax.ShapeDtypeStruct((8, 6), jnp.float32),
@@ -180,7 +180,7 @@ MULTIDEV_SCRIPT = textwrap.dedent(
         assert restored["w"].sharding.spec == P("data", None)
 
     # async save + retention
-    with use_mesh(mesh2), sharding_scope(mesh2):
+    with jax.set_mesh(mesh2), sharding_scope(mesh2):
         mgr.save(101, tree, blocking=False)
         mgr.wait()
         mgr.save(102, tree)

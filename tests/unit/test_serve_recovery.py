@@ -423,6 +423,54 @@ def test_flush_retry_absorbs_transient_fault():
     assert np.isfinite(out).all()
 
 
+def _flush_failures():
+    from repro.obs import metrics
+
+    return metrics().counter("serve_flush_failures_total").value
+
+
+def test_flush_lowering_error_raises_instead_of_degrading():
+    """A kernel the backend cannot compile is a bug, not a device fault:
+    the fused flush's compile runs outside its retry loop, so the error
+    reaches the caller and no query is answered from stale rows. (Pallas
+    without interpret mode has no CPU lowering.)"""
+    from repro.serve import EmbeddingService
+
+    base, stream = _svc()
+    base.ingest_edges(stream, block_size=64)
+    svc = EmbeddingService(base.graph, base.cores, base.store, batch=16,
+                           impl="pallas")
+    before = _flush_failures()
+    with pytest.raises(ValueError, match="interpret mode"):
+        svc.embed(np.arange(8))
+    assert _flush_failures() == before
+    assert not svc.degraded and svc.stats.degraded_queries == 0
+
+
+def test_descent_lowering_error_raises_instead_of_host_repeel():
+    """The fused descent compiles ahead of its retry wrapper: a lowering
+    error propagates, and is never absorbed as a failed dispatch that the
+    exact host peel then papers over."""
+    svc, stream = _svc()
+    svc.cores.kernel_impl = "pallas"
+    with pytest.raises(ValueError, match="interpret mode"):
+        svc.ingest_block(stream[:64])
+        svc.sync()
+    assert svc.cores.dispatch_failures == 0
+    assert svc.cores.dispatch_recoveries == 0
+
+
+def test_injected_device_dispatch_fault_still_degrades_to_host_peel():
+    svc, stream = _svc()
+    svc.cores.retry_backoff = 0.0
+    faults.install(FaultPlan.parse("device_dispatch:1+"))  # sticky fault
+    svc.ingest_edges(stream, block_size=64)
+    faults.install(None)
+    assert svc.cores.dispatch_failures > 0
+    assert svc.cores.dispatch_recoveries > 0
+    assert svc.cores.resync() == 0  # the host peel kept cores exact
+
+
 def _attach_retrainer(svc, seed=0):
     from repro.serve.retrain import RetrainConfig, Retrainer
     from repro.skipgram.trainer import SGNSConfig
@@ -460,6 +508,25 @@ def test_failed_retrain_rolls_back_store(point):
     # and with the fault gone the same forced retrain completes
     assert svc.maybe_retrain(force=True) is not None
     assert svc.stats.retrains == 1
+
+
+def test_retrain_non_runtime_error_rolls_back_and_raises(monkeypatch):
+    """Only device errors and injected faults are absorbed; any other
+    error, e.g. a kernel the compiler refuses, is rolled back and raised."""
+    svc, stream = _svc(n=200, seed=1, dim=16)
+    svc.ingest_edges(stream, block_size=64)
+    _attach_retrainer(svc)
+    pre = svc.store.state_dict()
+
+    def refused(*a, **k):
+        raise NotImplementedError("kernel refused by the compiler")
+
+    monkeypatch.setattr(svc.retrainer, "_train", refused)
+    with pytest.raises(NotImplementedError):
+        svc.maybe_retrain(force=True)
+    assert svc.stats.retrain_failures == 1 and svc.stats.retrains == 0
+    post = svc.store.state_dict()
+    assert all(np.array_equal(pre[k], post[k]) for k in pre)
 
 
 def test_retrain_crash_passes_through_transaction():
