@@ -4,10 +4,10 @@ CI's observability leg runs the serving benchmark with ``--trace`` and then
 runs this over the exported JSON: the trace must load, contain complete
 ("ph": "X") events, and cover every required stage of the pipeline —
 ingest, at least one core-repair phase (region / candidates / descend /
-fallback), and query flushes — plus the retrain stages when the run
-included retraining. A refactor that silently drops a span (or renames one
-without updating its consumers) fails here instead of producing
-quietly-empty traces.
+fallback), and query flushes with their device wait (``flush.wait``) —
+plus the retrain stages when the run included retraining. A refactor that
+silently drops a span (or renames one without updating its consumers)
+fails here instead of producing quietly-empty traces.
 
 Usage::
 
@@ -25,7 +25,7 @@ import sys
 REQUIRED = [
     "serve.ingest",
     "serve.flush",
-    "store.gather",
+    "flush.wait",
     "graph.add_edges",
 ]
 # block repair always runs the region phase; which later phase fires
@@ -45,9 +45,9 @@ RECOVERY_REQUIRED = [
     "recovery.restore",
     "recovery.replay",
 ]
-# the query-engine leg (--topk benchmark runs): retrieval spans plus the
-# fused-gather dispatch (store.gather tagged fused=1) the flush path uses
-TOPK_REQUIRED = ["serve.topk"]
+# the query-engine leg (--topk benchmark runs): the retrieval span and the
+# scoring kernel's dispatch-to-readback inside it
+TOPK_REQUIRED = ["serve.topk", "topk.wait"]
 
 
 def main(argv=None) -> int:
@@ -59,8 +59,8 @@ def main(argv=None) -> int:
                     help="also require the WAL/snapshot/restore/replay "
                          "recovery spans")
     ap.add_argument("--expect-topk", action="store_true",
-                    help="also require the serve.topk retrieval span and a "
-                         "fused store.gather dispatch (args.fused == 1)")
+                    help="also require the serve.topk retrieval span and "
+                         "its topk.wait kernel dispatch")
     args = ap.parse_args(argv)
 
     with open(args.trace) as f:
@@ -81,13 +81,6 @@ def main(argv=None) -> int:
         missing += [n for n in RECOVERY_REQUIRED if n not in names]
     if args.expect_topk:
         missing += [n for n in TOPK_REQUIRED if n not in names]
-        fused = any(
-            e["name"] == "store.gather"
-            and (e.get("args") or {}).get("fused") == 1
-            for e in events
-        )
-        if not fused:
-            missing.append("store.gather{fused=1}")
     if missing:
         print(f"[check-trace] FAIL: missing spans: {missing}")
         return 1

@@ -54,6 +54,7 @@ from .trace import (
     record,
     set_tracer,
     span,
+    timed,
     tracer,
 )
 
@@ -67,6 +68,7 @@ __all__ = [
     "enable",
     "disable",
     "span",
+    "timed",
     "record",
     "DEFAULT_EXEMPLAR_WATCH",
     # metrics

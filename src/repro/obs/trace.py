@@ -8,21 +8,42 @@ entry points:
 * context manager: ``with tracer.span("serve.flush", batch=64) as sp:
   ... sp.set(cold=3)``;
 * decorator: ``@tracer.wrap("retrain.train")``;
-* pre-timed: ``tracer.record(name, t0, t1, **attrs)`` for code that already
-  measures itself (the incremental-core phase timers hand their intervals
-  straight in, so their numbers and the trace are the same measurement).
+* pre-timed: ``tracer.record(name, t0, t1, **attrs)`` for an interval that
+  opens in one call and closes in another (a dispatch read back later);
+* always-timed: ``obs.timed(name, **attrs)`` for code that needs its own
+  interval whether or not tracing is on (the incremental-core phase timers
+  read the span's ``t0``/``t1``, so their numbers and the trace are the
+  same measurement).
+
+Every span carries an ``id`` and the ``parent`` id of the innermost span
+open on the same thread when it began (None at the top); a pre-timed
+``record()`` takes the innermost open span as its parent. A span's self time
+is its duration less the time its children cover.
+
+**On the profiler's clock.** While the tracer is enabled, each context span
+also enters a ``jax.profiler.TraceAnnotation`` of the same name for its
+lifetime, so a ``jax.profiler`` trace holds the program's spans on the host
+timeline beside the device ops. The tracer stays the one store; the
+annotation is its mirror in the profile.
+
+**Compile spans.** The first enabled default tracer (:func:`enable`)
+registers one ``jax.monitoring`` duration listener per process. While the
+default tracer is enabled, every JAX trace, lowering and backend compile
+becomes a pre-timed ``jit.compile`` span (``stage`` = ``trace``,
+``lower`` or ``compile``; ``fun`` = the function), ending when JAX reports
+it; while it is disabled the listener returns at once.
 
 Disabled tracing is a **zero-work no-op**: ``span()`` returns one shared
-:data:`NULL_SPAN` singleton, never touches the clock, and records nothing —
-the overhead-guard test asserts this with a counting fake clock, and the
-serving benchmark asserts the enabled path stays within a few percent of
-ingest throughput.
+:data:`NULL_SPAN` singleton, never touches the clock, enters no profiler
+annotation, imports nothing, and records nothing — the overhead-guard test
+asserts this with a counting fake clock, and the serving benchmark asserts
+the enabled path stays within a few percent of ingest throughput.
 
 Exports: JSON-lines (one span per line, machine-diffable) and Chrome
 ``trace_event`` format (``ph: "X"`` complete events), loadable in
 chrome://tracing or https://ui.perfetto.dev. Nesting is reconstructed by the
-viewers from containment on the per-thread timeline; ``depth``/``parent``
-ride along in ``args`` for programmatic consumers.
+viewers from containment on the per-thread timeline; ``id``/``parent``/
+``depth`` ride along in ``args`` for programmatic consumers.
 
 **Tail-sampled exemplars.** A latency histogram's p99 tells you a slow
 flush happened; it cannot tell you *which* dispatch was slow or what ran
@@ -46,6 +67,7 @@ their own instance via :func:`set_tracer`.
 from __future__ import annotations
 
 import functools
+import itertools
 import json
 import math
 import threading
@@ -62,6 +84,7 @@ __all__ = [
     "enable",
     "disable",
     "span",
+    "timed",
     "record",
     "DEFAULT_EXEMPLAR_WATCH",
 ]
@@ -69,6 +92,24 @@ __all__ = [
 # span names the tracer tail-samples exemplars for; a trailing "." matches
 # the whole namespace (every repair phase, present and future)
 DEFAULT_EXEMPLAR_WATCH = ("serve.flush", "serve.topk", "repair.")
+
+# jax.monitoring duration events recorded as ``jit.compile`` spans, by stage
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _annotation(name: str):
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
 
 
 class _NullSpan:
@@ -93,10 +134,35 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _Timer:
+    """What :func:`timed` returns while tracing is disabled: the interval on
+    ``time.perf_counter`` and the attributes, recorded nowhere."""
+
+    __slots__ = ("attrs", "t0", "t1")
+
+    def __init__(self, attrs: Dict[str, Any]):
+        self.attrs = attrs
+        self.t0 = 0.0
+        self.t1 = 0.0
+
+    def set(self, **attrs) -> "_Timer":
+        self.attrs.update(attrs)
+        return self
+
+    def __enter__(self) -> "_Timer":
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        return False
+
+
 class Span:
     """One live interval; created by :meth:`Tracer.span`, closed on exit."""
 
-    __slots__ = ("_tracer", "name", "attrs", "t0", "t1", "depth")
+    __slots__ = ("_tracer", "name", "attrs", "t0", "t1", "depth", "id",
+                 "parent", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -105,6 +171,9 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self.depth = 0
+        self.id = next(tracer._ids)
+        self.parent = None
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         """Attach/overwrite attributes mid-span (e.g. sizes known late)."""
@@ -113,19 +182,26 @@ class Span:
 
     def __enter__(self) -> "Span":
         t = self._tracer
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         stack = t._stack()
         self.depth = len(stack)
-        stack.append(self.name)
+        self.parent = stack[-1].id if stack else None
+        stack.append(self)
         self.t0 = t._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         t = self._tracer
-        self.t1 = t._clock()
-        stack = t._stack()
-        if stack and stack[-1] == self.name:
-            stack.pop()
-        t._emit(self.name, self.t0, self.t1, self.depth, self.attrs)
+        try:
+            self.t1 = t._clock()
+            stack = t._stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+            t._emit(self.name, self.t0, self.t1, self.depth, self.attrs,
+                    self.id, self.parent)
+        finally:
+            self._ann.__exit__(None, None, None)
         return False
 
 
@@ -148,6 +224,7 @@ class Tracer:
         self.events: List[Dict[str, Any]] = []
         self.dropped = 0  # events past max_events (never silently truncated)
         self._local = threading.local()
+        self._ids = itertools.count()
         # tail-sampled exemplars: per watched name, a bounded duration ring
         # drives the adaptive threshold; exemplars are keyed by (name,
         # histogram-bucket index) and keep the slowest capture per bucket
@@ -163,13 +240,13 @@ class Tracer:
 
     # ------------------------------------------------------------- recording
 
-    def _stack(self) -> List[str]:
+    def _stack(self) -> List[Span]:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
         return stack
 
-    def _emit(self, name, t0, t1, depth, attrs) -> None:
+    def _emit(self, name, t0, t1, depth, attrs, id_, parent) -> None:
         if len(self.events) >= self.max_events:
             self.dropped += 1
             return
@@ -180,6 +257,8 @@ class Tracer:
                 "ts": t0,
                 "dur": t1 - t0,
                 "depth": depth,
+                "id": id_,
+                "parent": parent,
                 "tid": tid,
                 "attrs": attrs,
             }
@@ -283,16 +362,30 @@ class Tracer:
             return NULL_SPAN
         return Span(self, name, attrs)
 
+    def timed(self, name: str, **attrs) -> Any:
+        """A span that is timed whether or not tracing is enabled.
+
+        Enabled, it is :meth:`span`; disabled, a bare ``time.perf_counter``
+        timer that records nothing. Either way ``t0``/``t1`` hold the
+        interval after exit, for code whose own accounting needs it.
+        """
+        if not self.enabled:
+            return _Timer(attrs)
+        return Span(self, name, attrs)
+
     def record(self, name: str, t0: float, t1: float, **attrs) -> None:
         """Log an already-measured ``[t0, t1]`` interval as a complete span.
 
         ``t0``/``t1`` must come from this tracer's clock (the default is
         ``time.perf_counter``, which the serve stack's own timers use) so
         pre-timed spans land on the same timeline as context-manager ones.
+        Its parent is the innermost span open on this thread.
         """
         if not self.enabled:
             return
-        self._emit(name, t0, t1, len(self._stack()), attrs)
+        stack = self._stack()
+        self._emit(name, t0, t1, len(stack), attrs, next(self._ids),
+                   stack[-1].id if stack else None)
 
     def wrap(self, name: Optional[str] = None) -> Callable:
         """Decorator form: the wrapped call body becomes one span."""
@@ -315,6 +408,7 @@ class Tracer:
         self.events = []
         self.dropped = 0
         self._local = threading.local()
+        self._ids = itertools.count()
         self.exemplars = {}
         self.exemplars_dropped = 0
         self._tail_durs = {}
@@ -343,6 +437,8 @@ class Tracer:
         for e in self.events:
             args = dict(e["attrs"])
             args["depth"] = e["depth"]
+            args["id"] = e["id"]
+            args["parent"] = e["parent"]
             out.append(
                 {
                     "name": e["name"],
@@ -404,12 +500,42 @@ def set_tracer(t: Tracer) -> Tracer:
     """Swap the default tracer (tests install fake-clock instances)."""
     global _tracer
     _tracer = t
+    if t.enabled:
+        _listen_for_compiles()
     return t
 
 
 def enable(**kwargs) -> Tracer:
     """Install a fresh enabled default tracer and return it."""
     return set_tracer(Tracer(enabled=True, **kwargs))
+
+
+_compile_listener_installed = False
+
+
+def _listen_for_compiles() -> None:
+    """Register :func:`_on_compile_event` with ``jax.monitoring``, once."""
+    global _compile_listener_installed
+    if _compile_listener_installed:
+        return
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+    _compile_listener_installed = True
+
+
+def _on_compile_event(event: str, duration: float, **kwargs) -> None:
+    """``jit.compile`` span for a JAX trace, lowering or compile that just
+    ended, while the default tracer is enabled."""
+    t = _tracer
+    if not t.enabled:
+        return
+    stage = _COMPILE_EVENTS.get(event)
+    if stage is None:
+        return
+    t1 = t._clock()
+    t.record("jit.compile", t1 - float(duration), t1, stage=stage,
+             fun=str(kwargs.get("fun_name", "?")))
 
 
 def disable() -> Tracer:
@@ -426,8 +552,13 @@ def span(name: str, **attrs) -> Any:
     return Span(t, name, attrs)
 
 
+def timed(name: str, **attrs) -> Any:
+    """``tracer().timed(...)``: a span whose interval is kept either way."""
+    return _tracer.timed(name, **attrs)
+
+
 def record(name: str, t0: float, t1: float, **attrs) -> None:
     """``tracer().record(...)`` for pre-timed intervals."""
     t = _tracer
     if t.enabled:
-        t._emit(name, t0, t1, len(t._stack()), attrs)
+        t.record(name, t0, t1, **attrs)

@@ -609,19 +609,18 @@ class IncrementalCore:
         # A/B runs against the old trigger measure the old fallback too
         return "rounds" if self.policy.mode == "region" else "shell"
 
-    def _tick(self, phase: str, mode: str, t0: float,
-              t1: Optional[float] = None) -> None:
-        if t1 is None:
-            t1 = time.perf_counter()
+    def _tick(self, phase: str, mode: str, t0: float, t1: float) -> None:
+        """Charge ``[t0, t1]`` to ``phase``.
+
+        The interval is that of the phase's ``repair.<phase>`` span (an
+        ``obs.timed`` block, or a pre-timed ``obs.record`` where it spans two
+        calls), so phase_report(), the metrics registry, the repair policy
+        and the trace all describe one measurement.
+        """
         self.phase_seconds[phase] = (
             self.phase_seconds.get(phase, 0.0) + t1 - t0
         )
         self.phase_impl[phase] = mode
-        # the same interval feeds the trace (one span per phase occurrence,
-        # nested under the enclosing serve.ingest/retract span) and the
-        # metrics registry — phase_report(), the trace, and the exporter all
-        # describe one measurement
-        obs.record(f"repair.{phase}", t0, t1, impl=mode)
         metrics().histogram("repair_phase_seconds", phase=phase).observe(
             t1 - t0
         )
@@ -837,52 +836,55 @@ class IncrementalCore:
         peel for ``impl="ref"``."""
         n = self.g.n_nodes
         mode = self._repeel_mode()
-        t0 = time.perf_counter()
-        work = 2 * self.g.n_edges
-        if mode == "shell":
-            oracle, mode, work = self._repeel_shell(old, hi)
-        elif mode == "descend":
-            deg = self.g.degrees_of(np.arange(n))
-            seed = np.maximum(
-                np.minimum(deg.astype(np.int64), old.astype(np.int64) + m_ins),
-                0,
-            ).astype(np.int32)
-            # the inner gather/descent ticks belong to the fallback bucket:
-            # roll them back so the phase report stays non-overlapping
-            before = {
-                k: self.phase_seconds.get(k)
-                for k in ("candidates", "descend")
-            }
-            pending = self._launch(self._stage(
-                np.arange(n, dtype=np.int64), seed, old, 0, 1 << 30,
-                cand_deg=deg,
-            ))
-            res = self._descend_read(pending)
-            for k, b in before.items():
-                if b is None:
-                    self.phase_seconds.pop(k, None)
-                    self.phase_impl.pop(k, None)
+        with obs.timed("repair.fallback") as sp:
+            work = 2 * self.g.n_edges
+            if mode == "shell":
+                oracle, mode, work = self._repeel_shell(old, hi)
+            elif mode == "descend":
+                deg = self.g.degrees_of(np.arange(n))
+                seed = np.maximum(
+                    np.minimum(deg.astype(np.int64),
+                               old.astype(np.int64) + m_ins),
+                    0,
+                ).astype(np.int32)
+                # the inner gather/descent ticks belong to the fallback
+                # bucket: roll them back so the phase report stays
+                # non-overlapping
+                before = {
+                    k: self.phase_seconds.get(k)
+                    for k in ("candidates", "descend")
+                }
+                pending = self._launch(self._stage(
+                    np.arange(n, dtype=np.int64), seed, old, 0, 1 << 30,
+                    cand_deg=deg,
+                ))
+                res = self._descend_read(pending)
+                for k, b in before.items():
+                    if b is None:
+                        self.phase_seconds.pop(k, None)
+                        self.phase_impl.pop(k, None)
+                    else:
+                        self.phase_seconds[k] = b
+                if res is None:
+                    # the sweep cap truncated the full descent
+                    # (pathological cascade depth) — recover with the
+                    # uncapped exact peel
+                    src, dst = self.g.arc_arrays()
+                    oracle = core_numbers_rounds(n, src, dst)
+                    mode = "rounds"
                 else:
-                    self.phase_seconds[k] = b
-            if res is None:
-                # the sweep cap truncated the full descent (pathological
-                # cascade depth) — recover with the uncapped exact peel
+                    # the dispatch may tier-reorder rows: scatter back by id
+                    oracle = np.zeros(n, np.int32)
+                    oracle[pending["cand"]] = res[0]
+            elif mode == "rounds":
                 src, dst = self.g.arc_arrays()
                 oracle = core_numbers_rounds(n, src, dst)
-                mode = "rounds"
+                work = len(src)
             else:
-                # the dispatch may tier-reorder rows: scatter back by id
-                oracle = np.zeros(n, np.int32)
-                oracle[pending["cand"]] = res[0]
-        elif mode == "rounds":
-            src, dst = self.g.arc_arrays()
-            oracle = core_numbers_rounds(n, src, dst)
-            work = len(src)
-        else:
-            oracle = core_numbers_host(self.g.snapshot())
-        t1 = time.perf_counter()
-        self._tick("fallback", mode, t0, t1)
-        self.policy.observe("repeel", max(work, 1), t1 - t0)
+                oracle = core_numbers_host(self.g.snapshot())
+            sp.set(impl=mode)
+        self._tick("fallback", mode, sp.t0, sp.t1)
+        self.policy.observe("repeel", max(work, 1), sp.t1 - sp.t0)
         changed = oracle != self._core[:n]
         self.promoted += int((oracle > self._core[:n]).sum())
         self.demoted += int((oracle < self._core[:n]).sum())
@@ -937,52 +939,53 @@ class IncrementalCore:
         retried or caught on the repair path: a compile error propagates."""
         g = self.g
         node_cap = g.node_cap
-        t0 = time.perf_counter()
-        n_rows = len(cand)
-        r_small, r_big, w_big, n_big, cells = self._tier_plan(
-            n_rows, cand_deg
-        )
-        keep = None
-        if r_big:
-            # hubs last; the stable partition keeps each tier's rows in
-            # input order, and ``keep`` maps the padded concat back to them
-            order = np.argsort(cand_deg > _W_SMALL, kind="stable")
-            cand, seed = cand[order], seed[order]
-            old_cand = old_cand[order]
-            keep = np.concatenate(
-                [np.arange(n_rows - n_big), r_small + np.arange(n_big)]
+        with obs.timed("repair.candidates", impl="gather") as sp:
+            n_rows = len(cand)
+            r_small, r_big, w_big, n_big, cells = self._tier_plan(
+                n_rows, cand_deg
             )
-        cand_out = cand  # unpadded (tier-ordered) rows the result maps to
-        idx, valid = g.gather_rows(cand)
-        est_full = np.zeros(node_cap + 1, np.int32)
-        est_full[: g.n_nodes] = self._core[: g.n_nodes]
+            sp.set(rows=n_rows, cells=cells)
+            keep = None
+            if r_big:
+                # hubs last; the stable partition keeps each tier's rows in
+                # input order, and ``keep`` maps the padded concat back to them
+                order = np.argsort(cand_deg > _W_SMALL, kind="stable")
+                cand, seed = cand[order], seed[order]
+                old_cand = old_cand[order]
+                keep = np.concatenate(
+                    [np.arange(n_rows - n_big), r_small + np.arange(n_big)]
+                )
+            cand_out = cand  # unpadded (tier-ordered) rows the result maps to
+            idx, valid = g.gather_rows(cand)
+            est_full = np.zeros(node_cap + 1, np.int32)
+            est_full[: g.n_nodes] = self._core[: g.n_nodes]
 
-        def vec(x, fill, dtype):
-            out = np.full(r_small + r_big, fill, dtype)
-            out[: n_rows - n_big] = x[: n_rows - n_big]
-            out[r_small : r_small + n_big] = x[n_rows - n_big :]
-            return out
+            def vec(x, fill, dtype):
+                out = np.full(r_small + r_big, fill, dtype)
+                out[: n_rows - n_big] = x[: n_rows - n_big]
+                out[r_small : r_small + n_big] = x[n_rows - n_big :]
+                return out
 
-        if r_big:
-            n_small = n_rows - n_big
-            idx_s, valid_s = _fit_width(
-                idx[:n_small], valid[:n_small], _W_SMALL, node_cap
-            )
-            idx_b, valid_b = _fit_width(
-                idx[n_small:], valid[n_small:], w_big, node_cap
-            )
-            idx_s, valid_s = _pad_rows(idx_s, valid_s, r_small, node_cap)
-            idx_b, valid_b = _pad_rows(idx_b, valid_b, r_big, node_cap)
-            cand_p = vec(cand, node_cap, np.int64)
-            seed_p = vec(seed, 0, np.int32)
-            old_p = vec(old_cand, 0, np.int32)
-        else:
-            idx, valid = _fit_width(idx, valid, w_big, node_cap)
-            idx, valid = _pad_rows(idx, valid, r_small, node_cap)
-            cand_p = vec(cand, node_cap, np.int64)
-            seed_p = vec(seed, 0, np.int32)
-            old_p = vec(old_cand, 0, np.int32)
-        self._tick("candidates", "gather", t0)
+            if r_big:
+                n_small = n_rows - n_big
+                idx_s, valid_s = _fit_width(
+                    idx[:n_small], valid[:n_small], _W_SMALL, node_cap
+                )
+                idx_b, valid_b = _fit_width(
+                    idx[n_small:], valid[n_small:], w_big, node_cap
+                )
+                idx_s, valid_s = _pad_rows(idx_s, valid_s, r_small, node_cap)
+                idx_b, valid_b = _pad_rows(idx_b, valid_b, r_big, node_cap)
+                cand_p = vec(cand, node_cap, np.int64)
+                seed_p = vec(seed, 0, np.int32)
+                old_p = vec(old_cand, 0, np.int32)
+            else:
+                idx, valid = _fit_width(idx, valid, w_big, node_cap)
+                idx, valid = _pad_rows(idx, valid, r_small, node_cap)
+                cand_p = vec(cand, node_cap, np.int64)
+                seed_p = vec(seed, 0, np.int32)
+                old_p = vec(old_cand, 0, np.int32)
+        self._tick("candidates", "gather", sp.t0, sp.t1)
 
         # under a ShardPlan (and a GSPMD-partitionable kernel impl) the
         # candidate matrix rows are split across the mesh: each shard sweeps
@@ -1031,21 +1034,35 @@ class IncrementalCore:
         Returns ``(new, max_gain, max_loss, ceiling_hit, floor_hit)`` or
         None when the sweep cap truncated the descent.
         """
-        t_read = time.perf_counter()
+        impl = f"fused[{self._kernel_mode()}]"
+        attrs = dict(impl=impl, rows=pending["n_rows"],
+                     cells=pending["cells"])
+        if full_interval:
+            # the interval opened at the dispatch, in another call
+            res, truncated = self._read_descent(pending)
+            t0, t1 = pending["t0"], time.perf_counter()
+            obs.record("repair.descend", t0, t1, **attrs)
+        else:
+            with obs.timed("repair.descend", **attrs) as sp:
+                res, truncated = self._read_descent(pending)
+            t0, t1 = sp.t0, sp.t1
+        self._tick("descend", impl, t0, t1)
+        self.policy.observe("descend", pending["cells"], t1 - t0)
+        # None: the max_sweeps cap was hit before the fixed point
+        return None if truncated else res
+
+    def _read_descent(self, pending):
+        """Block on a pending descent's outputs and count it; returns
+        ``((new, max_gain, max_loss, ceiling_hit, floor_hit), truncated)``."""
         new, gain, loss, ceiling, floor, sweeps, truncated = pending["out"]
         new = np.asarray(new, np.int32)
         keep = pending["keep"]
         new = new[: pending["n_rows"]] if keep is None else new[keep]
-        t0 = pending["t0"] if full_interval else t_read
         self.sweeps += int(sweeps)
         self.descends += 1
         metrics().counter("repair_descends_total").inc()
-        t1 = time.perf_counter()
-        self._tick("descend", f"fused[{self._kernel_mode()}]", t0, t1)
-        self.policy.observe("descend", pending["cells"], t1 - t0)
-        if bool(truncated):  # max_sweeps cap hit before the fixed point
-            return None
-        return new, int(gain), int(loss), bool(ceiling), bool(floor)
+        res = (new, int(gain), int(loss), bool(ceiling), bool(floor))
+        return res, bool(truncated)
 
     def _descend(self, cand: np.ndarray, seed: np.ndarray) -> np.ndarray:
         """Reference host descent: per-iteration jitted sweeps over a
@@ -1169,22 +1186,22 @@ class IncrementalCore:
         if mode == "fallback":
             return self._finish_repeel(ctx, hi)
 
-        t0 = time.perf_counter()
-        if region_mode == "ref":
-            cand = np.asarray(
-                self._region(ctx["ends"], lo, hi, ctx["removed"]), np.int64
-            )
-            if len(cand) > cap:
-                cand = None
-        elif region_mode == "jit":
-            cand = self._region_device(
-                ctx["ends"], lo, hi, ctx["side_src"], ctx["side_dst"], cap
-            )
-        else:
-            cand = self._region_np(
-                ctx["ends"], lo, hi, ctx["side_src"], ctx["side_dst"], cap
-            )
-        self._tick("region", region_mode, t0)
+        with obs.timed("repair.region", impl=region_mode) as sp:
+            if region_mode == "ref":
+                cand = np.asarray(
+                    self._region(ctx["ends"], lo, hi, ctx["removed"]), np.int64
+                )
+                if len(cand) > cap:
+                    cand = None
+            elif region_mode == "jit":
+                cand = self._region_device(
+                    ctx["ends"], lo, hi, ctx["side_src"], ctx["side_dst"], cap
+                )
+            else:
+                cand = self._region_np(
+                    ctx["ends"], lo, hi, ctx["side_src"], ctx["side_dst"], cap
+                )
+        self._tick("region", region_mode, sp.t0, sp.t1)
         if cand is not None:
             metrics().histogram(
                 "repair_region_nodes", buckets=_COUNT_BUCKETS
@@ -1193,16 +1210,19 @@ class IncrementalCore:
         if cand is None:  # legacy trigger fired (region/ref modes only)
             return self._finish_repeel(ctx, hi)
 
-        t0 = time.perf_counter()
-        cand_deg = self.g.degrees_of(cand)
-        seed = np.minimum(
-            cand_deg.astype(np.int64), old[cand].astype(np.int64) + m_ins
-        )
-        seed = np.maximum(seed, 0).astype(np.int32)
-        self._tick("candidates", "gather", t0)
+        with obs.timed("repair.candidates", impl="gather",
+                       rows=len(cand)) as sp:
+            cand_deg = self.g.degrees_of(cand)
+            seed = np.minimum(
+                cand_deg.astype(np.int64), old[cand].astype(np.int64) + m_ins
+            )
+            seed = np.maximum(seed, 0).astype(np.int32)
+            if self._device():
+                cells = self._tier_plan(len(cand), cand_deg)[4]
+                sp.set(cells=cells)
+        self._tick("candidates", "gather", sp.t0, sp.t1)
 
         if self._device():
-            cells = self._tier_plan(len(cand), cand_deg)[4]
             budget = self.descend_budget if not _on_tpu() else 1 << 62
             if adaptive:
                 deg = self.g.degrees()
@@ -1235,17 +1255,17 @@ class IncrementalCore:
                 return self._recover_ref(ctx, hi)
             return self._resolve(ctx, margin, lo, hi, pending["cand"], res)
 
-        t0 = time.perf_counter()
-        new = self._descend(cand, seed)
-        # a changed node's old level sits within the *deepest per-node
-        # cascade* of the block's endpoint levels, so the window is
-        # sufficient as long as the margin exceeds the largest single-node
-        # level change
-        max_gain = int(np.maximum(new - old[cand], 0).max(initial=0))
-        max_loss = int(np.maximum(old[cand] - new, 0).max(initial=0))
-        ceil_hit = bool(((new > hi) & (new > old[cand])).any())
-        floor_hit = bool(((new < lo) & (new < old[cand])).any())
-        self._tick("descend", "host", t0)
+        with obs.timed("repair.descend", impl="host", rows=len(cand)) as sp:
+            new = self._descend(cand, seed)
+            # a changed node's old level sits within the *deepest per-node
+            # cascade* of the block's endpoint levels, so the window is
+            # sufficient as long as the margin exceeds the largest
+            # single-node level change
+            max_gain = int(np.maximum(new - old[cand], 0).max(initial=0))
+            max_loss = int(np.maximum(old[cand] - new, 0).max(initial=0))
+            ceil_hit = bool(((new > hi) & (new > old[cand])).any())
+            floor_hit = bool(((new < lo) & (new < old[cand])).any())
+        self._tick("descend", "host", sp.t0, sp.t1)
         return self._resolve(
             ctx, margin, lo, hi, cand,
             (new, max_gain, max_loss, ceil_hit, floor_hit),

@@ -518,172 +518,188 @@ class EmbeddingService:
         scatter of resolved cold rows all run inside one jitted dispatch
         (``_fused_wb_fn``). The host's only jobs are slot reservation
         before the dispatch and metadata commit after the readback.
+
+        The ``serve.flush`` span's children partition it: ``flush.stage``
+        (slot map, compile check, promotion, slot reservation, the device
+        arguments), ``flush.wait`` (dispatch, blocking sync, readback),
+        ``flush.commit`` (write-back bookkeeping, spill overlay, stats) and,
+        once every retry has failed, ``flush.degraded``.
         """
         t0 = time.perf_counter()
-        sp = obs.span("serve.flush", batch=self.batch).__enter__()
-        st = self.store
-        sentinel = self.graph.node_cap
-        # align the slot map with the graph's id space up front so its device
-        # shape only changes when the graph grows (O(log n) jit recompiles).
-        # Padding travels as -1 and is masked here — a sentinel snapshotted
-        # at enqueue time could alias a node id minted by later growth
-        st.ensure_nodes(sentinel)
-        real = (nodes >= 0) & (nodes < sentinel)
-        nodes_c = np.where(real, nodes, sentinel)
-        self._compile_fused(sentinel)
-        degraded_batch = False
-        wb_slots_u = None
-        for attempt in range(self.flush_retries + 1):
-            try:
-                # spill-tier rows must answer queries directly (capacity <
-                # working set must never thrash real embeddings into
-                # cold-start means): restore what fits, overlay the rest
-                st.promote(nodes_c)
-                slots = st.slots_of(nodes_c)
-                resident = slots < st.capacity
-                bounced = {}  # row -> spilled vec served host-side
-                if st.spilled:
-                    for i in np.where(real & ~resident)[0]:
-                        hit = st.peek_spill(int(nodes_c[i]))
-                        if hit is not None:
-                            bounced[int(i)] = hit
-                st.note_fused_gather(slots, resident, len(bounced))
-                found = resident.copy()
-                if bounced:
-                    found[list(bounced)] = True
-                cold = real & ~found
-                # cold-start means must see every *embedded* neighbour,
-                # including rows currently spilled to host
-                if cold.any() and st.spilled:
-                    nbrs = np.concatenate(
-                        [self.graph.neighbours(int(v))
-                         for v in nodes_c[cold]]
-                    )
-                    st.promote(nbrs)
-                # dedup within the batch: duplicate cold ids share one
-                # reserved slot (and later count as one cold start)
-                uniq_cold, first_pos = np.unique(
-                    nodes_c[cold], return_index=True
-                )
-                if self.write_back and len(uniq_cold):
-                    wb_slots_u = st.reserve_slots(len(uniq_cold))
-                wb_slots = np.full(len(nodes), st.capacity, np.int32)
-                if wb_slots_u is not None:
-                    slot_of_cold = dict(
-                        zip(uniq_cold.tolist(), wb_slots_u.tolist())
-                    )
-                    for i in np.where(cold)[0]:
-                        wb_slots[i] = slot_of_cold[int(nodes_c[i])]
-
-                ell = self.graph.ell()
-                faults.check("flush_dispatch")
-                args = (
-                    jnp.asarray(nodes_c),
-                    ell.neighbours,
-                    st.slot_table_dev(),
-                    st.table(),
-                    jnp.int32(sentinel),
-                    jnp.int32(st.capacity),
-                    jnp.asarray(found),
-                )
-                if wb_slots_u is not None:
-                    out, resolved, table2 = self._fused_wb_fn(
-                        *args, jnp.asarray(wb_slots)
-                    )
-                else:  # nothing to scatter: skip the table write entirely
-                    out, resolved = self._fused_ro_fn(*args)
-                wd = self._watchdog
-                if wd is not None:
-                    wd.arm()
+        with obs.span("serve.flush", batch=self.batch) as sp:
+            st = self.store
+            sentinel = self.graph.node_cap
+            with obs.span("flush.stage"):
+                # align the slot map with the graph's id space up front so
+                # its device shape only changes when the graph grows
+                # (O(log n) jit recompiles). Padding travels as -1 and is
+                # masked here — a sentinel snapshotted at enqueue time could
+                # alias a node id minted by later growth
+                st.ensure_nodes(sentinel)
+                real = (nodes >= 0) & (nodes < sentinel)
+                nodes_c = np.where(real, nodes, sentinel)
+                self._compile_fused(sentinel)
+            degraded_batch = False
+            wb_slots_u = None
+            for attempt in range(self.flush_retries + 1):
                 try:
-                    out = np.asarray(out)  # the blocking device sync
-                finally:
-                    if wd is not None:
-                        wd.disarm()
-                resolved = np.asarray(resolved)
-                # commit the scattered rows: adopt the post-scatter table,
-                # tag versions/cores, return unresolved slots to the pool
-                if wb_slots_u is not None:
-                    cold_rows = np.where(cold)[0][first_pos]
-                    ok = resolved[cold_rows]
-                    st.adopt_fused(
-                        table2, uniq_cold[ok], wb_slots_u[ok],
-                        self._wb_cores(uniq_cold[ok]),
-                    )
-                    if (~ok).any():
-                        st.release_slots(wb_slots_u[~ok])
-                elif self.write_back and len(uniq_cold):
-                    # free list could not cover the batch: evicting
-                    # write-back through put_many (host readback path)
-                    cold_rows = np.where(cold)[0][first_pos]
-                    ok = resolved[cold_rows]
-                    if ok.any():
-                        st.put_many(
-                            uniq_cold[ok], out[cold_rows[ok]],
-                            self._wb_cores(uniq_cold[ok]),
+                    with obs.span("flush.stage"):
+                        # spill-tier rows must answer queries directly
+                        # (capacity < working set must never thrash real
+                        # embeddings into cold-start means): restore what
+                        # fits, overlay the rest
+                        st.promote(nodes_c)
+                        slots = st.slots_of(nodes_c)
+                        resident = slots < st.capacity
+                        bounced = {}  # row -> spilled vec served host-side
+                        if st.spilled:
+                            for i in np.where(real & ~resident)[0]:
+                                hit = st.peek_spill(int(nodes_c[i]))
+                                if hit is not None:
+                                    bounced[int(i)] = hit
+                        st.note_fused_gather(slots, resident, len(bounced))
+                        found = resident.copy()
+                        if bounced:
+                            found[list(bounced)] = True
+                        cold = real & ~found
+                        # cold-start means must see every *embedded*
+                        # neighbour, including rows currently spilled to host
+                        if cold.any() and st.spilled:
+                            nbrs = np.concatenate(
+                                [self.graph.neighbours(int(v))
+                                 for v in nodes_c[cold]]
+                            )
+                            st.promote(nbrs)
+                        # dedup within the batch: duplicate cold ids share
+                        # one reserved slot (and later count as one cold
+                        # start)
+                        uniq_cold, first_pos = np.unique(
+                            nodes_c[cold], return_index=True
                         )
-                for i, vec in bounced.items():  # spill-tier overlay
-                    out[i] = vec
-                if self.degraded:  # a healthy flush clears degraded mode
-                    self.degraded = False
-                    metrics().gauge("serve_degraded").set(0)
-                break
-            except Exception:
-                metrics().counter("serve_flush_failures_total").inc()
-                if wb_slots_u is not None:  # undo the reservation exactly
-                    st.release_slots(wb_slots_u)
-                    wb_slots_u = None
-                if attempt < self.flush_retries:
-                    time.sleep(self.retry_backoff * (2 ** attempt))
-                    continue
-                # degraded serving: answer from whatever rows both store
-                # tiers already hold (side-effect free peek — no promote,
-                # no device dispatch), cold starts stay unresolved
-                vecs, found, _, _ = self.store.peek_many(nodes_c)
-                cold = real & ~found
-                uniq_cold = np.unique(nodes_c[cold])
-                out = np.asarray(vecs, np.float32).copy()
-                resolved = np.zeros(len(nodes), bool)
-                degraded_batch = True
-                if not self.degraded:
-                    self.degraded = True
-                    metrics().gauge("serve_degraded").set(1)
+                        if self.write_back and len(uniq_cold):
+                            wb_slots_u = st.reserve_slots(len(uniq_cold))
+                        wb_slots = np.full(len(nodes), st.capacity, np.int32)
+                        if wb_slots_u is not None:
+                            slot_of_cold = dict(
+                                zip(uniq_cold.tolist(), wb_slots_u.tolist())
+                            )
+                            for i in np.where(cold)[0]:
+                                wb_slots[i] = slot_of_cold[int(nodes_c[i])]
 
-        n_real = int(real.sum())
-        n_hits = int((real & found).sum())
-        # duplicates within one batch are one cold start, not several
-        n_cold = int(len(uniq_cold))
-        if len(uniq_cold):
-            uniq_resolved = resolved[
-                np.where(cold)[0][
-                    np.unique(nodes_c[cold], return_index=True)[1]
-                ]
-            ]
-            n_unresolved = int((~uniq_resolved).sum())
-        else:
-            n_unresolved = 0
-        self.stats.queries += n_real
-        self.stats.store_hits += n_hits
-        self.stats.cold_starts += n_cold
-        self.stats.unresolved += n_unresolved
-        reg = metrics()
-        if degraded_batch:
-            self.stats.degraded_queries += n_real
-            reg.counter("serve_degraded_queries_total").inc(n_real)
-        reg.counter("serve_queries_total").inc(n_real)
-        reg.counter("serve_store_hits_total").inc(n_hits)
-        reg.counter("serve_cold_starts_total").inc(n_cold)
-        reg.counter("serve_unresolved_total").inc(n_unresolved)
-        self.stats.flushes += 1
-        dt = time.perf_counter() - t0
-        self.stats.flush_seconds.observe(dt)
-        if self._slo is not None:
-            self._slo.observe("flush_latency", dt)
-            self._slo.observe(
-                "degraded_serving", 1.0 if degraded_batch else 0.0
-            )
-        sp.set(hits=n_hits, cold=n_cold, unresolved=n_unresolved)
-        sp.__exit__(None, None, None)
+                        ell = self.graph.ell()
+                        faults.check("flush_dispatch")
+                        args = (
+                            jnp.asarray(nodes_c),
+                            ell.neighbours,
+                            st.slot_table_dev(),
+                            st.table(),
+                            jnp.int32(sentinel),
+                            jnp.int32(st.capacity),
+                            jnp.asarray(found),
+                        )
+                    with obs.span("flush.wait"):
+                        if wb_slots_u is not None:
+                            out, resolved, table2 = self._fused_wb_fn(
+                                *args, jnp.asarray(wb_slots)
+                            )
+                        else:  # nothing to scatter: skip the table write
+                            out, resolved = self._fused_ro_fn(*args)
+                        wd = self._watchdog
+                        if wd is not None:
+                            wd.arm()
+                        try:
+                            out = np.asarray(out)  # the blocking device sync
+                        finally:
+                            if wd is not None:
+                                wd.disarm()
+                        resolved = np.asarray(resolved)
+                    with obs.span("flush.commit"):
+                        # commit the scattered rows: adopt the post-scatter
+                        # table, tag versions/cores, return unresolved slots
+                        # to the pool
+                        if wb_slots_u is not None:
+                            cold_rows = np.where(cold)[0][first_pos]
+                            ok = resolved[cold_rows]
+                            st.adopt_fused(
+                                table2, uniq_cold[ok], wb_slots_u[ok],
+                                self._wb_cores(uniq_cold[ok]),
+                            )
+                            if (~ok).any():
+                                st.release_slots(wb_slots_u[~ok])
+                        elif self.write_back and len(uniq_cold):
+                            # free list could not cover the batch: evicting
+                            # write-back through put_many (host readback
+                            # path)
+                            cold_rows = np.where(cold)[0][first_pos]
+                            ok = resolved[cold_rows]
+                            if ok.any():
+                                st.put_many(
+                                    uniq_cold[ok], out[cold_rows[ok]],
+                                    self._wb_cores(uniq_cold[ok]),
+                                )
+                        for i, vec in bounced.items():  # spill-tier overlay
+                            out[i] = vec
+                        if self.degraded:  # a healthy flush clears degraded
+                            self.degraded = False
+                            metrics().gauge("serve_degraded").set(0)
+                    break
+                except Exception:
+                    metrics().counter("serve_flush_failures_total").inc()
+                    if wb_slots_u is not None:  # undo the reservation exactly
+                        st.release_slots(wb_slots_u)
+                        wb_slots_u = None
+                    if attempt < self.flush_retries:
+                        time.sleep(self.retry_backoff * (2 ** attempt))
+                        continue
+                    # degraded serving: answer from whatever rows both store
+                    # tiers already hold (side-effect free peek — no promote,
+                    # no device dispatch), cold starts stay unresolved
+                    with obs.span("flush.degraded"):
+                        vecs, found, _, _ = self.store.peek_many(nodes_c)
+                        cold = real & ~found
+                        uniq_cold = np.unique(nodes_c[cold])
+                        out = np.asarray(vecs, np.float32).copy()
+                        resolved = np.zeros(len(nodes), bool)
+                        degraded_batch = True
+                        if not self.degraded:
+                            self.degraded = True
+                            metrics().gauge("serve_degraded").set(1)
+
+            with obs.span("flush.commit"):
+                n_real = int(real.sum())
+                n_hits = int((real & found).sum())
+                # duplicates within one batch are one cold start, not several
+                n_cold = int(len(uniq_cold))
+                if len(uniq_cold):
+                    uniq_resolved = resolved[
+                        np.where(cold)[0][
+                            np.unique(nodes_c[cold], return_index=True)[1]
+                        ]
+                    ]
+                    n_unresolved = int((~uniq_resolved).sum())
+                else:
+                    n_unresolved = 0
+                self.stats.queries += n_real
+                self.stats.store_hits += n_hits
+                self.stats.cold_starts += n_cold
+                self.stats.unresolved += n_unresolved
+                reg = metrics()
+                if degraded_batch:
+                    self.stats.degraded_queries += n_real
+                    reg.counter("serve_degraded_queries_total").inc(n_real)
+                reg.counter("serve_queries_total").inc(n_real)
+                reg.counter("serve_store_hits_total").inc(n_hits)
+                reg.counter("serve_cold_starts_total").inc(n_cold)
+                reg.counter("serve_unresolved_total").inc(n_unresolved)
+                self.stats.flushes += 1
+                dt = time.perf_counter() - t0
+                self.stats.flush_seconds.observe(dt)
+                if self._slo is not None:
+                    self._slo.observe("flush_latency", dt)
+                    self._slo.observe(
+                        "degraded_serving", 1.0 if degraded_batch else 0.0
+                    )
+            sp.set(hits=n_hits, cold=n_cold, unresolved=n_unresolved)
         return out
 
     def flush(self) -> np.ndarray:
@@ -730,9 +746,13 @@ class EmbeddingService:
         pairs = np.asarray(pairs, np.int64)
         flat = pairs.reshape(-1)
         uniq, inv = np.unique(flat, return_inverse=True)
-        emb = np.asarray(ops.normalize_rows(jnp.asarray(self.embed(uniq))))
-        e = emb[inv]
-        return np.sum(e[0::2] * e[1::2], axis=1)
+        with obs.span("serve.link", pairs=len(pairs), uniq=len(uniq)):
+            vecs = self.embed(uniq)
+            with obs.span("link.score"):
+                emb = np.asarray(ops.normalize_rows(jnp.asarray(vecs)))
+                e = emb[inv]
+                scores = np.sum(e[0::2] * e[1::2], axis=1)
+        return scores
 
     def top_k_neighbors(
         self, nodes: Sequence[int], k: int
@@ -759,38 +779,41 @@ class EmbeddingService:
         with obs.span("serve.topk", batch=len(nodes), k=k) as sp:
             qv = self.embed(nodes)  # resolves cold starts + write-back
             st = self.store
-            qn = ops.normalize_rows(jnp.asarray(qv))
-            tn = ops.normalize_rows(st.table())
+            with obs.span("topk.prep"):
+                qn = ops.normalize_rows(jnp.asarray(qv))
+                tn = ops.normalize_rows(st.table())
+                valid = jnp.asarray(st.row_valid())
             # ask for k+1 candidates: the query's own row (when resident)
             # is dropped host-side, leaving a full k for everyone
             kk = k + 1
-            if st.plan is None:
-                vals, idx = ops.top_k_scores(
-                    qn, tn, kk, valid=jnp.asarray(st.row_valid()),
-                    impl=self.impl,
+            with obs.span("topk.wait"):
+                if st.plan is None:
+                    vals, idx = ops.top_k_scores(
+                        qn, tn, kk, valid=valid, impl=self.impl,
+                    )
+                    vals = np.asarray(vals)
+                    idx = np.asarray(idx, np.int64)
+                else:
+                    pv, pi = st.plan.partial_topk_fn(
+                        qn, tn, valid, kk, self.impl,
+                    )
+                    vals, idx = st.plan.merge_topk(pv, pi, kk)
+            with obs.span("topk.merge"):
+                own = st.slots_of(nodes).astype(np.int64)  # capacity = absent
+                keep = (idx >= 0) & (idx != own[:, None])
+                order = np.argsort(~keep, axis=1, kind="stable")
+                sel = np.take_along_axis(idx, order, 1)[:, :k]
+                sval = np.take_along_axis(vals, order, 1)[:, :k]
+                kept = np.take_along_axis(keep, order, 1)[:, :k]
+                ids = np.where(
+                    kept, st.node_of_slots(np.maximum(sel, 0)), -1
                 )
-                vals = np.asarray(vals)
-                idx = np.asarray(idx, np.int64)
-            else:
-                pv, pi = st.plan.partial_topk_fn(
-                    qn, tn, jnp.asarray(st.row_valid()), kk, self.impl,
-                )
-                vals, idx = st.plan.merge_topk(pv, pi, kk)
-            own = st.slots_of(nodes).astype(np.int64)  # capacity = absent
-            keep = (idx >= 0) & (idx != own[:, None])
-            order = np.argsort(~keep, axis=1, kind="stable")
-            sel = np.take_along_axis(idx, order, 1)[:, :k]
-            sval = np.take_along_axis(vals, order, 1)[:, :k]
-            kept = np.take_along_axis(keep, order, 1)[:, :k]
-            ids = np.where(
-                kept, st.node_of_slots(np.maximum(sel, 0)), -1
-            )
-            scores = np.where(kept, sval, -np.inf).astype(np.float32)
-            self.stats.topk_queries += len(nodes)
-            dt = time.perf_counter() - t0
-            self.stats.topk_seconds.observe(dt)
-            reg = metrics()
-            reg.counter("serve_topk_queries_total").inc(len(nodes))
+                scores = np.where(kept, sval, -np.inf).astype(np.float32)
+                self.stats.topk_queries += len(nodes)
+                dt = time.perf_counter() - t0
+                self.stats.topk_seconds.observe(dt)
+                reg = metrics()
+                reg.counter("serve_topk_queries_total").inc(len(nodes))
             sp.set(candidates=int(st.resident))
         return ids, scores
 

@@ -299,28 +299,22 @@ class EmbeddingStore:
         :meth:`gather` would have recorded for the same request."""
         slots = np.asarray(slots)
         resident = np.asarray(resident, bool)
-        # the row movement itself happened inside the fused device program;
-        # this span marks the gather in the trace (fused=1) so pipeline-
-        # coverage checks keep seeing the stage, with the same attributes
-        # the host-side gather() recorded
-        with obs.span("store.gather", batch=len(slots), fused=1) as sp:
-            if resident.any():
-                self._last_used[slots[resident]] = self._tick()
-            if self.plan is not None:
-                self.shard_gather_rows += self.plan.balance_of(
-                    slots[resident], self._rows
-                )
-                self.cross_shard_row_copies += int(resident.sum()) * (
-                    self.plan.n_shards - 1
-                )
-            reg = metrics()
-            reg.counter("store_gather_requests_total").inc(len(slots))
-            reg.counter("store_gather_found_total").inc(
-                int(resident.sum()) + int(spill_served)
+        if resident.any():
+            self._last_used[slots[resident]] = self._tick()
+        if self.plan is not None:
+            self.shard_gather_rows += self.plan.balance_of(
+                slots[resident], self._rows
             )
-            if spill_served:
-                reg.counter("store_spill_serves_total").inc(int(spill_served))
-            sp.set(found=int(resident.sum()) + int(spill_served))
+            self.cross_shard_row_copies += int(resident.sum()) * (
+                self.plan.n_shards - 1
+            )
+        reg = metrics()
+        reg.counter("store_gather_requests_total").inc(len(slots))
+        reg.counter("store_gather_found_total").inc(
+            int(resident.sum()) + int(spill_served)
+        )
+        if spill_served:
+            reg.counter("store_spill_serves_total").inc(int(spill_served))
 
     def peek_spill(self, node: int) -> Optional[np.ndarray]:
         """Spill-tier vector for ``node`` (None if not spilled); no side

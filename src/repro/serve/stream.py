@@ -380,39 +380,43 @@ class DynamicGraph:
         with obs.span(
             "graph.compact", overflow_arcs=self.overflow_arcs
         ) as sp:
-            deg = self.degrees()
-            max_deg = int(deg.max()) if deg.size else 0
-            # a power of two: the device programs over the mirror recompile
-            # per width, so widths that track the max degree exactly would
-            # recompile on nearly every compaction
-            width = pow2(max(int(np.ceil(max_deg * self.slack)), min_width))
-            nbr = np.full(
-                (self.node_cap + 1, width), self.node_cap, np.int32
-            )
-            n = self.n_nodes
-            # gather all arcs: in-table rows (row-major mask flatten) +
-            # overflow
-            rows, dsts = self.arc_arrays()
-            order = np.lexsort((dsts, rows))  # sorted rows, like Graph CSR
-            rows, dsts = rows[order], dsts[order]
-            uniq, start, counts = np.unique(
-                rows, return_index=True, return_counts=True
-            )
-            slot = np.arange(len(rows)) - np.repeat(start, counts)
-            nbr[rows, slot] = dsts
-            new_deg = np.zeros(self.node_cap + 1, np.int32)
-            new_deg[:n] = deg
-            # dispatch the device upload of the side buffer *before* the swap
-            dev_nbr, dev_deg = self._upload_mirror(nbr, new_deg)
-            self._nbr, self._deg, self.width = nbr, new_deg, width
-            self._dev_nbr, self._dev_deg = dev_nbr, dev_deg
-            self._overflow.clear()
-            self._pending.clear()
-            self._dirty_full = False
-            self.compactions += 1
-            self.edges_since_compact = 0
+            with obs.span("compact.build"):
+                deg = self.degrees()
+                max_deg = int(deg.max()) if deg.size else 0
+                # a power of two: the device programs over the mirror
+                # recompile per width, so widths that track the max degree
+                # exactly would recompile on nearly every compaction
+                width = pow2(max(int(np.ceil(max_deg * self.slack)),
+                                 min_width))
+                nbr = np.full(
+                    (self.node_cap + 1, width), self.node_cap, np.int32
+                )
+                n = self.n_nodes
+                # gather all arcs: in-table rows (row-major mask flatten) +
+                # overflow
+                rows, dsts = self.arc_arrays()
+                order = np.lexsort((dsts, rows))  # sorted rows, like CSR
+                rows, dsts = rows[order], dsts[order]
+                uniq, start, counts = np.unique(
+                    rows, return_index=True, return_counts=True
+                )
+                slot = np.arange(len(rows)) - np.repeat(start, counts)
+                nbr[rows, slot] = dsts
+                new_deg = np.zeros(self.node_cap + 1, np.int32)
+                new_deg[:n] = deg
+            with obs.span("compact.upload"):
+                # dispatch the device upload of the side buffer *before*
+                # the swap
+                dev_nbr, dev_deg = self._upload_mirror(nbr, new_deg)
+                self._nbr, self._deg, self.width = nbr, new_deg, width
+                self._dev_nbr, self._dev_deg = dev_nbr, dev_deg
+                self._overflow.clear()
+                self._pending.clear()
+                self._dirty_full = False
+                self.compactions += 1
+                self.edges_since_compact = 0
+                metrics().counter("graph_compactions_total").inc()
             sp.set(width=width)
-            metrics().counter("graph_compactions_total").inc()
 
     # --------------------------------------------------------- device mirror
 

@@ -362,3 +362,229 @@ def test_chrome_export_nests_under_pipelined_ingest(default_tracer, tmp_path):
                 f"span {e['name']} [{t0}, {t1}] half-overlaps an "
                 f"enclosing span ending at {stack[-1]}")
             stack.append(t1)
+
+
+# ------------------------------------------------------------ ids and parents
+
+
+def test_spans_carry_ids_and_parents():
+    t = Tracer(enabled=True, clock=FakeClock())
+    with t.span("outer") as outer:
+        with t.span("inner") as inner:
+            t.record("pretimed", 2.5, 2.6)  # takes the innermost open span
+        t.record("sibling", 3.5, 3.6)
+    t.record("top", 10.0, 11.0)
+    by_name = {e["name"]: e for e in t.events}
+    assert by_name["outer"]["parent"] is None
+    assert by_name["inner"]["parent"] == outer.id
+    assert by_name["pretimed"]["parent"] == inner.id
+    assert by_name["pretimed"]["depth"] == 2
+    assert by_name["sibling"]["parent"] == outer.id
+    assert by_name["top"]["parent"] is None
+    ids = [e["id"] for e in t.events]
+    assert len(set(ids)) == len(ids)
+    assert by_name["outer"]["id"] == outer.id
+
+
+def test_exports_carry_parent(tmp_path):
+    t = Tracer(enabled=True, clock=FakeClock())
+    with t.span("a") as a:
+        with t.span("b"):
+            pass
+    t.export_jsonl(str(tmp_path / "s.jsonl"))
+    lines = [json.loads(l)
+             for l in (tmp_path / "s.jsonl").read_text().splitlines()]
+    assert [(l["name"], l["parent"]) for l in lines] == \
+        [("b", a.id), ("a", None)]
+    t.export_chrome(str(tmp_path / "t.json"))
+    ev = {e["name"]: e for e in
+          json.loads((tmp_path / "t.json").read_text())["traceEvents"]}
+    assert ev["b"]["args"]["parent"] == ev["a"]["args"]["id"] == a.id
+    assert ev["a"]["args"]["parent"] is None
+
+
+def test_self_time_is_duration_less_children():
+    # fake clock: outer [1, 8], children [2, 3] and [4, 7] -> self 3
+    t = Tracer(enabled=True, clock=FakeClock())
+    with t.span("outer") as outer:
+        with t.span("c1"):
+            pass
+        with t.span("c2"):
+            with t.span("grandchild"):
+                pass
+    by_name = {e["name"]: e for e in t.events}
+    kids = [e for e in t.events if e["parent"] == outer.id]
+    assert {e["name"] for e in kids} == {"c1", "c2"}
+    own = by_name["outer"]["dur"] - sum(e["dur"] for e in kids)
+    assert own == 3.0
+
+
+def test_timed_span_is_timed_either_way():
+    clock = FakeClock()
+    off = Tracer(enabled=False, clock=clock)
+    with off.timed("repair.region", impl="np") as sp:
+        sp.set(rows=3)
+    assert sp.t1 >= sp.t0 > 0.0  # timed on perf_counter
+    assert sp.attrs == {"impl": "np", "rows": 3}
+    assert clock.reads == 0 and off.events == []
+    on = Tracer(enabled=True, clock=FakeClock())
+    with on.timed("repair.region", impl="np") as sp:
+        pass
+    assert (sp.t0, sp.t1) == (1.0, 2.0)
+    assert on.events[0]["name"] == "repair.region"
+    assert on.events[0]["attrs"] == {"impl": "np"}
+
+
+# ----------------------------------------------------------- profiler mirror
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation`` and logs its use."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+
+        return Ann()
+
+
+def test_span_mirrors_a_profiler_annotation(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(obs, "_TraceAnnotation", ann)
+    t = Tracer(enabled=True, clock=FakeClock())
+    with t.span("a"):
+        with pytest.raises(RuntimeError):
+            with t.span("b"):
+                raise RuntimeError("x")
+    # the annotation exits on the exception too
+    assert ann.log == [("enter", "a"), ("enter", "b"), ("exit", "b"),
+                       ("exit", "a")]
+    assert [e["name"] for e in t.events] == ["b", "a"]
+
+
+# ------------------------------------------------------------- compile spans
+
+
+def test_compile_spans_only_while_enabled(default_tracer):
+    np = pytest.importorskip("numpy")
+    import jax
+    import jax.numpy as jnp
+
+    t = obs.enable(exemplar_watch=())
+    # a constant drawn per run keeps the program out of any compile cache
+    scale = float(np.random.default_rng().integers(1, 1 << 30))
+
+    def scaled(x):
+        return x * scale + 1.0
+
+    f = jax.jit(scaled)
+    with t.span("outer") as outer:
+        f(jnp.ones(3)).block_until_ready()
+    comp = [e for e in t.events if e["name"] == "jit.compile"]
+    assert {"trace", "lower"} <= {e["attrs"]["stage"] for e in comp}
+    assert all(e["parent"] == outer.id for e in comp)
+    assert any(e["attrs"]["fun"] == "scaled" for e in comp)
+    out = t.events[-1]
+    assert out["name"] == "outer"
+    for e in comp:  # they end inside the span that was open
+        assert out["ts"] <= e["ts"] + e["dur"] <= out["ts"] + out["dur"]
+        assert e["dur"] >= 0.0
+    obs.disable()
+    n = len(t.events)
+    f(jnp.ones(5)).block_until_ready()  # a new shape compiles again
+    assert len(t.events) == n
+
+
+# ------------------------------------------- disabled serving path = no work
+
+
+def _small_service():
+    np = pytest.importorskip("numpy")
+    from repro.graph import generators
+    from repro.serve import (DynamicGraph, EmbeddingService, EmbeddingStore,
+                             IncrementalCore)
+
+    g = generators.barabasi_albert_varying(120, 4.0, seed=3)
+    dyn = DynamicGraph(g.n_nodes, g.edge_list(), width=4)
+    inc = IncrementalCore(dyn)
+    store = EmbeddingStore(capacity=g.n_nodes, dim=8, node_cap=dyn.node_cap)
+    emb = np.random.default_rng(0).normal(size=(g.n_nodes, 8))
+    stored = np.arange(0, g.n_nodes, 2)  # odd nodes cold-start
+    store.put_many(stored, emb[stored].astype(np.float32),
+                   inc.core[stored])
+    return EmbeddingService(dyn, inc, store, batch=16)
+
+
+def _serve(svc, rounds=1):
+    np = pytest.importorskip("numpy")
+    rng = np.random.default_rng(1)
+    n = svc.graph.n_nodes
+    for _ in range(rounds):
+        svc.embed(rng.integers(0, n, size=24))
+        svc.link_scores(rng.integers(0, n, size=(6, 2)))
+        svc.top_k_neighbors(rng.integers(0, n, size=8), 5)
+        block = rng.integers(0, n, size=(12, 2))
+        svc.ingest_block(block[block[:, 0] != block[:, 1]])
+        svc.sync()
+        svc.graph.compact()
+
+
+def test_disabled_serving_path_is_zero_work(default_tracer, monkeypatch):
+    """Disabled tracing over flush, link, top-k, ingest and compaction: no
+    clock read, no profiler annotation, and a listener that returns at
+    once."""
+    svc = _small_service()
+    _serve(svc)  # compiles outside the check
+    clock = FakeClock()
+    off = obs.set_tracer(Tracer(enabled=False, clock=clock))
+    ann = _Annotations()
+    monkeypatch.setattr(obs, "_TraceAnnotation", ann)
+    _serve(svc)
+    obs._on_compile_event("/jax/core/compile/backend_compile_duration",
+                          1.5, fun_name="f")
+    assert clock.reads == 0
+    assert ann.log == []
+    assert off.events == []
+
+
+def test_program_spans_land_in_the_profiler_trace(default_tracer, tmp_path):
+    """Under a profiler session the program's spans are in the host trace,
+    and each start, mapped by one offset taken at a ``bench.window``
+    annotation, lies within 1 ms of the tracer's own."""
+    import time
+
+    import jax
+
+    from benchmarks.chip.trace_reduce import find_xplane, load_xplane
+
+    svc = _small_service()
+    _serve(svc)  # compiles outside the profile
+    t = obs.enable(exemplar_watch=())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t_window = time.perf_counter()
+        with jax.profiler.TraceAnnotation("bench.window"):
+            _serve(svc, rounds=2)
+    finally:
+        jax.profiler.stop_trace()
+        obs.disable()
+    path = find_xplane(str(tmp_path))
+    mark = [s for s in load_xplane(path).spans if s.name == "bench.window"]
+    offset = mark[-1].start - t_window  # the harness's one-offset rule
+    profiled = load_xplane(path, prefix="serve.").spans
+    ours = sorted((e for e in t.events if e["name"].startswith("serve.")),
+                  key=lambda e: e["ts"])
+    assert {"serve.flush", "serve.link", "serve.topk", "serve.ingest"} <= \
+        {s.name for s in profiled}
+    assert [s.name for s in profiled] == [e["name"] for e in ours]
+    for s, e in zip(profiled, ours):
+        assert abs(s.start - offset - e["ts"]) < 1e-3, s.name

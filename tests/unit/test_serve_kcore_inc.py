@@ -293,3 +293,63 @@ def test_drift_and_membership_gate():
     assert inc.drift() > 0
     oracle = core_numbers_host(dyn.snapshot())
     np.testing.assert_array_equal(inc.core, oracle)
+
+
+@pytest.mark.parametrize("kw", [
+    {},
+    {"repair_policy": "fallback", "repeel_impl": "descend"},
+], ids=["adaptive", "repeel-by-descent"])
+def test_repair_spans_are_the_phase_intervals(stream_case, kw):
+    """phase_report(), repair_phase_seconds, the repair policy and the trace
+    are fed by one interval per phase occurrence: that of its span."""
+    from repro.obs import MetricsRegistry, metrics, set_metrics
+    from repro.obs import trace as obs
+
+    prev_t, prev_m = obs.tracer(), metrics()
+    t = obs.enable(exemplar_watch=())
+    reg = set_metrics(MetricsRegistry())
+    try:
+        g, edges, dyn, inc = stream_case(
+            lambda: generators.barabasi_albert(120, 3, seed=5), seed=2,
+            **kw)
+        seen = []
+        observe = inc.policy.observe
+        inc.policy.observe = lambda path, work, s: (
+            seen.append((path, s)), observe(path, work, s))
+        for start in range(0, len(edges), 48):
+            inc.on_edge_block(dyn.add_edges(edges[start:start + 48]))
+        np.testing.assert_array_equal(
+            inc.core, core_numbers_host(dyn.snapshot()))
+    finally:
+        obs.set_tracer(prev_t)
+        set_metrics(prev_m)
+    by_id = {e["id"]: e for e in t.events}
+
+    def in_repeel(e):
+        while e["parent"] is not None:
+            e = by_id[e["parent"]]
+            if e["name"] == "repair.fallback":
+                return True
+        return False
+
+    phases = [e for e in t.events if e["name"].startswith("repair.")]
+    report = inc.phase_report()
+    assert report
+    for phase in ("region", "candidates", "descend", "fallback"):
+        spans = [e for e in phases if e["name"] == f"repair.{phase}"]
+        outside = sum(e["dur"] for e in spans if not in_repeel(e))
+        assert report.get(phase, {"seconds": 0.0})["seconds"] == \
+            pytest.approx(outside, abs=2e-6)
+        h = reg.get("repair_phase_seconds", phase=phase)
+        assert (h.sum if h is not None else 0.0) == \
+            pytest.approx(sum(e["dur"] for e in spans), abs=1e-9)
+    assert [s for p, s in seen if p == "descend"] == pytest.approx(
+        [e["dur"] for e in phases if e["name"] == "repair.descend"])
+    assert [s for p, s in seen if p == "repeel"] == pytest.approx(
+        [e["dur"] for e in phases if e["name"] == "repair.fallback"])
+    if kw:  # a re-peel's own gather and descent nest in its span
+        assert report.keys() == {"fallback"}
+        nested = [e for e in phases if e["name"] != "repair.fallback"]
+        assert nested and all(
+            by_id[e["parent"]]["name"] == "repair.fallback" for e in nested)
+        assert all({"rows", "cells"} <= e["attrs"].keys() for e in nested)

@@ -331,3 +331,60 @@ def test_retrain_pressure_rises_with_membership_churn():
             svc.ingest(int(low[i]), int(low[j]))
     assert svc.retrain_pressure() > 0.0
     assert svc.should_retrain()
+
+
+@pytest.fixture(scope="module")
+def traced_serving():
+    """Spans of warm flushes, link and top-k calls and compactions."""
+    import gc
+
+    from repro.obs import trace as obs
+
+    g = generators.barabasi_albert_varying(150, 4.0, seed=11)
+    emb = np.random.default_rng(2).normal(size=(g.n_nodes, DIM))
+    svc = _service_from(g, np.arange(0, g.n_nodes, 2), emb.astype(np.float32))
+    rng = np.random.default_rng(3)
+
+    def serve():
+        svc.embed(rng.integers(0, g.n_nodes, size=40))
+        svc.link_scores(rng.integers(0, g.n_nodes, size=(8, 2)))
+        svc.top_k_neighbors(rng.integers(0, g.n_nodes, size=16), 5)
+        block = rng.integers(0, g.n_nodes, size=(64, 2))
+        svc.ingest_block(block[block[:, 0] != block[:, 1]])
+        svc.sync()
+        svc.graph.compact()
+
+    serve()  # compiles the shapes first
+    prev = obs.tracer()
+    t = obs.enable(exemplar_watch=())
+    gc.collect()
+    try:
+        for _ in range(6):
+            serve()
+    finally:
+        obs.set_tracer(prev)
+    return t.events
+
+
+@pytest.mark.parametrize("outer, children", [
+    ("serve.flush", {"flush.stage", "flush.wait", "flush.commit"}),
+    ("serve.topk", {"serve.flush", "topk.prep", "topk.wait", "topk.merge"}),
+    ("serve.link", {"serve.flush", "link.score"}),
+    ("graph.compact", {"compact.build", "compact.upload"}),
+])
+def test_child_spans_partition_their_parent(traced_serving, outer, children):
+    """The children of each outer span name it as their parent, lie inside
+    it, and cover at least 90% of its time, so an idle gap inside it gets
+    a specific name."""
+    events = traced_serving
+    outers = {e["id"]: e for e in events if e["name"] == outer}
+    assert len(outers) >= 6
+    kids = [e for e in events if e["parent"] in outers]
+    assert {e["name"] for e in kids} == children
+    covered = 0.0
+    for e in kids:
+        o = outers[e["parent"]]
+        assert o["ts"] <= e["ts"] and \
+            e["ts"] + e["dur"] <= o["ts"] + o["dur"]
+        covered += e["dur"]
+    assert covered >= 0.9 * sum(o["dur"] for o in outers.values())
