@@ -68,14 +68,17 @@ repair stage is vectorized or fused:
   mirror (``_region_fixpoint``); elsewhere the same traversal runs as
   vectorized numpy over the host table, where XLA scatters lose to the host.
   Both are bounded: discovery aborts early once it exceeds the fallback cap.
-* **Candidate matrices** come from one vectorized gather
-  (``DynamicGraph.gather_rows``), trimmed to the candidates' true max degree.
+* **Candidate matrices** are degree tiers: each row goes in the narrowest
+  width of a fixed ladder (32, 128, 512, then pow2 of the largest candidate
+  degree) that holds it, and each tier is one vectorized gather
+  (``DynamicGraph.gather_rows``) of only its own columns, so hub-width
+  padding is neither built nor swept for the many low-degree rows.
 * **The h-index descent is one fused jitted fixpoint** (``_fused_descent``):
-  seeding, every sweep, the convergence test, and the adaptive-window
-  boundary statistics all run inside a single ``lax.while_loop`` dispatch —
-  no per-iteration ``est[cand]`` ping-pong between host and device. Each
-  sweep applies ``kernels.ops.h_index_sweep`` (the Pallas kernel on TPU, the
-  sort-free counting search elsewhere).
+  seeding, every sweep over every tier, the convergence test, and the
+  adaptive-window boundary statistics all run inside a single
+  ``lax.while_loop`` dispatch — no per-iteration ``est[cand]`` ping-pong
+  between host and device. Each sweep applies ``kernels.ops.h_index_sweep``
+  (the Pallas kernel on TPU, the sort-free counting search elsewhere).
 * **The fallback** is the same fused descent seeded over *all* nodes on TPU
   (still one dispatch); off-TPU it is the vectorized rounds peel
   (``core_numbers_rounds``) fed straight from the graph's arc arrays.
@@ -97,6 +100,7 @@ from __future__ import annotations
 import time
 from collections import deque
 from functools import partial
+from itertools import accumulate, combinations
 from typing import Optional
 
 import jax
@@ -121,8 +125,12 @@ __all__ = ["IncrementalCore", "RepairPolicy"]
 
 _EMPTY = np.zeros((0, 2), np.int64)
 
-# two-tier descent split: rows with degree <= this go in the narrow matrix
+# narrow tier of the region traversal, and the first rung of the descent's
 _W_SMALL = 32
+
+# widths of the fused descent's tiers below its widest, which is pow2 of the
+# largest candidate degree: a row sweeps in the narrowest tier that holds it
+_LADDER = (_W_SMALL, 128, 512)
 
 # smallest padded side table of the device region traversal: padding arcs
 # are masked and near-free, and every distinct size is one more compile
@@ -137,20 +145,31 @@ def _on_tpu() -> bool:
 
 
 @partial(jax.jit, static_argnames=("impl", "max_sweeps"))
-def _fused_descent(idx, valid, cand, seed, old, est_full, lo, hi, *,
+def _fused_descent(tiers, cand, seed, old, est_full, lo, hi, *,
                    impl: str, max_sweeps: int):
     """Whole h-index descent as one device dispatch.
 
-    ``idx``/``valid``: (R, W) candidate neighbour matrix (global node ids,
-    padding = sentinel); ``cand``: (R,) candidate ids (padded rows point at
-    the sentinel, whose estimate stays 0); ``seed``: (R,) upper bound on the
-    new cores; ``old``: (R,) old cores (0 on padded rows); ``est_full``:
-    (node_cap + 1,) frozen boundary = current cores. Runs the row-masked
-    sweep to its fixed point inside one ``lax.while_loop`` and returns
-    ``(new, max_gain, max_loss, ceiling_hit, floor_hit, sweeps)`` — the
-    adaptive-window boundary statistics ride along so the caller reads back
-    five scalars plus the repaired levels, never per-sweep intermediates.
+    ``tiers``: tuple of ``(idx, valid)`` candidate neighbour matrices
+    (global node ids, padding = sentinel), each ``(R_t, W_t)``; ``cand``:
+    (R,) candidate ids of the concatenated rows in tier order (padded rows
+    point at the sentinel, whose estimate stays 0); ``seed``: (R,) upper
+    bound on the new cores; ``old``: (R,) old cores (0 on padded rows);
+    ``est_full``: (node_cap + 1,) frozen boundary = current cores. Runs the
+    row-masked sweep to its fixed point inside one ``lax.while_loop`` and
+    returns ``(new, max_gain, max_loss, ceiling_hit, floor_hit, sweeps,
+    truncated)`` — the adaptive-window boundary statistics ride along so
+    the caller reads back a few scalars plus the repaired levels, never
+    per-sweep intermediates.
+
+    One ELL matrix pays the hub tax: a few high-degree rows set its width
+    and most swept cells are padding. So the rows come split by degree into
+    tiers of growing width (``IncrementalCore._tier_plan``). Each sweep
+    applies the identical row operator to every tier against the shared
+    estimate of the previous sweep, so the fixpoint trajectory — and the
+    result — is bit-identical to the single-matrix descent, at a fraction
+    of the swept cells. The number of tiers is static.
     """
+    bounds = list(accumulate((idx.shape[0] for idx, _ in tiers), initial=0))
     est = est_full.at[cand].set(seed)
 
     def cond(state):
@@ -159,8 +178,10 @@ def _fused_descent(idx, valid, cand, seed, old, est_full, lo, hi, *,
 
     def body(state):
         est, cur, _, it = state
-        vals = est[idx]
-        new = kops.h_index_sweep(vals, valid, cur, impl=impl)
+        new = jnp.concatenate([
+            kops.h_index_sweep(est[idx], valid, cur[a:b], impl=impl)
+            for (idx, valid), a, b in zip(tiers, bounds[:-1], bounds[1:])
+        ])
         est = est.at[cand].set(new)
         return est, new, jnp.any(new != cur), it + 1
 
@@ -178,47 +199,6 @@ def _fused_descent(idx, valid, cand, seed, old, est_full, lo, hi, *,
     return new, gain, loss, ceiling, floor, sweeps, changed
 
 
-@partial(jax.jit, static_argnames=("impl", "max_sweeps"))
-def _fused_descent_two(idx_s, valid_s, idx_b, valid_b, cand, seed, old,
-                       est_full, lo, hi, *, impl: str, max_sweeps: int):
-    """Two-tier variant of :func:`_fused_descent`.
-
-    One ELL candidate matrix pays the hub tax: a handful of high-degree
-    rows force ``w_pad`` to 4-8x the typical degree, so most swept cells
-    are padding. Here the rows are split into a narrow matrix
-    (``idx_s``/``valid_s``, degree <= ``_W_SMALL``) and a small hub matrix
-    (``idx_b``/``valid_b``); ``cand``/``seed``/``old`` are the
-    concatenated per-row vectors in the same [small rows..., hub rows...]
-    order. Each sweep applies the identical row operator to both tiers
-    against the shared estimate, so the fixpoint trajectory — and the
-    result — is bit-identical to the single-matrix descent, at a fraction
-    of the swept cells.
-    """
-    r_s = idx_s.shape[0]
-    est = est_full.at[cand].set(seed)
-
-    def cond(state):
-        _, _, changed, it = state
-        return jnp.logical_and(changed, it < max_sweeps)
-
-    def body(state):
-        est, cur, _, it = state
-        new_s = kops.h_index_sweep(est[idx_s], valid_s, cur[:r_s], impl=impl)
-        new_b = kops.h_index_sweep(est[idx_b], valid_b, cur[r_s:], impl=impl)
-        new = jnp.concatenate([new_s, new_b])
-        est = est.at[cand].set(new)
-        return est, new, jnp.any(new != cur), it + 1
-
-    _, new, changed, sweeps = jax.lax.while_loop(
-        cond, body, (est, seed, jnp.asarray(True), jnp.asarray(0, jnp.int32))
-    )
-    gain = jnp.max(jnp.maximum(new - old, 0), initial=0)
-    loss = jnp.max(jnp.maximum(old - new, 0), initial=0)
-    ceiling = jnp.any((new > hi) & (new > old))
-    floor = jnp.any((new < lo) & (new < old))
-    return new, gain, loss, ceiling, floor, sweeps, changed
-
-
 @jax.jit
 def _region_fixpoint(nbr, deg, hubs, core, ends, side_src, side_dst,
                      side_valid, lo, hi, cap):
@@ -233,10 +213,10 @@ def _region_fixpoint(nbr, deg, hubs, core, ends, side_src, side_dst,
     recompute, so a partial region is never used).
 
     The mirror is as wide as its largest row, and on a power-law graph
-    nearly all of it is padding. So each level sweeps two tiers, as the
-    descent does: the first ``_W_SMALL`` columns of every row, and the whole
-    rows of ``hubs`` (the rows with more entries; padding ids point at the
-    empty sentinel row). Together they hold every arc of the mirror.
+    nearly all of it is padding. So each level sweeps two tiers: the first
+    ``_W_SMALL`` columns of every row, and the whole rows of ``hubs`` (the
+    rows with more entries; padding ids point at the empty sentinel row).
+    Together they hold every arc of the mirror.
     """
     n1, width = nbr.shape
     w = min(width, _W_SMALL)
@@ -265,30 +245,6 @@ def _region_fixpoint(nbr, deg, hubs, core, ends, side_src, side_dst,
         cond, body, (ends, ends, jnp.sum(ends))
     )
     return visited, count
-
-
-def _fit_width(idx: np.ndarray, valid: np.ndarray, w_pad: int,
-               sentinel: int):
-    """Trim/pad the gathered candidate matrix to a static ``w_pad`` columns.
-
-    Safe to trim: ``w_pad >= max candidate degree``, and a row only owns
-    overflow columns when its degree exceeds the table width, which forces
-    ``w_pad`` past them.
-    """
-    w = idx.shape[1]
-    if w > w_pad:
-        return np.ascontiguousarray(idx[:, :w_pad]), np.ascontiguousarray(
-            valid[:, :w_pad]
-        )
-    if w < w_pad:
-        rows = idx.shape[0]
-        idx = np.concatenate(
-            [idx, np.full((rows, w_pad - w), sentinel, np.int32)], axis=1
-        )
-        valid = np.concatenate(
-            [valid, np.zeros((rows, w_pad - w), bool)], axis=1
-        )
-    return idx, valid
 
 
 def _pad_rows(idx: np.ndarray, valid: np.ndarray, r_pad: int, sentinel: int):
@@ -576,8 +532,9 @@ class IncrementalCore:
         fallback, so a lowering or compile error raises to the caller
         instead of being absorbed as a failed dispatch.
         """
-        key = (fn, tuple(sorted(static.items())),
-               tuple((a.shape, a.dtype, a.sharding) for a in args))
+        key = (fn, tuple(sorted(static.items())), jax.tree.structure(args),
+               tuple((a.shape, a.dtype, a.sharding)
+                     for a in jax.tree.leaves(args)))
         exe = self._executables.get(key)
         if exe is None:
             exe = self._executables[key] = fn.lower(*args, **static).compile()
@@ -894,120 +851,98 @@ class IncrementalCore:
         return int(changed.sum())
 
     @staticmethod
-    def _pad_shape(n_cand: int, cand_deg: np.ndarray):
-        """Static (r_pad, w_pad) of the fused-descent candidate matrix.
-
-        Floored at 64x64: masked rows/lanes are near-free to sweep, and
-        fewer distinct (R, W) combinations means far fewer jit compiles
-        across a stream of variously-sized repairs. The adaptive policy
-        costs the descent on exactly this padded area.
-        """
-        w_pad = max(pow2(max(int(cand_deg.max(initial=1)), 1)), 64)
-        r_pad = max(pow2(n_cand), 64)
-        return r_pad, w_pad
-
-    @classmethod
-    def _tier_plan(cls, n_cand: int, cand_deg: np.ndarray):
+    def _tier_plan(cand_deg: np.ndarray):
         """Static tier shapes of the descent matrix, plus padded cell count.
 
-        A single ELL matrix pays the hub tax: a few high-degree rows force
-        ``w_pad`` to 4-8x the typical degree and the sweep is mostly
-        padding. When splitting the rows at ``_W_SMALL`` into a narrow
-        matrix plus a small hub matrix strictly shrinks the swept area, do
-        it — the per-row operator is unchanged, so the fixpoint (and the
-        policy's cells-proportional cost model) is the same computation
-        over fewer cells. Returns ``(r_small, r_big, w_big, n_big, cells)``
-        with ``r_big == 0`` meaning single-tier.
+        Returns ``(tiers, tier, cells)``: ``tiers`` is ``((width, rows),
+        ...)``, narrowest first, and ``tier`` the tier of each candidate
+        row: the narrowest whose width holds the row's true degree. Widths
+        are rungs of ``_LADDER`` below the widest, ``pow2`` of the largest
+        degree; that width and every tier's rows are powers of two floored
+        at 64 (masked rows/lanes are near-free to sweep, fewer distinct
+        shapes mean fewer compiles, and row-sharding stays divisible). A
+        rung becomes a tier only where that strictly shrinks the swept
+        cells: of every set of rungs (empty tiers skipped) the plan with
+        fewest cells wins, the fewer tiers on a tie. A set whose largest
+        degree is at most 64 stays one matrix. The per-row operator is
+        unchanged, so the fixpoint (and the policy's cells-proportional cost
+        model) is the same computation over fewer cells.
         """
-        r_pad, w_pad = cls._pad_shape(n_cand, cand_deg)
-        cells = r_pad * w_pad
-        if w_pad <= 2 * _W_SMALL:
-            return r_pad, 0, w_pad, 0, cells
-        n_big = int((cand_deg > _W_SMALL).sum())
-        if not 0 < n_big < n_cand:
-            return r_pad, 0, w_pad, 0, cells
-        r_small = max(pow2(n_cand - n_big), 64)
-        r_big = max(pow2(n_big), 64)
-        split_cells = r_small * _W_SMALL + r_big * w_pad
-        if split_cells >= cells:
-            return r_pad, 0, w_pad, 0, cells
-        return r_small, r_big, w_pad, n_big, split_cells
+        w_top = max(pow2(int(cand_deg.max(initial=1))), 64)
+        best = ((w_top, max(pow2(len(cand_deg)), 64)),)
+        best_tier = np.zeros(len(cand_deg), np.int64)
+        best_cells = w_top * best[0][1]
+        rungs = [w for w in _LADDER if w < w_top] if w_top > 64 else []
+        for k in range(1, len(rungs) + 1):
+            for cut in combinations(rungs, k):
+                widths = cut + (w_top,)
+                tier = np.searchsorted(widths, cand_deg)
+                counts = np.bincount(tier, minlength=len(widths))
+                if not counts.all():
+                    continue
+                tiers = tuple((w, max(pow2(c), 64))
+                              for w, c in zip(widths, counts))
+                cells = sum(w * r for w, r in tiers)
+                if cells < best_cells:
+                    best, best_tier, best_cells = tiers, tier, cells
+        return best, best_tier, best_cells
 
     def _stage(self, cand, seed, old_cand, lo, hi, *, cand_deg):
-        """Host half of a descent: gather/pad the candidate matrix, place
-        it, and compile the fused descent for its shapes. Nothing here is
-        retried or caught on the repair path: a compile error propagates."""
+        """Host half of a descent: build each tier's matrix at its own
+        width, place them, and compile the fused descent for their shapes.
+        Nothing here is retried or caught on the repair path: a compile
+        error propagates."""
         g = self.g
         node_cap = g.node_cap
         with obs.timed("repair.candidates", impl="gather") as sp:
             n_rows = len(cand)
-            r_small, r_big, w_big, n_big, cells = self._tier_plan(
-                n_rows, cand_deg
-            )
+            tiers, tier, cells = self._tier_plan(cand_deg)
             sp.set(rows=n_rows, cells=cells)
-            keep = None
-            if r_big:
-                # hubs last; the stable partition keeps each tier's rows in
-                # input order, and ``keep`` maps the padded concat back to them
-                order = np.argsort(cand_deg > _W_SMALL, kind="stable")
-                cand, seed = cand[order], seed[order]
-                old_cand = old_cand[order]
-                keep = np.concatenate(
-                    [np.arange(n_rows - n_big), r_small + np.arange(n_big)]
-                )
-            cand_out = cand  # unpadded (tier-ordered) rows the result maps to
-            idx, valid = g.gather_rows(cand)
+            # tier by tier; the stable sort keeps each tier's rows in input
+            # order, and ``keep`` maps the padded concat back to them
+            order = np.argsort(tier, kind="stable")
+            cand, seed, old_cand = cand[order], seed[order], old_cand[order]
+            counts = np.bincount(tier, minlength=len(tiers))
+            starts = np.cumsum([0] + [r for _, r in tiers])
+            keep = np.concatenate(
+                [s + np.arange(c) for s, c in zip(starts, counts)]
+            )
+            mats = [
+                _pad_rows(*g.gather_rows(rows, width=w), r, node_cap)
+                for (w, r), rows in zip(
+                    tiers, np.split(cand, np.cumsum(counts)[:-1]))
+            ]
             est_full = np.zeros(node_cap + 1, np.int32)
             est_full[: g.n_nodes] = self._core[: g.n_nodes]
 
-            def vec(x, fill, dtype):
-                out = np.full(r_small + r_big, fill, dtype)
-                out[: n_rows - n_big] = x[: n_rows - n_big]
-                out[r_small : r_small + n_big] = x[n_rows - n_big :]
+            def vec(x, fill):
+                out = np.full(starts[-1], fill, np.int32)
+                out[keep] = x
                 return out
 
-            if r_big:
-                n_small = n_rows - n_big
-                idx_s, valid_s = _fit_width(
-                    idx[:n_small], valid[:n_small], _W_SMALL, node_cap
-                )
-                idx_b, valid_b = _fit_width(
-                    idx[n_small:], valid[n_small:], w_big, node_cap
-                )
-                idx_s, valid_s = _pad_rows(idx_s, valid_s, r_small, node_cap)
-                idx_b, valid_b = _pad_rows(idx_b, valid_b, r_big, node_cap)
-                cand_p = vec(cand, node_cap, np.int64)
-                seed_p = vec(seed, 0, np.int32)
-                old_p = vec(old_cand, 0, np.int32)
-            else:
-                idx, valid = _fit_width(idx, valid, w_big, node_cap)
-                idx, valid = _pad_rows(idx, valid, r_small, node_cap)
-                cand_p = vec(cand, node_cap, np.int64)
-                seed_p = vec(seed, 0, np.int32)
-                old_p = vec(old_cand, 0, np.int32)
+            cand_p = vec(cand, node_cap)
+            seed_p, old_p = vec(seed, 0), vec(old_cand, 0)
         self._tick("candidates", "gather", sp.t0, sp.t1)
 
-        # under a ShardPlan (and a GSPMD-partitionable kernel impl) the
-        # candidate matrix rows are split across the mesh: each shard sweeps
-        # its own rows and the frozen-boundary estimate stays replicated. A
-        # Pallas kernel cannot be partitioned, so its descent runs on one
-        # device.
+        # under a ShardPlan (and a GSPMD-partitionable kernel impl) each
+        # tier's rows are split across the mesh: each shard sweeps its own
+        # rows and the frozen-boundary estimate stays replicated. A Pallas
+        # kernel cannot be partitioned, so its descent runs on one device.
         plan = g.plan if self._kernel_mode() in ("count", "ref") else None
         row = jnp.asarray if plan is None else plan.place_rows
         rep = jnp.asarray if plan is None else plan.replicate
-        mats = (idx_s, valid_s, idx_b, valid_b) if r_big else (idx, valid)
-        args = tuple(row(m) for m in mats) + (
-            row(np.asarray(cand_p, np.int32)),
-            row(np.asarray(seed_p, np.int32)),
-            row(np.asarray(old_p, np.int32)),
+        args = (
+            tuple((row(i), row(v)) for i, v in mats),
+            row(cand_p), row(seed_p), row(old_p),
             rep(est_full), rep(np.int32(lo)), rep(np.int32(hi)),
         )
         exe = self._compiled(
-            _fused_descent_two if r_big else _fused_descent, args,
+            _fused_descent, args,
             impl=self._kernel_mode(), max_sweeps=self.max_sweeps,
         )
         return {"exe": exe, "args": args, "n_rows": n_rows, "cells": cells,
-                "cand": cand_out, "keep": keep}
+                "arcs": int(cand_deg.sum()), "tiers": len(tiers),
+                "cand": cand, "keep": keep}
 
     @staticmethod
     def _launch(staged):
@@ -1035,8 +970,8 @@ class IncrementalCore:
         None when the sweep cap truncated the descent.
         """
         impl = f"fused[{self._kernel_mode()}]"
-        attrs = dict(impl=impl, rows=pending["n_rows"],
-                     cells=pending["cells"])
+        attrs = {k: pending[k] for k in ("cells", "arcs", "tiers")}
+        attrs.update(impl=impl, rows=pending["n_rows"])
         if full_interval:
             # the interval opened at the dispatch, in another call
             res, truncated = self._read_descent(pending)
@@ -1055,29 +990,22 @@ class IncrementalCore:
         """Block on a pending descent's outputs and count it; returns
         ``((new, max_gain, max_loss, ceiling_hit, floor_hit), truncated)``."""
         new, gain, loss, ceiling, floor, sweeps, truncated = pending["out"]
-        new = np.asarray(new, np.int32)
-        keep = pending["keep"]
-        new = new[: pending["n_rows"]] if keep is None else new[keep]
+        new = np.asarray(new, np.int32)[pending["keep"]]
         self.sweeps += int(sweeps)
         self.descends += 1
         metrics().counter("repair_descends_total").inc()
         res = (new, int(gain), int(loss), bool(ceiling), bool(floor))
         return res, bool(truncated)
 
-    def _descend(self, cand: np.ndarray, seed: np.ndarray) -> np.ndarray:
+    def _descend(self, cand: np.ndarray, seed: np.ndarray,
+                 cand_deg: np.ndarray) -> np.ndarray:
         """Reference host descent: per-iteration jitted sweeps over a
         host-maintained estimate (the PR 2 path, kept as the oracle)."""
         g = self.g
-        idx, valid = g.gather_rows(cand)
-        w_pad = pow2(max(int(valid.sum(axis=1).max(initial=1)), 1))
-        idx, valid = _fit_width(idx, valid, w_pad, g.node_cap)
+        w_pad = pow2(int(cand_deg.max(initial=1)))
         n_rows = pow2(len(cand))
-        if n_rows != len(cand):
-            pad = n_rows - len(cand)
-            idx = np.concatenate(
-                [idx, np.full((pad, w_pad), g.node_cap, np.int32)]
-            )
-            valid = np.concatenate([valid, np.zeros((pad, w_pad), bool)])
+        idx, valid = _pad_rows(*g.gather_rows(cand, width=w_pad), n_rows,
+                               g.node_cap)
 
         est = np.zeros(g.node_cap + 1, np.int32)
         est[: len(self._core)] = self._core
@@ -1218,7 +1146,7 @@ class IncrementalCore:
             )
             seed = np.maximum(seed, 0).astype(np.int32)
             if self._device():
-                cells = self._tier_plan(len(cand), cand_deg)[4]
+                cells = self._tier_plan(cand_deg)[2]
                 sp.set(cells=cells)
         self._tick("candidates", "gather", sp.t0, sp.t1)
 
@@ -1255,8 +1183,9 @@ class IncrementalCore:
                 return self._recover_ref(ctx, hi)
             return self._resolve(ctx, margin, lo, hi, pending["cand"], res)
 
-        with obs.timed("repair.descend", impl="host", rows=len(cand)) as sp:
-            new = self._descend(cand, seed)
+        with obs.timed("repair.descend", impl="host", rows=len(cand),
+                       arcs=int(cand_deg.sum()), tiers=1) as sp:
+            new = self._descend(cand, seed, cand_deg)
             # a changed node's old level sits within the *deepest per-node
             # cascade* of the block's endpoint levels, so the window is
             # sufficient as long as the margin exceeds the largest

@@ -159,37 +159,35 @@ class DynamicGraph:
         )
         return rows, dsts
 
-    def gather_rows(self, nodes) -> Tuple[np.ndarray, np.ndarray]:
+    def gather_rows(self, nodes, width: int) -> Tuple[np.ndarray, np.ndarray]:
         """Neighbour matrix of ``nodes``: (idx, valid) with overflow merged.
 
-        ``idx`` is (len(nodes), W') int32 of neighbour ids (padding =
+        ``idx`` is (len(nodes), width) int32 of neighbour ids (padding =
         ``node_cap``, the sentinel row), ``valid`` the matching bool mask.
-        The table part is one vectorized gather; only rows that currently
-        hold overflow arcs (rare between compactions) widen the matrix and
-        are patched individually.
+        Rows are left-packed: table entries, then overflow arcs (a row only
+        holds overflow once its table part is full), so ``width`` must hold
+        every row's true degree; only that many columns are copied. The
+        table part is one vectorized gather; only rows that currently hold
+        overflow arcs (rare between compactions) are patched individually.
         """
         nodes = np.asarray(nodes, np.int64)
-        idx = self._nbr[nodes]  # fancy indexing: already a fresh copy
-        valid = np.arange(self.width)[None, :] < self._deg[nodes][:, None]
+        hits = []
         if self._overflow:
-            pos = {int(v): i for i, v in enumerate(nodes)}
-            hits = [
-                (pos[v], lst)
-                for v, lst in self._overflow.items()
-                if v in pos
-            ]
-            if hits:
-                extra_w = max(len(lst) for _, lst in hits)
-                idx = np.concatenate(
-                    [idx, np.full((len(nodes), extra_w), self.node_cap,
-                                  np.int32)], axis=1
-                )
-                valid = np.concatenate(
-                    [valid, np.zeros((len(nodes), extra_w), bool)], axis=1
-                )
-                for i, lst in hits:
-                    idx[i, self.width : self.width + len(lst)] = lst
-                    valid[i, self.width : self.width + len(lst)] = True
+            keys = np.fromiter(self._overflow, np.int64, len(self._overflow))
+            hits = [(int(i), self._overflow[int(nodes[i])])
+                    for i in np.nonzero(np.isin(nodes, keys))[0]]
+        cols = min(width, self.width)
+        idx = self._nbr[nodes, :cols]  # fancy indexing: already a fresh copy
+        valid = np.arange(cols)[None, :] < self._deg[nodes][:, None]
+        if width > cols:
+            pad = (len(nodes), width - cols)
+            idx = np.concatenate(
+                [idx, np.full(pad, self.node_cap, np.int32)], axis=1
+            )
+            valid = np.concatenate([valid, np.zeros(pad, bool)], axis=1)
+        for i, lst in hits:
+            idx[i, self.width : self.width + len(lst)] = lst
+            valid[i, self.width : self.width + len(lst)] = True
         return idx, valid
 
     # ------------------------------------------------------------- staging

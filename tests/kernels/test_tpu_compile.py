@@ -29,7 +29,7 @@ BATCH = 64  # query flush batch
 # compaction (pow2 of 1.5x the max degree)
 ELL_WIDTHS = [16, 2048]
 K_SGNS, B_SGNS = 5, 4096  # SGNSConfig negatives / batch
-W_SMALL = kcore_inc._W_SMALL  # two-tier descent: narrow tier width
+W_SMALL = kcore_inc._W_SMALL  # narrowest tier of the descent
 W_HUB = 2048  # hub tier: pow2 of github-like's max degree (1,202)
 
 
@@ -87,26 +87,30 @@ def test_hindex_compiles(shape, rows, width):
     )
 
 
-@pytest.mark.parametrize("two_tier", [False, True], ids=["one", "two"])
-def test_fused_descent_compiles(shape, two_tier):
+# (rows, width) of each tier of the fused descent's candidate matrix
+DESCENT_TIERS = {
+    "one": [(4096, 64)],
+    # github-like's full-graph fallback: narrow tier + one hub tier
+    "two": [(65536, W_SMALL), (4096, W_HUB)],
+    # graph500-s14's whole graph on the ladder, at its largest degree (3,654)
+    "ladder": [(16384, W_SMALL), (4096, 128), (512, 512), (128, 4096)],
+}
+DESCENT_EST = {"one": ROWS + 1, "two": ROWS + 1, "ladder": 12518}
+
+
+@pytest.mark.parametrize("layout", list(DESCENT_TIERS))
+def test_fused_descent_compiles(shape, layout):
     """The whole repair dispatch: the h-index kernel inside the fused
     ``lax.while_loop`` fixpoint, at the full-graph fallback's shapes."""
     i32 = jnp.int32
-    n1 = ROWS + 1
-    if two_tier:
-        fn = kcore_inc._fused_descent_two
-        r = 65536 + 4096
-        mats = (shape((65536, W_SMALL), i32), shape((65536, W_SMALL), bool),
-                shape((4096, W_HUB), i32), shape((4096, W_HUB), bool))
-    else:
-        fn = kcore_inc._fused_descent
-        r = 4096
-        mats = (shape((r, 64), i32), shape((r, 64), bool))
-    vec = shape((r,), i32)
-    args = mats + (vec, vec, vec, shape((n1,), i32),
-                   shape((), i32), shape((), i32))
-    compiled = fn.lower(*args, impl="pallas", max_sweeps=512).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    tiers = tuple((shape((r, w), i32), shape((r, w), bool))
+                  for r, w in DESCENT_TIERS[layout])
+    vec = shape((sum(r for r, _ in DESCENT_TIERS[layout]),), i32)
+    args = (tiers, vec, vec, vec, shape((DESCENT_EST[layout],), i32),
+            shape((), i32), shape((), i32))
+    compiled = kcore_inc._fused_descent.lower(
+        *args, impl="pallas", max_sweeps=512).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= len(tiers)
     assert compiled.memory_analysis().argument_size_in_bytes > 0
 
 

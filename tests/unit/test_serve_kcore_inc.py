@@ -353,3 +353,130 @@ def test_repair_spans_are_the_phase_intervals(stream_case, kw):
         assert nested and all(
             by_id[e["parent"]]["name"] == "repair.fallback" for e in nested)
         assert all({"rows", "cells"} <= e["attrs"].keys() for e in nested)
+
+
+# ------------------------------------------------- degree-tiered descent
+
+def _two_tier_cells(deg):
+    """Cells of the plain two-tier split at 32 (rows <= 32 wide, the rest
+    at pow2 of the largest degree), or of one matrix where that is fewer."""
+    pow2 = lambda x: 1 << max(int(x) - 1, 0).bit_length()
+    w_top = max(pow2(deg.max()), 64)
+    one = max(pow2(len(deg)), 64) * w_top
+    n_big = int((deg > 32).sum())
+    if w_top <= 64 or not 0 < n_big < len(deg):
+        return one
+    return min(one, max(pow2(len(deg) - n_big), 64) * 32
+               + max(pow2(n_big), 64) * w_top)
+
+
+def _degree_sets():
+    rng = np.random.default_rng(40)
+    return {
+        "power-law": np.minimum(rng.zipf(1.8, 20000), 3654),
+        "few-hubs": np.concatenate([rng.integers(1, 30, 5000), [700, 2000]]),
+        "mid-heavy": np.concatenate([rng.integers(1, 33, 300),
+                                     rng.integers(100, 500, 400), [1500]]),
+        "all-wide": rng.integers(600, 900, 100),
+        "one-row": np.array([3000]),
+        "low": rng.integers(0, 65, 4000),
+    }
+
+
+@pytest.mark.parametrize("name", list(_degree_sets()))
+def test_tier_plan_puts_each_row_in_one_tier_that_holds_it(name):
+    deg = _degree_sets()[name]
+    tiers, tier, cells = IncrementalCore._tier_plan(deg)
+    widths = np.array([w for w, _ in tiers])
+    rows = np.array([r for _, r in tiers])
+    assert np.all(np.diff(widths) > 0)  # narrowest first
+    assert tier.shape == deg.shape and tier.min() >= 0
+    assert tier.max() < len(tiers)
+    assert np.all(deg <= widths[tier])  # the row fits its tier
+    # and it sits in the narrowest tier that holds it
+    assert np.all(tier == np.searchsorted(widths, deg))
+    counts = np.bincount(tier, minlength=len(tiers))
+    assert np.all(counts > 0) and np.all(counts <= rows)  # no empty tier
+    assert all(r >= 64 and r & (r - 1) == 0 for r in rows)
+    assert cells == int((widths * rows).sum())
+    assert cells <= _two_tier_cells(deg)
+
+
+def test_tier_plan_ladder_cuts_the_cells_of_a_hub_heavy_set():
+    deg = _degree_sets()["mid-heavy"]
+    tiers, _, cells = IncrementalCore._tier_plan(deg)
+    assert [w for w, _ in tiers] == [32, 512, 2048]
+    assert cells < _two_tier_cells(deg)
+
+
+@pytest.mark.parametrize("deg", [
+    np.arange(65), np.full(5000, 64), np.array([1]), np.zeros(10, np.int64),
+], ids=["0-64", "all-64", "one", "zeros"])
+def test_tier_plan_keeps_low_degree_sets_one_matrix(deg):
+    tiers, tier, cells = IncrementalCore._tier_plan(deg)
+    assert tiers == ((64, max(64, 1 << (len(deg) - 1).bit_length())),)
+    assert not tier.any() and cells == tiers[0][0] * tiers[0][1]
+
+
+def _hub_graph(seed):
+    """1,600 nodes of degree ~4, 110 of degree ~60, 70 of ~200 and one of
+    ~1,100: candidate rows in every tier of the ladder."""
+    rng = np.random.default_rng(seed)
+    n = 1600
+    edges = [np.stack([np.arange(n), rng.integers(0, n, n)], 1)]
+    edges += [np.stack([np.full(k, h), rng.choice(n, k, replace=False)], 1)
+              for h, k in [(0, 1100)] + [(h, 200) for h in range(1, 71)]
+              + [(h, 60) for h in range(71, 181)]]
+    e = np.concatenate(edges + [rng.integers(0, n, (2 * n, 2))])
+    e = e[e[:, 0] != e[:, 1]]
+    e = np.unique(np.sort(e, axis=1), axis=0)
+    return n, e[rng.permutation(len(e))]
+
+
+@pytest.mark.parametrize("kw", [
+    {"repair_policy": "region", "repeel_frac": 1.0},
+    {"repair_policy": "fallback", "repeel_impl": "descend"},
+], ids=["region-descent", "repeel-by-descent"])
+def test_tiered_descent_matches_ref_and_oracle_on_hub_stream(kw):
+    """Insert and retract blocks on a hub-heavy graph: the ladder's fused
+    descent commits the host descent's and the peeling oracle's cores,
+    including while candidate rows hold overflow arcs."""
+    from repro.obs import trace as obs
+
+    n, edges = _hub_graph(41)
+    base, stream = edges[: len(edges) * 3 // 4], edges[len(edges) * 3 // 4:]
+    dyn_ref = DynamicGraph(n, base, width=64)
+    dyn_dev = DynamicGraph(n, base, width=64)
+    ref = IncrementalCore(dyn_ref, impl="ref", repeel_frac=1.0)
+    prev = obs.tracer()
+    t = obs.enable(exemplar_watch=())
+    try:
+        dev = IncrementalCore(dyn_dev, impl="device", **kw)
+        rng = np.random.default_rng(42)
+        overflowed = False
+        for step, block in enumerate(np.array_split(stream, 3)):
+            for dyn, inc in ((dyn_ref, ref), (dyn_dev, dev)):
+                inc.on_edge_block(dyn.add_edges(block))
+            overflowed |= dyn_dev.overflow_arcs > 0
+            np.testing.assert_array_equal(ref.core, dev.core)
+            gone = block[rng.choice(len(block), len(block) // 4,
+                                    replace=False)]
+            for dyn, inc in ((dyn_ref, ref), (dyn_dev, dev)):
+                inc.on_remove(dyn.remove_edges(gone))
+            overflowed |= dyn_dev.overflow_arcs > 0
+            np.testing.assert_array_equal(ref.core, dev.core)
+            np.testing.assert_array_equal(
+                dev.core, core_numbers_host(dyn_dev.snapshot()))
+            if step == 1:
+                dyn_dev.compact()
+                dyn_ref.compact()
+    finally:
+        obs.set_tracer(prev)
+    assert overflowed  # some candidate rows held overflow arcs
+    spans = [e for e in t.events if e["name"] == "repair.descend"
+             and e["attrs"]["impl"].startswith("fused")]
+    assert dev.descends == len(spans) > 0 and ref.descends == 0
+    assert max(e["attrs"]["tiers"] for e in spans) >= 3
+    for e in spans:
+        a = e["attrs"]
+        assert a["arcs"] > 0 and a["cells"] >= a["arcs"]

@@ -196,3 +196,23 @@ def test_ell_view_consistent_with_to_ell_after_compact():
         row = np.sort(nbr[v][nbr[v] != dyn.node_cap])
         np.testing.assert_array_equal(row, g.neighbours(v))
         assert deg[v] == len(g.neighbours(v))
+
+
+def test_gather_rows_at_a_given_width_merges_overflow():
+    # width 4: node 0 spills 5 arcs to overflow, node 9 holds 2 arcs
+    dyn = DynamicGraph(12, width=4)
+    dyn.add_edges([[0, v] for v in range(1, 10)] + [[9, 10]])
+    nodes = np.array([9, 0, 11])
+    for width in (9, 16):
+        idx, valid = dyn.gather_rows(nodes, width=width)
+        assert idx.shape == valid.shape == (3, width)
+        for row, v in zip(range(3), nodes):
+            assert sorted(idx[row][valid[row]].tolist()) == \
+                sorted(dyn.neighbours(v).tolist())
+            # left-packed: the true degree's first columns, padding after
+            assert valid[row].sum() == dyn.degree(v)
+            assert valid[row, : dyn.degree(v)].all()
+        assert (idx[~valid] == dyn.node_cap).all()
+    # rows that fit: only the asked-for columns are gathered
+    idx, valid = dyn.gather_rows(np.array([9, 11]), width=2)
+    assert idx.shape == (2, 2) and valid[0].all() and not valid[1].any()
