@@ -183,10 +183,17 @@ def one_chip(g, seed, phase, check) -> None:
     import numpy as np
 
     from repro.launch.serve_embed import build_service
+    from repro.serve.retrain import RetrainConfig
+    from repro.skipgram.trainer import SGNSConfig
 
+    refresh = RetrainConfig(
+        n_walks=8, walk_length=16,
+        sgns=SGNSConfig(dim=DIM, epochs=0.25, seed=seed),
+        prop_iters=20, seed=seed,
+    )
     with phase("build (CoreWalk+SGNS on the base graph)"):
         svc, edges, _, k0 = build_service(
-            g, dim=DIM, batch=BATCH, train=True, retrain=True,
+            g, dim=DIM, batch=BATCH, train=True, refresh=refresh,
             retrain_threshold=float("inf"), seed=seed,
         )
     log(f"base: {svc.graph.n_edges} edges, k0={k0}, "

@@ -17,7 +17,7 @@ import dataclasses
 
 import numpy as np
 
-__all__ = ["WalkPlan", "deepwalk_plan", "corewalk_plan"]
+__all__ = ["WalkPlan", "plan_from_counts", "deepwalk_plan", "corewalk_plan"]
 
 
 @dataclasses.dataclass
@@ -35,7 +35,8 @@ class WalkPlan:
         return other.n_real / max(self.n_real, 1)
 
 
-def _plan_from_counts(per_node: np.ndarray, pad_to: int | None) -> WalkPlan:
+def plan_from_counts(per_node: np.ndarray, pad_to: int | None) -> WalkPlan:
+    """Walks rooted at each node in turn, ``per_node[v]`` at node v."""
     roots = np.repeat(np.arange(len(per_node), dtype=np.int32), per_node)
     n_real = len(roots)
     if pad_to is not None and n_real % pad_to:
@@ -46,7 +47,7 @@ def _plan_from_counts(per_node: np.ndarray, pad_to: int | None) -> WalkPlan:
 
 def deepwalk_plan(n_nodes: int, n_walks: int, pad_to: int | None = None) -> WalkPlan:
     """Fixed budget: n walks per node (DeepWalk / Node2Vec baseline)."""
-    return _plan_from_counts(np.full(n_nodes, n_walks, dtype=np.int64), pad_to)
+    return plan_from_counts(np.full(n_nodes, n_walks, dtype=np.int64), pad_to)
 
 
 def corewalk_plan(
@@ -56,4 +57,4 @@ def corewalk_plan(
     core = np.asarray(core, dtype=np.int64)
     kdeg = max(int(core.max()), 1)
     per_node = np.maximum((n_walks * core) // kdeg, 1)
-    return _plan_from_counts(per_node, pad_to)
+    return plan_from_counts(per_node, pad_to)

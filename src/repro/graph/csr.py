@@ -120,12 +120,17 @@ class Graph:
         sizes = np.bincount(comp, minlength=cur)
         return comp == np.argmax(sizes)
 
-    def to_ell(self, max_width: Optional[int] = None, seed: int = 0) -> "EllGraph":
+    def to_ell(self, max_width: Optional[int] = None, seed: int = 0,
+               width: Optional[int] = None) -> "EllGraph":
+        """Padded neighbour table, as wide as the largest degree (at most
+        ``max_width``), or exactly ``width`` wide when given; a row longer
+        than the table keeps a seeded sample of its neighbours."""
         deg = self.degrees()
-        width = int(deg.max()) if deg.size else 0
-        if max_width is not None:
-            width = min(width, int(max_width))
-        width = max(width, 1)
+        if width is None:
+            width = int(deg.max()) if deg.size else 0
+            if max_width is not None:
+                width = min(width, int(max_width))
+        width = max(int(width), 1)
         n = self.n_nodes
         nbr = np.full((n + 1, width), n, dtype=np.int32)  # sentinel row n
         eff_deg = np.minimum(deg, width).astype(np.int32)

@@ -79,7 +79,7 @@ def build_service(
     prop_iters: int = 20,
     seed: int = 0,
     shards: int = 1,
-    retrain: bool = False,
+    refresh=None,
     retrain_threshold: float = 0.1,
     retrain_budget: int = 0,
     repair_policy: str = "adaptive",
@@ -91,11 +91,12 @@ def build_service(
 
     ``shards > 1`` row-shards the store table and ELL mirror across that
     many devices (``ShardPlan``); 1 keeps the exact single-device path.
-    ``retrain=True`` attaches a drift-triggered ``Retrainer`` in auto mode:
-    after every ingested block the service re-checks ``retrain_pressure``
-    against ``retrain_threshold`` and, while ``retrain_budget`` allows,
-    refreshes the k0-core embeddings (CoreWalk+SGNS warm start, Procrustes
-    alignment, chunked hot swap) in place. ``repair_policy`` selects the
+    ``refresh`` (a ``RetrainConfig``) attaches a drift-triggered
+    ``Retrainer`` with those settings in auto mode: after every ingested
+    block the service re-checks ``retrain_pressure`` against
+    ``retrain_threshold`` and, while ``retrain_budget`` allows, refreshes the
+    k0-core embeddings (CoreWalk+SGNS warm start, Procrustes alignment,
+    chunked hot swap) in place. ``repair_policy`` selects the
     block-repair decision rule (``adaptive`` measured crossover /
     ``region`` legacy static trigger / ``fallback`` always re-peel) and
     ``pipeline`` overlaps block staging with the in-flight descent — both
@@ -153,20 +154,25 @@ def build_service(
         base, inc, store, batch=batch, compact_every=compact_every, k0=k0,
         retrain_threshold=retrain_threshold, pipeline=pipeline,
     )
-    if retrain:
-        from repro.serve.retrain import RetrainConfig, Retrainer
-        from repro.skipgram.trainer import SGNSConfig
+    if refresh is not None:
+        from repro.serve.retrain import Retrainer
 
-        cfg = RetrainConfig(
-            n_walks=8,
-            walk_length=16,
-            sgns=SGNSConfig(dim=dim, epochs=0.25, seed=seed),
-            prop_iters=prop_iters,
-            seed=seed,
-        )
-        svc.set_retrainer(Retrainer(svc, cfg), auto=True,
+        svc.set_retrainer(Retrainer(svc, refresh), auto=True,
                           budget=retrain_budget)
     return svc, stream_edges, core, k0
+
+
+def _refresh_config(args):
+    """The ``--retrain`` loop's settings: ``RetrainConfig``'s defaults at
+    the run's width and seed, with a quarter epoch of SGNS."""
+    if not args.retrain:
+        return None
+    from repro.serve.retrain import RetrainConfig
+    from repro.skipgram.trainer import SGNSConfig
+
+    return RetrainConfig(sgns=SGNSConfig(dim=args.dim, epochs=0.25,
+                                         seed=args.seed),
+                         seed=args.seed)
 
 
 def main(argv=None):
@@ -270,7 +276,7 @@ def main(argv=None):
         train=args.train,
         seed=args.seed,
         shards=args.shards,
-        retrain=args.retrain,
+        refresh=_refresh_config(args),
         retrain_threshold=args.retrain_threshold,
         retrain_budget=args.retrain_budget,
         repair_policy=args.repair_policy,
@@ -297,15 +303,9 @@ def main(argv=None):
     # identically during replay
     def _reconfigure(s):
         if args.retrain:
-            from repro.serve.retrain import RetrainConfig, Retrainer
-            from repro.skipgram.trainer import SGNSConfig
+            from repro.serve.retrain import Retrainer
 
-            cfg = RetrainConfig(
-                n_walks=8, walk_length=16,
-                sgns=SGNSConfig(dim=args.dim, epochs=0.25, seed=args.seed),
-                seed=args.seed,
-            )
-            s.set_retrainer(Retrainer(s, cfg), auto=True,
+            s.set_retrainer(Retrainer(s, _refresh_config(args)), auto=True,
                             budget=args.retrain_budget)
 
     # --- ingest the stream in blocks, with churn (deletions of streamed

@@ -35,10 +35,16 @@ separately:
 
 ``Retrainer.run()`` chains the four stages and finishes with
 ``IncrementalCore.mark_refresh()``, resetting the drift baseline the next
-trigger measures against.
+trigger measures against. Each stage is a ``retrain.<stage>`` span; a
+refresh keeps its shapes from one run to the next (the walk corpus padded
+to a multiple of ``WALK_BUCKET`` walks, the subcore's ELL at a power-of-two
+width, the swap's scatters at ``swap_chunk`` rows), so a service that
+refreshes after every block compiles nothing once warm. ``Retrainer.last``
+keeps what the latest refresh computed, for inspection.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Callable, Optional
@@ -46,26 +52,33 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
-from repro.core.corewalk import WalkPlan, corewalk_plan, deepwalk_plan
+from repro.core.corewalk import (
+    WalkPlan,
+    corewalk_plan,
+    deepwalk_plan,
+    plan_from_counts,
+)
 from repro.obs import metrics
 from repro.obs import trace as obs
 from repro.core.kcore import degeneracy, kcore_subgraph
 from repro.core.propagation import propagate
 from repro.graph.csr import Graph
-from repro.skipgram.corpus import build_corpus
+from repro.skipgram.corpus import WalkCorpus, build_corpus
 from repro.skipgram.model import init_params
-from repro.skipgram.trainer import SGNSConfig, train_sgns
+from repro.skipgram.trainer import SGNSConfig, SGNSResult, train_sgns
 
 from . import faults
 from .kcore_inc import IncrementalCore
 from .store import EmbeddingStore
 from .stream import DynamicGraph
+from .util import pow2
 
 __all__ = [
     "RetrainConfig",
     "RetrainPlan",
     "RetrainPlanner",
     "RetrainReport",
+    "RefreshRecord",
     "Retrainer",
     "EmbeddingAligner",
     "VersionRollout",
@@ -73,16 +86,22 @@ __all__ = [
 ]
 
 
-def _mark_stage(stage: str, t0: float) -> float:
-    """Close one retrain stage: emit its span + latency histogram sample.
+# walks per corpus bucket: the corpus is padded to a multiple of this, so a
+# refresh's walk and SGNS programs keep their shapes while churn moves the
+# CoreWalk budget by a few walks
+WALK_BUCKET = 1024
 
-    Returns the stage duration so call sites can keep the ``times`` dict
-    (the report API) without re-reading the clock.
-    """
-    t1 = time.perf_counter()
-    obs.record(f"retrain.{stage}", t0, t1)
-    metrics().histogram("retrain_stage_seconds", stage=stage).observe(t1 - t0)
-    return t1 - t0
+
+@contextlib.contextmanager
+def _stage(stage: str, times: dict, **attrs):
+    """One retrain stage: a ``retrain.<stage>`` span (timed whether or not
+    tracing is on) whose duration lands in ``times`` and the stage-latency
+    histogram."""
+    with obs.timed(f"retrain.{stage}", **attrs) as sp:
+        yield sp
+    times[stage] = sp.t1 - sp.t0
+    metrics().histogram("retrain_stage_seconds", stage=stage).observe(
+        times[stage])
 
 
 # --------------------------------------------------------------- planning
@@ -104,8 +123,7 @@ class RetrainConfig:
     # the first step's emb_in gradient is exactly zero — a 1-step "refresh"
     # would be a no-op on the served table
     min_sgns_steps: int = 50
-    align: bool = True  # Procrustes back into the old space
-    min_anchors: int = 8  # below this, alignment is skipped (identity)
+    align: bool = True  # Procrustes back into the old space (anchors >= dim)
     propagate: bool = True  # refill every shell below k0 (§2.2) in the swap
     prop_iters: int = 10
     swap_chunk: int = 1024  # put_many rows per rollout chunk
@@ -184,13 +202,11 @@ class EmbeddingAligner:
 
     Anchors should be nodes whose representation has no reason to have
     moved: still in the k0-core, core number unchanged since the last
-    refresh, previous vector available. With enough of them the rotation is
-    well-conditioned; with fewer than ``min_anchors`` the aligner returns
-    the input unchanged (identity), which the report flags.
+    refresh, previous vector available. A rotation of dimension d is
+    determined only by at least d anchors in general position; with fewer
+    the aligner returns the input unchanged (identity), which the report
+    flags.
     """
-
-    def __init__(self, min_anchors: int = 8):
-        self.min_anchors = int(min_anchors)
 
     def align(
         self, new_emb: np.ndarray, old_vecs: np.ndarray, anchors: np.ndarray
@@ -201,7 +217,7 @@ class EmbeddingAligner:
         anchor node; ``anchors`` indexes rows of ``new_emb``.
         """
         anchors = np.asarray(anchors, np.int64)
-        if len(anchors) < self.min_anchors:
+        if len(anchors) < new_emb.shape[1]:
             return np.asarray(new_emb, np.float32), {
                 "aligned": False,
                 "anchors": int(len(anchors)),
@@ -262,18 +278,16 @@ class VersionRollout:
         chunk_seconds = []
         for s in range(0, len(nodes), self.chunk):
             faults.check("retrain_swap_chunk")
-            t0 = time.perf_counter()
-            self.store.put_many(
-                nodes[s : s + self.chunk],
-                vecs[s : s + self.chunk],
-                cores[s : s + self.chunk],
-            )
-            t1 = time.perf_counter()
-            chunk_seconds.append(t1 - t0)
-            obs.record(
-                "retrain.swap_chunk", t0, t1,
-                rows=int(min(self.chunk, len(nodes) - s)),
-            )
+            rows = int(min(self.chunk, len(nodes) - s))
+            with obs.timed("retrain.swap_chunk", rows=rows) as sp:
+                # every chunk's scatter at the chunk's shape, the last too
+                self.store.put_many(
+                    nodes[s : s + self.chunk],
+                    vecs[s : s + self.chunk],
+                    cores[s : s + self.chunk],
+                    min_rows=self.chunk,
+                )
+            chunk_seconds.append(sp.t1 - sp.t0)
             if between is not None:
                 between()
         return {
@@ -310,6 +324,20 @@ class RetrainReport:
     times: dict  # plan / walks / train / align / propagate / swap / total
 
 
+@dataclasses.dataclass
+class RefreshRecord:
+    """What one refresh computed, kept by ``Retrainer.last``."""
+
+    plan: RetrainPlan
+    walk_plan: WalkPlan  # CoreWalk roots and budgets (n_real of them real)
+    corpus: WalkCorpus
+    warm: np.ndarray  # (n, d) emb_in before the first SGNS step
+    sgns: SGNSResult  # trained emb_in, final tables and Adam state
+    anchors: np.ndarray  # (a,) anchor node ids (empty when not aligned)
+    anchor_vecs: np.ndarray  # (a, d) their previous vectors
+    staged: tuple  # (nodes, vecs, cores) handed to the rollout
+
+
 class Retrainer:
     """Drives one full detect→snapshot→retrain→align→swap cycle.
 
@@ -324,17 +352,19 @@ class Retrainer:
         self.service = service
         self.cfg = cfg or RetrainConfig()
         self.planner = RetrainPlanner(service.graph, service.cores, service.k0)
-        self.aligner = EmbeddingAligner(self.cfg.min_anchors)
+        self.aligner = EmbeddingAligner()
+        self.last: Optional[RefreshRecord] = None
 
     # one stage per method so components stay independently testable
 
-    def _train(self, plan: RetrainPlan) -> tuple:
-        """CoreWalk walks + warm-started SGNS on the subcore.
+    def _walks(self, plan: RetrainPlan) -> tuple:
+        """CoreWalk budgets on the subcore and the walk corpus.
 
-        Returns (emb (n, d) float32, meta dict, times dict).
+        The corpus is padded to a multiple of ``WALK_BUCKET`` walks (padding
+        walks are never sampled) and the subcore's ELL is a power of two
+        wide, so successive refreshes reuse the same programs.
         """
         cfg = self.cfg
-        times = {}
         n = plan.snapshot.n_nodes
         if cfg.method == "corewalk":
             budgets = corewalk_plan(plan.core, cfg.n_walks).per_node
@@ -342,50 +372,41 @@ class Retrainer:
             budgets = deepwalk_plan(n, cfg.n_walks).per_node
         else:
             raise ValueError(cfg.method)
-        budgets = np.where(plan.core >= plan.k0, budgets, 0).astype(np.int32)
-        roots = np.repeat(np.arange(n, dtype=np.int32), budgets)
-        wplan = WalkPlan(roots=roots, n_real=len(roots), per_node=budgets)
-
-        self.service.pet_watchdog()
-        faults.check("retrain_walks")
-        t0 = time.perf_counter()
+        budgets = np.where(plan.core >= plan.k0, budgets, 0)
+        wplan = plan_from_counts(budgets, WALK_BUCKET)
+        deg = plan.sub.degrees()
+        ell = plan.sub.to_ell(width=pow2(int(deg.max()) if deg.size else 1))
         corpus = build_corpus(
-            plan.sub.to_ell(),
-            wplan,
-            cfg.walk_length,
-            jax.random.PRNGKey(cfg.seed),
+            ell, wplan, cfg.walk_length, jax.random.PRNGKey(cfg.seed)
         )
         corpus.walks.block_until_ready()
-        times["walks"] = _mark_stage("walks", t0)
+        return wplan, corpus
 
-        self.service.pet_watchdog()
-        faults.check("retrain_train")
-        t0 = time.perf_counter()
+    def _warm_table(self, plan: RetrainPlan) -> tuple:
+        """(n, d) emb_in to start from: the seeded init, with the previous
+        vectors of subcore nodes the store still holds. Built on the host,
+        so the warm rows never shape a device program."""
+        cfg = self.cfg
         params = init_params(
-            n, cfg.sgns.dim, jax.random.PRNGKey(cfg.sgns.seed)
+            plan.snapshot.n_nodes, cfg.sgns.dim,
+            jax.random.PRNGKey(cfg.sgns.seed),
         )
+        warm = np.array(params["emb_in"], np.float32)
         warm_rows = 0
         if cfg.warm_start:
             old, found, _, _ = self.service.store.peek_many(plan.nodes)
             keep = found & (np.linalg.norm(old, axis=1) > 1e-12)
             warm_rows = int(keep.sum())
-            if warm_rows:
-                params["emb_in"] = (
-                    params["emb_in"].at[plan.nodes[keep]].set(old[keep])
-                )
-        steps = max(
-            cfg.min_sgns_steps,
-            int(cfg.sgns.epochs * corpus.pairs_per_epoch(cfg.sgns.window)
-                // cfg.sgns.batch),
-        )
-        res = train_sgns(corpus, cfg.sgns, params=params, steps=steps)
-        times["train"] = _mark_stage("train", t0)
-        meta = {
-            "n_walks": int(wplan.n_real),
-            "sgns_steps": int(res.n_steps),
-            "warm_rows": warm_rows,
-        }
-        return res.embeddings, meta, times
+            warm[plan.nodes[keep]] = old[keep]
+        params["emb_in"] = jax.numpy.asarray(warm)
+        return params, warm, warm_rows
+
+    def _train(self, corpus: WalkCorpus, params) -> tuple:
+        """Warm-started SGNS on the corpus -> (SGNSResult, pairs)."""
+        cfg = self.cfg
+        pairs = int(cfg.sgns.epochs * corpus.pairs_per_epoch(cfg.sgns.window))
+        steps = max(cfg.min_sgns_steps, pairs // cfg.sgns.batch)
+        return train_sgns(corpus, cfg.sgns, params=params, steps=steps), pairs
 
     def _anchors(self, plan: RetrainPlan) -> tuple:
         """Stable anchors + their previous vectors (store peek, no LRU churn)."""
@@ -394,7 +415,7 @@ class Retrainer:
         ]
         old, found, _, _ = self.service.store.peek_many(stable)
         keep = found & (np.linalg.norm(old, axis=1) > 1e-12)
-        if int(keep.sum()) < self.aligner.min_anchors:
+        if int(keep.sum()) < self.cfg.sgns.dim:
             # heavy churn can leave too few level-stable survivors; fall back
             # to every in-core node whose previous vector is still held
             old, found, _, _ = self.service.store.peek_many(plan.nodes)
@@ -414,59 +435,74 @@ class Retrainer:
 
         svc.pet_watchdog()
         faults.check("retrain_plan")
-        t0 = time.perf_counter()
-        plan = self.planner.plan()
-        times["plan"] = _mark_stage("plan", t0)
+        with _stage("plan", times):
+            plan = self.planner.plan()
         if len(plan.nodes) == 0:
             return None  # nothing alive at any k0 — nothing to refresh
 
-        emb, meta, t_train = self._train(plan)
-        times.update(t_train)
+        svc.pet_watchdog()
+        faults.check("retrain_walks")
+        with _stage("walks", times) as sp:
+            wplan, corpus = self._walks(plan)
+            sp.set(walks=int(wplan.n_real), tokens=int(corpus.n_tokens))
+
+        svc.pet_watchdog()
+        faults.check("retrain_train")
+        with _stage("train", times) as sp:
+            params, warm, warm_rows = self._warm_table(plan)
+            res, pairs = self._train(corpus, params)
+            sp.set(steps=int(res.n_steps), pairs=pairs)
+        emb = res.embeddings
 
         svc.pet_watchdog()
         faults.check("retrain_align")
-        t0 = time.perf_counter()
-        if cfg.align:
-            anchors, old_vecs = self._anchors(plan)
-            emb, align_rep = self.aligner.align(emb, old_vecs, anchors)
-        else:
-            align_rep = {"aligned": False, "anchors": 0, "residual": 0.0}
-        times["align"] = _mark_stage("align", t0)
+        with _stage("align", times):
+            if cfg.align:
+                anchors, old_vecs = self._anchors(plan)
+                emb, align_rep = self.aligner.align(emb, old_vecs, anchors)
+            else:
+                anchors = np.zeros(0, np.int64)
+                old_vecs = np.zeros((0, emb.shape[1]), np.float32)
+                align_rep = {"aligned": False, "anchors": 0, "residual": 0.0}
 
         svc.pet_watchdog()
         faults.check("retrain_propagate")
-        t0 = time.perf_counter()
-        if cfg.propagate:
-            # §2.2: refill every shell below k0 from the aligned subcore, so
-            # the swap covers the whole served id space, not just the core
-            emb = propagate(
-                plan.snapshot, plan.core, plan.k0, emb,
-                n_iters=cfg.prop_iters,
-            )
-            served = np.where(
-                (plan.snapshot.degrees() > 0) | (plan.core >= plan.k0)
-            )[0]
-        else:
-            served = plan.nodes
-        times["propagate"] = _mark_stage("propagate", t0)
+        with _stage("propagate", times):
+            if cfg.propagate:
+                # §2.2: refill every shell below k0 from the aligned
+                # subcore, so the swap covers the whole served id space,
+                # not just the core
+                emb = propagate(
+                    plan.snapshot, plan.core, plan.k0, emb,
+                    n_iters=cfg.prop_iters,
+                )
+                served = np.where(
+                    (plan.snapshot.degrees() > 0) | (plan.core >= plan.k0)
+                )[0]
+            else:
+                served = plan.nodes
 
         svc.pet_watchdog()
         faults.check("retrain_swap")
-        t0 = time.perf_counter()
-        rollout = VersionRollout(svc.store, chunk=cfg.swap_chunk)
-        rollout.stage(served, emb[served], plan.core[served])
-        roll = rollout.commit(between)
-        svc.cores.mark_refresh()
-        times["swap"] = _mark_stage("swap", t0)
+        with _stage("swap", times):
+            staged = (served, emb[served], plan.core[served])
+            rollout = VersionRollout(svc.store, chunk=cfg.swap_chunk)
+            rollout.stage(*staged)
+            roll = rollout.commit(between)
+            svc.cores.mark_refresh()
         times["total"] = time.perf_counter() - t_total
+        self.last = RefreshRecord(
+            plan=plan, walk_plan=wplan, corpus=corpus, warm=warm, sgns=res,
+            anchors=anchors, anchor_vecs=old_vecs, staged=staged,
+        )
 
         return RetrainReport(
             k0=plan.k0,
             core_size=int(len(plan.nodes)),
             drifted=plan.drifted,
-            n_walks=meta["n_walks"],
-            sgns_steps=meta["sgns_steps"],
-            warm_rows=meta["warm_rows"],
+            n_walks=int(wplan.n_real),
+            sgns_steps=int(res.n_steps),
+            warm_rows=warm_rows,
             anchors=align_rep["anchors"],
             aligned=align_rep["aligned"],
             align_residual=align_rep["residual"],

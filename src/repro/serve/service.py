@@ -865,12 +865,15 @@ class EmbeddingService:
         # BaseException and deliberately passes through. Only a device error
         # or an injected fault is absorbed; anything else, such as a kernel
         # the compiler refuses, is rolled back and re-raised.
-        pre = self.store.state_dict() if self.transactional_retrain else None
+        pre = None
         wd = self._watchdog
         if wd is not None:
             wd.arm()
         try:
             with obs.span("serve.retrain") as sp:
+                if self.transactional_retrain:
+                    with obs.span("retrain.rollback_copy"):
+                        pre = self.store.state_dict()
                 report = self.retrainer.run(between=between)
         except Exception as e:
             self.stats.retrain_failures += 1

@@ -189,11 +189,13 @@ class EmbeddingStore:
         vecs: np.ndarray,
         cores: np.ndarray,
         version: Optional[np.ndarray] = None,
+        min_rows: int = 1,
     ) -> None:
         """Insert/overwrite rows (batched device scatter; evicts LRU as needed).
 
         ``version`` may be a scalar or per-row array (promotion restores each
-        row's original write version); defaults to the store version.
+        row's original write version); defaults to the store version. The
+        scatter is padded to a power of two rows, at least ``min_rows``.
         """
         nodes = np.asarray(nodes, np.int64)
         vecs = np.asarray(vecs, np.float32)
@@ -223,7 +225,7 @@ class EmbeddingStore:
         # one batched scatter of the surviving slot->vector writes, padded to
         # a power-of-two row count (extra rows rewrite the zero sentinel row)
         # so eager .at[].set compiles O(log) distinct shapes
-        n_pad = pow2(len(staged))
+        n_pad = max(pow2(len(staged)), int(min_rows))
         slots_p = np.full(n_pad, self.capacity, np.int32)
         vecs_p = np.zeros((n_pad, self.dim), np.float32)
         for j, (s, vec) in enumerate(staged.items()):

@@ -66,8 +66,9 @@ def build_corpus(
             outs.append(node2vec_walks(ell, sub, length, k, p=p, q=q))
     walks = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
+    # slice on the host: a device slice would compile once per walk count
     counts = np.bincount(
-        np.asarray(walks[: plan.n_real]).reshape(-1), minlength=ell.n_nodes
+        np.asarray(walks)[: plan.n_real].reshape(-1), minlength=ell.n_nodes
     ).astype(np.float64)
     probs = counts**0.75
     total = probs.sum()

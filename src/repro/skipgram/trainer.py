@@ -9,19 +9,20 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
-from typing import Optional
+from functools import lru_cache, partial
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace as obs
 from repro.train import optim
 
 from .corpus import WalkCorpus, sample_batch
 from .model import batch_loss, init_params
 
-__all__ = ["SGNSConfig", "SGNSResult", "train_sgns"]
+__all__ = ["SGNSConfig", "SGNSResult", "train_sgns", "sgns_step"]
 
 
 @dataclasses.dataclass
@@ -42,6 +43,8 @@ class SGNSResult:
     n_steps: int
     train_seconds: float
     final_loss: float
+    params: Any = None  # the trained tables, on the device
+    opt_state: Any = None  # Adam's state after the last step
 
 
 @partial(jax.jit, static_argnames=("impl", "window", "n_neg", "batch", "opt_update"), donate_argnums=(0, 1))
@@ -60,6 +63,30 @@ def _train_step(params, opt_state, walks_nreal_cdf, key, *, impl, window, n_neg,
     return params, opt_state, loss
 
 
+@lru_cache(maxsize=None)
+def _adam(lr: float) -> optim.GradientTransform:
+    """One optimizer per learning rate: ``_train_step`` compiles per update
+    function, so a fresh closure on every call would recompile it."""
+    return optim.adam(lr)
+
+
+def sgns_step(params, opt_state, corpus: WalkCorpus, key, cfg: SGNSConfig):
+    """One jitted step: sample a batch from ``corpus`` with ``key``, loss,
+    gradients and Adam update. Donates ``params`` and ``opt_state``; returns
+    ``(params, opt_state, loss)``."""
+    return _train_step(
+        params,
+        opt_state,
+        (corpus.walks, corpus.n_real, corpus.noise_cdf),
+        key,
+        impl=cfg.impl,
+        window=cfg.window,
+        n_neg=cfg.n_neg,
+        batch=cfg.batch,
+        opt_update=_adam(cfg.lr).update,
+    )
+
+
 def train_sgns(
     corpus: WalkCorpus, cfg: SGNSConfig, *, params=None, steps: Optional[int] = None
 ) -> SGNSResult:
@@ -67,31 +94,26 @@ def train_sgns(
     kinit, ktrain = jax.random.split(key)
     if params is None:
         params = init_params(corpus.n_nodes, cfg.dim, kinit)
-    opt = optim.adam(cfg.lr)
-    opt_state = opt.init(params)
+    opt_state = _adam(cfg.lr).init(params)
     if steps is None:
         steps = max(1, int(cfg.epochs * corpus.pairs_per_epoch(cfg.window) // cfg.batch))
 
-    n_real = corpus.n_real
     loss = jnp.zeros(())
     t0 = time.perf_counter()
-    for s in range(steps):
-        params, opt_state, loss = _train_step(
-            params,
-            opt_state,
-            (corpus.walks, n_real, corpus.noise_cdf),
-            jax.random.fold_in(ktrain, s),
-            impl=cfg.impl,
-            window=cfg.window,
-            n_neg=cfg.n_neg,
-            batch=cfg.batch,
-            opt_update=opt.update,
-        )
-    loss = float(loss)
+    with obs.span("sgns.steps", steps=steps):
+        for s in range(steps):
+            params, opt_state, loss = sgns_step(
+                params, opt_state, corpus, jax.random.fold_in(ktrain, s), cfg
+            )
+    with obs.span("sgns.wait"):
+        loss = float(loss)
+        emb = np.asarray(params["emb_in"], dtype=np.float32)
     dt = time.perf_counter() - t0
     return SGNSResult(
-        embeddings=np.asarray(params["emb_in"], dtype=np.float32),
+        embeddings=emb,
         n_steps=steps,
         train_seconds=dt,
         final_loss=loss,
+        params=params,
+        opt_state=opt_state,
     )

@@ -313,8 +313,10 @@ def test_sgns_loss_positive_and_monotone_in_negatives(B, K, seed):
 def test_procrustes_alignment_is_orthogonal(n, d, seed):
     """The retraining aligner's rotation is always orthogonal: row norms and
     anchor dot products survive alignment within tolerance, for any pair of
-    anchor clouds (related by a planted rotation or not)."""
-    from repro.serve import procrustes_rotation
+    anchor clouds (related by a planted rotation or not). With at least d
+    anchors a planted rotation is recovered; with fewer it is not
+    determined, and the aligner leaves the table as it is."""
+    from repro.serve import EmbeddingAligner, procrustes_rotation
 
     rng = np.random.default_rng(seed)
     X = rng.standard_normal((n, d)).astype(np.float32)
@@ -333,7 +335,14 @@ def test_procrustes_alignment_is_orthogonal(n, d, seed):
         np.testing.assert_allclose(
             aligned @ aligned.T, X @ X.T, rtol=1e-3, atol=1e-3
         )
-    # and a planted rotation is recovered exactly (up to float error)
-    np.testing.assert_allclose(
-        procrustes_rotation(X, X @ planted), planted, atol=1e-3
-    )
+    out, rep = EmbeddingAligner().align(X, X @ planted, np.arange(n))
+    if n < d:
+        assert not rep["aligned"]
+        np.testing.assert_array_equal(out, X)
+    else:
+        # a planted rotation is recovered exactly (up to float error)
+        assert rep["aligned"]
+        np.testing.assert_allclose(
+            procrustes_rotation(X, X @ planted), planted, atol=1e-3
+        )
+        np.testing.assert_allclose(out, X @ planted, atol=1e-3)

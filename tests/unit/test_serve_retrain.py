@@ -90,12 +90,16 @@ def test_procrustes_is_orthogonal_even_for_unrelated_clouds():
 
 
 def test_aligner_identity_below_min_anchors():
+    # a rotation in DIM dimensions needs DIM anchors: one fewer is identity
     rng = np.random.default_rng(2)
-    emb = rng.normal(size=(10, DIM)).astype(np.float32)
-    aligner = EmbeddingAligner(min_anchors=8)
-    out, rep = aligner.align(emb, emb[:3], np.arange(3))
-    assert not rep["aligned"] and rep["anchors"] == 3
-    np.testing.assert_array_equal(out, emb)
+    emb = rng.normal(size=(2 * DIM, DIM)).astype(np.float32)
+    aligner = EmbeddingAligner()
+    for n in (3, DIM - 1):
+        out, rep = aligner.align(emb, emb[:n] + 1.0, np.arange(n))
+        assert not rep["aligned"] and rep["anchors"] == n
+        np.testing.assert_array_equal(out, emb)
+    out, rep = aligner.align(emb, emb[:DIM] + 1.0, np.arange(DIM))
+    assert rep["aligned"] and rep["anchors"] == DIM
 
 
 def test_aligner_maps_back_into_old_space():
@@ -103,7 +107,7 @@ def test_aligner_maps_back_into_old_space():
     old = rng.normal(size=(50, DIM)).astype(np.float32)
     R0 = _random_rotation(DIM, rng)
     new = old @ R0.T  # the fresh run landed in a rotated copy of the space
-    aligner = EmbeddingAligner(min_anchors=8)
+    aligner = EmbeddingAligner()
     anchors = np.arange(0, 50, 2)
     out, rep = aligner.align(new, old[anchors], anchors)
     assert rep["aligned"] and rep["residual"] < 1e-4
@@ -208,7 +212,7 @@ def test_retrain_warm_start_and_anchor_accounting():
     assert rep is not None
     assert rep.core_size == len(np.where(svc.cores.core >= rep.k0)[0])
     assert rep.warm_rows > 0  # persisted nodes seeded emb_in
-    assert rep.anchors >= 8 and rep.aligned
+    assert rep.anchors >= DIM and rep.aligned
     assert rep.rows_swapped >= rep.core_size  # propagation covers shells
     assert rep.staleness_after == 0.0
     assert rep.times["total"] > 0
