@@ -38,9 +38,10 @@ separately:
 trigger measures against. Each stage is a ``retrain.<stage>`` span; a
 refresh keeps its shapes from one run to the next (the walk corpus padded
 to a multiple of ``WALK_BUCKET`` walks, the subcore's ELL at a power-of-two
-width, the swap's scatters at ``swap_chunk`` rows), so a service that
-refreshes after every block compiles nothing once warm. ``Retrainer.last``
-keeps what the latest refresh computed, for inspection.
+width, the SGNS tables at the corpus's support padded to a multiple of
+``trainer.LOCAL_BUCKET`` rows, the swap's scatters at ``swap_chunk`` rows),
+so a service that refreshes after every block compiles nothing once warm.
+``Retrainer.last`` keeps what the latest refresh computed, for inspection.
 """
 from __future__ import annotations
 
@@ -451,7 +452,7 @@ class Retrainer:
         with _stage("train", times) as sp:
             params, warm, warm_rows = self._warm_table(plan)
             res, pairs = self._train(corpus, params)
-            sp.set(steps=int(res.n_steps), pairs=pairs)
+            sp.set(steps=int(res.n_steps), pairs=pairs, rows=res.rows)
         emb = res.embeddings
 
         svc.pet_watchdog()

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,7 @@ class WalkCorpus:
     length: int
     noise_cdf: jnp.ndarray  # (V,) float32 cumulative unigram^0.75
     n_nodes: int
+    counts: Optional[np.ndarray] = None  # (V,) token counts of the real walks
 
     @property
     def n_tokens(self) -> int:
@@ -80,6 +82,7 @@ def build_corpus(
         length=length,
         noise_cdf=cdf,
         n_nodes=ell.n_nodes,
+        counts=counts,
     )
 
 
