@@ -76,8 +76,7 @@ SCHEMA_PATH = os.path.join(
 
 def _ingest_run(g, block_size: int, *, seed: int, churn: float = 0.0,
                 compact_every: int = 1024, max_edges: int = 0,
-                shards: int = 1, repair_policy: str = "adaptive",
-                pipeline: bool = True, slo: bool = False):
+                shards: int = 1, slo: bool = False):
     """Fresh service; stream held-out edges in blocks.
 
     Returns ``(service, metrics dict)`` — the fully ingested service so the
@@ -88,7 +87,6 @@ def _ingest_run(g, block_size: int, *, seed: int, churn: float = 0.0,
     """
     svc, stream_edges, _, _ = build_service(
         g, seed=seed, compact_every=compact_every, shards=shards,
-        repair_policy=repair_policy, pipeline=pipeline,
     )
     if slo:
         svc.attach_slo()
@@ -128,8 +126,8 @@ def _ingest_run(g, block_size: int, *, seed: int, churn: float = 0.0,
         # region / candidate-build / descend / fallback split, each tagged
         # with the backend it ran on (host numpy vs jitted device path)
         "phases": svc.cores.phase_report(),
-        # per-block repair-policy decisions, predicted-vs-actual phase cost,
-        # and the shell-incremental re-peel depth histogram
+        # per-block repair-policy decisions and predicted-vs-actual phase
+        # cost
         "policy": svc.cores.policy_report(),
     }
 
@@ -768,7 +766,6 @@ def _overhead_guard(*, seed: int, repeats: int = 6, block_size: int = 1024):
 def run(quick: bool = False, seed: int = 0, shards: int = 1,
         retrain: bool = False, trace: str = None, metrics_out: str = None,
         jax_profile: str = None, assert_overhead: float = None,
-        repair_policy: str = "adaptive", pipeline: bool = True,
         recovery: bool = False, topk: bool = False,
         history: str = "results/history/serve_latency.jsonl"):
     n = 1000 if quick else 4000
@@ -807,7 +804,6 @@ def run(quick: bool = False, seed: int = 0, shards: int = 1,
                 g, bs, seed=seed,
                 compact_every=256 if quick else 1024,
                 max_edges=BASELINE_CAP if bs == 1 else 0,
-                repair_policy=repair_policy, pipeline=pipeline,
             )
             sweep.append(metrics)
     base_eps = sweep[0]["edges_per_s"]
@@ -821,7 +817,6 @@ def run(quick: bool = False, seed: int = 0, shards: int = 1,
     _, churn_run = _ingest_run(
         g, 256, seed=seed + 1, churn=0.25,
         compact_every=256 if quick else 1024,
-        repair_policy=repair_policy, pipeline=pipeline,
     )
 
     # --- h-index kernel backends (the Pallas kernel measured directly)
@@ -915,7 +910,6 @@ def run(quick: bool = False, seed: int = 0, shards: int = 1,
         "cold_start_fraction": float(st.cold_fraction),
         "unresolved": int(st.unresolved),
         "sharding": sharded if sharded is not None else {"n_shards": 1},
-        "repair_policy": {"mode": repair_policy, "pipeline": bool(pipeline)},
         "hindex_kernel": hindex_sec,
         "obs": obs_section,
         "slo": svc.slo_health(),
@@ -991,9 +985,7 @@ def run(quick: bool = False, seed: int = 0, shards: int = 1,
                  f"block256_vs_per_edge={speedup_256:.1f}x"),
         csv_line(
             "serve_repair_policy", 0.0,
-            f"mode={repair_policy};pipeline={int(pipeline)};"
-            f"decisions={best['policy']['decisions']};"
-            f"shell_repeels={best['policy']['shell_repeel']['count']}",
+            f"decisions={best['policy']['decisions']}",
         ),
     ]
     lines += [
@@ -1149,13 +1141,6 @@ def main(argv=None):
                     metavar="PCT",
                     help="fail the run if enabling tracing slows the "
                          "largest-block ingest by more than PCT percent")
-    ap.add_argument("--repair-policy", default="adaptive",
-                    choices=["adaptive", "region", "fallback"],
-                    help="block core-repair decision rule (A/B runs: "
-                         "region = legacy static trigger, fallback = "
-                         "always re-peel)")
-    ap.add_argument("--no-pipeline", action="store_true",
-                    help="disable pipelined block ingest (serial staging)")
     ap.add_argument("--history", default="results/history/serve_latency.jsonl",
                     metavar="PATH",
                     help="JSON-lines history file this run appends its "
@@ -1170,8 +1155,6 @@ def main(argv=None):
                     metrics_out=args.metrics_out,
                     jax_profile=args.jax_profile,
                     assert_overhead=args.assert_overhead,
-                    repair_policy=args.repair_policy,
-                    pipeline=not args.no_pipeline,
                     recovery=args.recovery, topk=args.topk,
                     history=None if args.no_history else args.history):
         print(line)

@@ -18,7 +18,7 @@ too: ``core_mask`` (k-core membership) and ``shells`` (nodes per core index).
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, Tuple
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +30,6 @@ from repro.kernels import ops as _kernel_ops
 __all__ = [
     "core_numbers_host",
     "core_numbers_rounds",
-    "core_numbers_shell_peel",
     "core_numbers_jax",
     "h_index_sweep",
     "degeneracy",
@@ -120,69 +119,6 @@ def core_numbers_rounds(n_nodes: int, arc_src: np.ndarray,
     return core
 
 
-def core_numbers_shell_peel(
-    n_nodes: int,
-    arc_src: np.ndarray,
-    arc_dst: np.ndarray,
-    peel: np.ndarray,
-    degrees: np.ndarray,
-    hi: int,
-) -> Tuple[np.ndarray, bool]:
-    """Boundary-frozen rounds peel of the sub-level set ``peel``.
-
-    Incremental counterpart of :func:`core_numbers_rounds`: only the nodes in
-    ``peel`` (the shells at level ``<= hi`` *before* the mutation block) are
-    re-peeled; everything above stays frozen and acts purely as boundary
-    support. ``degrees`` must be every node's **full** current degree (frozen
-    neighbours included), and ``arc_src``/``arc_dst`` only the arcs with both
-    endpoints inside ``peel`` — peeling a node therefore decrements peel-side
-    neighbours only, while its frozen support is baked into the starting
-    degrees, exactly as if the upper shells were peeled last.
-
-    Returns ``(core, ok)`` where ``core`` holds the recomputed levels of the
-    ``peel`` nodes (untouched elsewhere). Soundness: anchoring the frozen
-    side *over-estimates* the peel side pointwise, so if the frozen
-    assumption is wrong (the block pushed some peeled node past ``hi``,
-    which could in turn invalidate frozen levels) the over-estimate must
-    also push a node past ``hi`` — detected as a survivor whose remaining
-    degree exceeds ``hi``, returned as ``ok=False`` with the result
-    discarded. ``ok=True`` certifies the freeze and makes the result exact.
-    With no insertions (levels only fall) a window top ``hi >= `` the max
-    touched level can never ceiling-hit.
-    """
-    n = int(n_nodes)
-    core = np.zeros(n, np.int32)
-    if n == 0:
-        return core, True
-    arc_src = np.asarray(arc_src, np.int64)
-    arc_dst = np.asarray(arc_dst, np.int64)
-    deg = np.asarray(degrees, np.int64).copy()
-    active = np.asarray(peel, bool).copy()
-    core[active] = 0  # isolated / degree-0 peel nodes resolve to level 0
-    active &= deg > 0
-    k = 0
-    n_active = int(active.sum())
-    while n_active:
-        k = max(k, int(deg[active].min()))
-        if k > hi:  # survivor past the ceiling: freeze assumption violated
-            return core, False
-        frontier = active & (deg <= k)
-        while frontier.any():
-            core[frontier] = k
-            active &= ~frontier
-            n_active -= int(frontier.sum())
-            m = frontier[arc_src] & active[arc_dst]
-            if m.any():
-                deg -= np.bincount(arc_dst[m], minlength=n)
-            frontier = active & (deg <= k)
-        if len(arc_src) > 1024:  # same geometric arc-drop as the full peel
-            keep = active[arc_src]
-            keep &= active[arc_dst]
-            if int(keep.sum()) * 2 < len(arc_src):
-                arc_src, arc_dst = arc_src[keep], arc_dst[keep]
-    return core, True
-
-
 def h_index_sweep(values: jnp.ndarray, valid: jnp.ndarray,
                   est: jnp.ndarray, *, impl: str = "ref") -> jnp.ndarray:
     """One row-masked h-index repair sweep (the shared operator).
@@ -235,7 +171,7 @@ def core_numbers_jax(ell: EllGraph, max_sweeps: int = 256,
     and the counting search elsewhere (XLA sort is the slow path on both).
     """
     if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "count"
+        impl = _kernel_ops.h_index_impl()
     core, _ = _core_fixpoint(ell.neighbours, ell.degrees, max_sweeps, impl)
     return core[: ell.n_nodes]
 

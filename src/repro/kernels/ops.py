@@ -25,6 +25,7 @@ from .ellmean import ell_mean_pallas
 __all__ = [
     "sgns_loss",
     "ell_mean",
+    "h_index_impl",
     "h_index_sweep",
     "decode_attention",
     "top_k_scores",
@@ -129,6 +130,12 @@ def ell_mean(idx, valid, emb, *, impl: str = "auto"):
 # -------------------------------------------------------------- h-index ----
 
 
+def h_index_impl() -> str:
+    """What ``impl="auto"`` means for the h-index sweep on this backend:
+    the Pallas kernel on TPU, the sort-free counting search elsewhere."""
+    return "pallas" if _on_tpu() else "count"
+
+
 def h_index_sweep(values, valid, est, *, impl: str = "auto"):
     """One row-masked h-index repair sweep: ``min(est, H(row))``.
 
@@ -140,7 +147,7 @@ def h_index_sweep(values, valid, est, *, impl: str = "auto"):
     "pallas_interpret" (the ``kernels.hindex`` kernel).
     """
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "count"
+        impl = h_index_impl()
     if impl == "ref":
         return _ref.h_index_ref(values, valid, est)
     if impl == "count":

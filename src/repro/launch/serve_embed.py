@@ -82,10 +82,6 @@ def build_service(
     refresh=None,
     retrain_threshold: float = 0.1,
     retrain_budget: int = 0,
-    repair_policy: str = "adaptive",
-    crossover_margin: float = 1.0,
-    cold_cells_per_arc: float = 32.0,
-    pipeline: bool = True,
 ):
     """Returns (service, stream_edges, base_core, k0).
 
@@ -96,11 +92,10 @@ def build_service(
     block the service re-checks ``retrain_pressure`` against
     ``retrain_threshold`` and, while ``retrain_budget`` allows, refreshes the
     k0-core embeddings (CoreWalk+SGNS warm start, Procrustes alignment,
-    chunked hot swap) in place. ``repair_policy`` selects the
-    block-repair decision rule (``adaptive`` measured crossover /
-    ``region`` legacy static trigger / ``fallback`` always re-peel) and
-    ``pipeline`` overlaps block staging with the in-flight descent — both
-    exist so A/B runs can reach every old behaviour.
+    chunked hot swap) in place. Block repair takes the one device path of
+    ``IncrementalCore``, its measured policy choosing descend or re-peel
+    per block, and ingest overlaps each block's staging with the previous
+    block's in-flight descent.
     """
     plan = ShardPlan.build(shards)
     base_edges, stream_edges = _split_stream(g, stream_frac, seed)
@@ -144,15 +139,11 @@ def build_service(
     )
     store.put_many(served, emb[served], core[served])
 
-    inc = IncrementalCore(
-        base, core, repair_policy=repair_policy,
-        crossover_margin=crossover_margin,
-        cold_cells_per_arc=cold_cells_per_arc,
-    )
+    inc = IncrementalCore(base, core)
     inc.mark_refresh()
     svc = EmbeddingService(
         base, inc, store, batch=batch, compact_every=compact_every, k0=k0,
-        retrain_threshold=retrain_threshold, pipeline=pipeline,
+        retrain_threshold=retrain_threshold,
     )
     if refresh is not None:
         from repro.serve.retrain import Retrainer
@@ -209,20 +200,6 @@ def main(argv=None):
                          "a retrain")
     ap.add_argument("--retrain-budget", type=int, default=2,
                     help="max drift-triggered retrains per run (0 = no cap)")
-    ap.add_argument("--repair-policy", default="adaptive",
-                    choices=["adaptive", "region", "fallback"],
-                    help="block core-repair decision rule: adaptive = "
-                         "measured descend-vs-repeel crossover (default), "
-                         "region = legacy static candidate-region trigger, "
-                         "fallback = always re-peel")
-    ap.add_argument("--crossover-margin", type=float, default=1.0,
-                    help="adaptive policy prefers the fused descent while "
-                         "predicted descend cost <= margin * repeel cost")
-    ap.add_argument("--cold-cells-per-arc", type=float, default=32.0,
-                    help="cold-start shape heuristic: descend while padded "
-                         "cells <= this many per affected-shell arc")
-    ap.add_argument("--no-pipeline", action="store_true",
-                    help="disable pipelined block ingest (serial staging)")
     ap.add_argument("--verify", action="store_true",
                     help="assert incremental cores match the oracle at the end")
     ap.add_argument("--score-frac", type=float, default=0.3,
@@ -279,10 +256,6 @@ def main(argv=None):
         refresh=_refresh_config(args),
         retrain_threshold=args.retrain_threshold,
         retrain_budget=args.retrain_budget,
-        repair_policy=args.repair_policy,
-        crossover_margin=args.crossover_margin,
-        cold_cells_per_arc=args.cold_cells_per_arc,
-        pipeline=not args.no_pipeline,
     )
     print(f"[serve-embed] base: {svc.graph.n_edges} edges, k0={k0}, "
           f"store {svc.store.resident}/{svc.store.capacity} resident")
@@ -359,11 +332,9 @@ def main(argv=None):
               f"({svc.cores.descends} fused descents, "
               f"{svc.cores.sweeps} sweeps)")
     pol = svc.cores.policy_report()
-    print(f"[serve-embed] repair policy[{pol['mode']}]: "
-          f"decisions {pol['decisions']} (cold {pol['cold_decisions']}), "
-          f"shell re-peels {pol['shell_repeel']['count']} "
-          f"(widened {pol['shell_repeel']['widens']}, mean frac peeled "
-          f"{pol['shell_repeel']['mean_frac_peeled']})")
+    print(f"[serve-embed] repair policy: "
+          f"decisions {pol['decisions']} (cold {pol['cold_decisions']}, "
+          f"probes {pol['probes']})")
     st_i = svc.stats
     if st_i.degraded_queries or st_i.retrain_failures or st_i.hangs:
         print(f"[serve-embed] degradation: {st_i.degraded_queries} degraded "

@@ -18,56 +18,41 @@ Block repair (``on_edge_block`` / ``on_remove`` / ``on_update``):
   (``min(new_degree, old_core + #inserted)``, one vectorized gather from the
   graph's maintained degree array) and swept with the *same* row-masked
   h-index operator the offline device fixpoint uses
-  (``repro.kernels.ops.h_index_sweep``, Pallas-backed on TPU), with
-  non-candidate neighbours frozen at their true (unchanged) core numbers.
-  The operator is monotone, so the sweep descends to the exact new core
-  numbers (Lü et al. 2016).
+  (``repro.kernels.ops.h_index_sweep``), with non-candidate neighbours
+  frozen at their true (unchanged) core numbers. The operator is monotone,
+  so the sweep descends to the exact new core numbers (Lü et al. 2016).
 * A block can cascade promotions/demotions across several levels, so the
   window half-width is **adaptive**: the repair re-runs with a wider window
   whenever the computed level changes touch the window boundary. Single-edge
   repairs never widen.
-* **Measured repair policy** (``repair_policy="adaptive"``, the default):
-  instead of the old static trigger (abort region discovery past
-  ``repeel_frac * n`` and re-peel the whole graph — which on real block sizes
-  meant the fused descent *never* ran), the maintainer predicts both paths'
-  cost from per-regime EMAs of its own measured phase seconds (the same
-  intervals exported as ``repair_phase_seconds{phase=}`` through the metrics
-  registry, which also warm-starts the priors across maintainer instances in
-  one process) and runs whichever is cheaper. Cold start — before either
-  path has been measured — falls back to a shape heuristic: descend unless
-  the padded candidate matrix dwarfs the affected-shell arc mass
-  (``cold_cells_per_arc``) or busts ``descend_budget``. ``"region"``
-  restores the legacy static trigger for A/B runs; ``"fallback"`` always
-  re-peels.
-* **Shell-incremental re-peel**: when re-peeling *is* chosen (or forced by a
-  truncated descent), only the shells at level ``<= hi`` (the repair
-  window's top) are re-peeled — upper shells are frozen and enter as
-  boundary degrees (``core.kcore.core_numbers_shell_peel``), so fallback
-  cost scales with the affected sub-level set, not the graph. A survivor
-  past ``hi`` disproves the freeze (possible only under insertions) and
-  widens ``hi`` until certified; deletions-only blocks can never hit the
-  ceiling. Exactness argument in ``core_numbers_shell_peel``'s docstring.
+* **Measured repair policy** (:class:`RepairPolicy`): the descent over the
+  region and a re-peel of the whole graph are both exact, so the choice
+  between them is cost only. The policy predicts both paths' cost from
+  per-regime EMAs of its own measured phase seconds (the same intervals
+  exported as ``repair_phase_seconds{phase=}`` through the metrics registry,
+  which also warm-starts the priors across maintainer instances in one
+  process) and runs whichever is cheaper. Before the descent has been
+  measured it falls back to a shape heuristic: descend unless the padded
+  candidate matrix dwarfs the affected-shell arc mass.
 * **Pipelined handoff**: ``begin_update`` runs region discovery + the policy
   decision and *dispatches* the fused descent without reading it back
   (``jax`` async dispatch); ``finish_update`` blocks on the result, runs any
   window widenings, and commits. ``on_update`` is simply the two
   back-to-back; the serving layer calls them split so block N+1's host-side
   dedup/scatter overlaps block N's in-flight descent. Every other public
-  entry point settles an in-flight ticket first, so results are
-  bit-identical to the serial path.
+  entry point settles an in-flight ticket first, so the results are those
+  of ``on_update``.
 
-Device-resident path (``impl="device"``, the ``"auto"`` default) — every
-repair stage is vectorized or fused:
+One repair path on every backend (``impl="device"``, the default), every
+stage vectorized or fused:
 
 * **Region growing** is a frontier-masked traversal: boolean frontier /
   visited masks expanded one level per step with the ``[lo, hi]`` core-window
   filter applied in bulk, plus a static-shaped **side table** of extra arcs
   (the removed block edges, so deletions keep their discovery path, and the
-  overflow arcs the device mirror cannot see between compactions). On TPU it
-  runs as a jitted ``lax.while_loop`` over the ``DynamicGraph`` device ELL
-  mirror (``_region_fixpoint``); elsewhere the same traversal runs as
-  vectorized numpy over the host table, where XLA scatters lose to the host.
-  Both are bounded: discovery aborts early once it exceeds the fallback cap.
+  overflow arcs the device mirror cannot see between compactions), as one
+  jitted ``lax.while_loop`` over the ``DynamicGraph`` device ELL mirror
+  (``_region_fixpoint``).
 * **Candidate matrices** are degree tiers: each row goes in the narrowest
   width of a fixed ladder (32, 128, 512, then pow2 of the largest candidate
   degree) that holds it, and each tier is one vectorized gather
@@ -77,17 +62,19 @@ repair stage is vectorized or fused:
   seeding, every sweep over every tier, the convergence test, and the
   adaptive-window boundary statistics all run inside a single
   ``lax.while_loop`` dispatch — no per-iteration ``est[cand]`` ping-pong
-  between host and device. Each sweep applies ``kernels.ops.h_index_sweep``
-  (the Pallas kernel on TPU, the sort-free counting search elsewhere).
-* **The fallback** is the same fused descent seeded over *all* nodes on TPU
-  (still one dispatch); off-TPU it is the vectorized rounds peel
-  (``core_numbers_rounds``) fed straight from the graph's arc arrays.
+  between host and device. Each sweep applies the h-index kernel that
+  ``kernels.ops.h_index_impl`` names (the Pallas kernel on TPU, the
+  sort-free counting search elsewhere).
+* **The re-peel** is the same fused descent seeded over *all* nodes (still
+  one dispatch). Should the sweep cap truncate it, the vectorized rounds
+  peel (``core_numbers_rounds``) over the graph's arc arrays recovers.
 
-The PR 2 host path survives as ``impl="ref"`` — the dict/set BFS, the
-per-iteration jitted sweep, and the snapshot re-peel — and doubles as the
-correctness oracle for the device path. ``phase_report()`` exposes per-phase
-wall time (region / candidates / descend / fallback) and which backend each
-phase ran on, so benchmarks can show *where* repair time goes.
+``impl="ref"`` is the correctness oracle for that path: the dict/set BFS,
+the per-iteration jitted host sweep, and the snapshot re-peel
+(``core_numbers_host``). The snapshot peel also repairs a block whose device
+dispatch keeps failing (``_recover_ref``). ``phase_report()`` exposes
+per-phase wall time (region / candidates / descend / fallback) and which
+backend each phase ran on, so benchmarks can show *where* repair time goes.
 
 Core-number **drift** (how many nodes changed level since the embedding table
 was last refreshed) is the staleness signal the store/service use to gate
@@ -111,7 +98,6 @@ from repro.core.kcore import (
     _h_index_sweep_jit,
     core_numbers_host,
     core_numbers_rounds,
-    core_numbers_shell_peel,
 )
 from repro.kernels import ops as kops
 from repro.obs import metrics
@@ -139,9 +125,13 @@ _SIDE_FLOOR = 1024
 # size-distribution buckets (region node counts): powers of 4 up to ~4M
 _COUNT_BUCKETS = 4.0 ** np.arange(12)
 
+# the measured crossover runs the descent while its predicted cost is at
+# most this multiple of the re-peel's
+_CROSSOVER_MARGIN = 1.0
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+# cold-start shape heuristic: descend while the padded candidate cells are
+# at most this many per arc of the shells a re-peel would touch
+_COLD_CELLS_PER_ARC = 32.0
 
 
 @partial(jax.jit, static_argnames=("impl", "max_sweeps"))
@@ -201,16 +191,14 @@ def _fused_descent(tiers, cand, seed, old, est_full, lo, hi, *,
 
 @jax.jit
 def _region_fixpoint(nbr, deg, hubs, core, ends, side_src, side_dst,
-                     side_valid, lo, hi, cap):
+                     side_valid, lo, hi):
     """Frontier-masked union-subcore traversal, one jitted while-loop.
 
     ``nbr``/``deg`` are the device ELL mirror; ``side_*`` is the padded side
     table of extra arcs (removed block edges + overflow arcs the mirror
     cannot see). Expands boolean frontier/visited masks one level per
     iteration, filtering discovered nodes by old core in ``[lo, hi]``;
-    endpoints are pre-seeded regardless of their level. Aborts early once
-    the visited count exceeds ``cap`` (the caller falls back to a full
-    recompute, so a partial region is never used).
+    endpoints are pre-seeded regardless of their level.
 
     The mirror is as wide as its largest row, and on a power-law graph
     nearly all of it is padding. So each level sweeps two tiers: the first
@@ -228,23 +216,18 @@ def _region_fixpoint(nbr, deg, hubs, core, ends, side_src, side_dst,
     )
     eligible = (core >= lo) & (core <= hi)
 
-    def cond(state):
-        frontier, _, count = state
-        return jnp.logical_and(frontier.any(), count <= cap)
-
     def body(state):
-        frontier, visited, _ = state
+        frontier, visited = state
         nxt = jnp.zeros(n1, bool).at[narrow].max(frontier[:, None] & valid)
         nxt = nxt.at[hub_nbr].max(frontier[hubs][:, None] & hub_valid)
         nxt = nxt.at[side_dst].max(frontier[side_src] & side_valid)
         newf = nxt & eligible & ~visited
-        visited = visited | newf
-        return newf, visited, jnp.sum(visited)
+        return newf, visited | newf
 
-    _, visited, count = jax.lax.while_loop(
-        cond, body, (ends, ends, jnp.sum(ends))
+    _, visited = jax.lax.while_loop(
+        lambda state: state[0].any(), body, (ends, ends)
     )
-    return visited, count
+    return visited
 
 
 def _pad_rows(idx: np.ndarray, valid: np.ndarray, r_pad: int, sentinel: int):
@@ -261,8 +244,8 @@ def _pad_rows(idx: np.ndarray, valid: np.ndarray, r_pad: int, sentinel: int):
 class RepairPolicy:
     """Measured-crossover choice of which *exact* repair path runs.
 
-    Both paths (window-validated fused descent, shell-incremental re-peel)
-    are exact, so the policy only affects cost, never results. Per decision
+    Both paths (window-validated fused descent, whole-graph re-peel) are
+    exact, so the policy only affects cost, never results. Per decision
     it predicts each path's wall time at the block's work scale — descend
     work = padded candidate-matrix cells, re-peel work = affected-shell arc
     mass — from an EMA kept per ``(path, regime)`` where a regime is a
@@ -273,33 +256,16 @@ class RepairPolicy:
     warm-starts absolute priors from the live histograms (so a fresh
     maintainer in a warmed process doesn't start cold), and every
     observation lands back in the registry via the phase histograms.
-
-    Modes: ``adaptive`` (measured crossover, the default), ``region`` (the
-    legacy PR 3 static trigger: region capped at ``repeel_frac * n``,
-    ``descend_budget`` bound, full-graph re-peel), ``fallback`` (always
-    re-peel; with the shell-incremental path when a window is available).
     """
-
-    MODES = ("adaptive", "region", "fallback")
 
     def __init__(
         self,
-        mode: str = "adaptive",
         *,
         alpha: float = 0.25,
-        crossover_margin: float = 1.0,
-        cold_cells_per_arc: float = 32.0,
         probe_every: int = 6,
         history: int = 512,
     ):
-        if mode not in self.MODES:
-            raise ValueError(
-                f"unknown repair policy {mode!r}; expected one of {self.MODES}"
-            )
-        self.mode = mode
         self.alpha = float(alpha)
-        self.crossover_margin = float(crossover_margin)
-        self.cold_cells_per_arc = float(cold_cells_per_arc)
         self.probe_every = int(probe_every)
         self._ema: dict = {}  # (path, regime) -> [ema_seconds, ema_work]
         self._prior: dict = {}  # path -> absolute prior seconds (registry)
@@ -362,12 +328,12 @@ class RepairPolicy:
                 (path, int(work), float(pend[1]), float(seconds))
             )
 
-    def choose(self, *, cells: int, repeel_work: int, budget: int) -> str:
+    def choose(self, *, cells: int, repeel_work: int) -> str:
         """``"descend"`` or ``"repeel"`` for one block repair.
 
         ``cells``: padded candidate-matrix area the fused descent would
         sweep; ``repeel_work``: arc mass of the shells a re-peel would
-        touch; ``budget``: hard cold-start cap on ``cells``.
+        touch.
         """
         pd = self._measured("descend", cells)
         pred = None
@@ -376,22 +342,25 @@ class RepairPolicy:
             # (possibly from some other maintainer's regime) must not starve
             # it, or the crossover never gets data. Run it unless the shape
             # heuristic says the padded matrix dwarfs the affected-shell
-            # arc mass (block size x shell span) or busts the budget.
+            # arc mass (block size x shell span).
             self.cold_decisions += 1
-            cold_ok = cells <= self.cold_cells_per_arc * max(repeel_work, 64)
-            choice = "descend" if (cold_ok and cells <= budget) else "repeel"
+            choice = (
+                "descend"
+                if cells <= _COLD_CELLS_PER_ARC * max(repeel_work, 64)
+                else "repeel"
+            )
         else:
             pr = self._measured("repeel", repeel_work)
             if pr is None:
                 # descend is measured but the re-peel side never has been —
-                # explore it once (it is exact too, and cheap at shell
-                # granularity) so the crossover gets data for both paths
-                # instead of riding the first measurement forever
+                # explore it once (it is exact too) so the crossover gets
+                # data for both paths instead of riding the first
+                # measurement forever
                 choice = "repeel"
                 pred = self._prior.get("repeel")
             else:
                 choice = (
-                    "descend" if pd <= self.crossover_margin * pr
+                    "descend" if pd <= _CROSSOVER_MARGIN * pr
                     else "repeel"
                 )
                 pred = pd if choice == "descend" else pr
@@ -424,7 +393,6 @@ class RepairPolicy:
         for d in by.values():
             d["mean_abs_rel_err"] = round(d.pop("_err") / d["n"], 3)
         return {
-            "mode": self.mode,
             "decisions": dict(self.decisions),
             "cold_decisions": int(self.cold_decisions),
             "probes": int(self.probes),
@@ -444,39 +412,36 @@ class _RepairTicket:
     ``finish_update`` needs to validate the window and commit.
     """
 
-    __slots__ = ("done", "changed", "pending", "ctx", "margin", "lo", "hi",
-                 "cand")
+    __slots__ = ("done", "changed", "pending", "ctx", "margin", "lo", "cand")
 
     def __init__(self, *, changed=None, pending=None, ctx=None, margin=0,
-                 lo=0, hi=0, cand=None):
+                 lo=0, cand=None):
         self.done = changed is not None
         self.changed = int(changed or 0)
         self.pending = pending
         self.ctx = ctx
         self.margin = margin
         self.lo = lo
-        self.hi = hi
         self.cand = cand
 
 
 class IncrementalCore:
+    """Block-repaired core numbers of a :class:`DynamicGraph`.
+
+    ``impl``: ``"device"`` (the one repair path, on every backend) or
+    ``"ref"`` (its host oracle). ``policy`` chooses descend or re-peel per
+    block; tests pass a double to force one path.
+    """
+
     def __init__(
         self,
         g: DynamicGraph,
         core: Optional[np.ndarray] = None,
         *,
-        repeel_frac: float = 0.6,
         margin0: int = 8,
-        impl: str = "auto",
-        region_impl: Optional[str] = None,
-        kernel_impl: Optional[str] = None,
-        repeel_impl: Optional[str] = None,
-        descend_budget: int = 1 << 20,
+        impl: str = "device",
         max_sweeps: int = 512,
-        repair_policy: str = "adaptive",
         policy: Optional[RepairPolicy] = None,
-        crossover_margin: float = 1.0,
-        cold_cells_per_arc: float = 32.0,
     ):
         self.g = g
         if core is None:
@@ -487,22 +452,12 @@ class IncrementalCore:
             )
         self._core = np.asarray(core, np.int32).copy()
         self._baseline = self._core.copy()  # levels at last embedding refresh
-        self.repeel_frac = float(repeel_frac)
         self.margin0 = int(margin0)
-        if impl not in ("auto", "ref", "device"):
+        if impl not in ("device", "ref"):
             raise ValueError(f"unknown impl {impl!r}")
         self.impl = impl
-        self.region_impl = region_impl  # None=auto | "jit" | "np"
-        self.kernel_impl = kernel_impl  # None=auto | ops.h_index_sweep impl
-        # None=auto | "shell"|"descend"|"rounds"|"peel"
-        self.repeel_impl = repeel_impl
-        self.descend_budget = int(descend_budget)
         self.max_sweeps = int(max_sweeps)
-        self.policy = policy if policy is not None else RepairPolicy(
-            repair_policy,
-            crossover_margin=crossover_margin,
-            cold_cells_per_arc=cold_cells_per_arc,
-        )
+        self.policy = policy if policy is not None else RepairPolicy()
         self.repairs = 0
         self.sweeps = 0
         self.descends = 0
@@ -515,9 +470,6 @@ class IncrementalCore:
         self.promoted = 0
         self.demoted = 0
         self.repeels = 0
-        self.shell_repeels = 0  # re-peels that stayed shell-incremental
-        self.shell_widens = 0  # ceiling hits that widened the peel window
-        self._shell_depths: list = []  # (hi, peeled_nodes, n) per shell peel
         self._inflight: Optional[_RepairTicket] = None
         self.phase_seconds: dict = {}
         self.phase_impl: dict = {}
@@ -541,30 +493,7 @@ class IncrementalCore:
         return exe
 
     def _device(self) -> bool:
-        return self.impl != "ref"
-
-    def _region_mode(self) -> str:
-        if not self._device():
-            return "ref"
-        if self.region_impl:
-            return self.region_impl
-        return "jit" if _on_tpu() else "np"
-
-    def _kernel_mode(self) -> str:
-        if self.kernel_impl:
-            return self.kernel_impl
-        return "pallas" if _on_tpu() else "count"
-
-    def _repeel_mode(self) -> str:
-        if not self._device():
-            return "peel"
-        if self.repeel_impl:
-            return self.repeel_impl
-        if _on_tpu():
-            return "descend"
-        # legacy "region" policy keeps the PR 3 full-graph rounds peel so
-        # A/B runs against the old trigger measure the old fallback too
-        return "rounds" if self.policy.mode == "region" else "shell"
+        return self.impl == "device"
 
     def _tick(self, phase: str, mode: str, t0: float, t1: float) -> None:
         """Charge ``[t0, t1]`` to ``phase``.
@@ -590,27 +519,9 @@ class IncrementalCore:
         }
 
     def policy_report(self) -> dict:
-        """Repair-policy decisions + shell re-peel depth for one maintainer.
-
-        Extends :meth:`RepairPolicy.report` with the shell-incremental
-        re-peel telemetry: how many re-peels stayed incremental, how often
-        a ceiling hit widened the peel window, and a histogram of the peel
-        depth (``hi``) and peeled-node fraction.
-        """
+        """:meth:`RepairPolicy.report` plus this maintainer's re-peel and
+        fused-descent counts."""
         rep = self.policy.report()
-        depth_hist: dict = {}
-        frac_sum = 0.0
-        for hi, peeled, n in self._shell_depths:
-            depth_hist[str(hi)] = depth_hist.get(str(hi), 0) + 1
-            frac_sum += peeled / max(n, 1)
-        rep["shell_repeel"] = {
-            "count": int(self.shell_repeels),
-            "widens": int(self.shell_widens),
-            "depth_hist": depth_hist,
-            "mean_frac_peeled": round(
-                frac_sum / max(len(self._shell_depths), 1), 4
-            ),
-        }
         rep["repeels"] = int(self.repeels)
         rep["descends"] = int(self.descends)
         return rep
@@ -681,33 +592,7 @@ class IncrementalCore:
                     stack.append(x)
         return sorted(seen)
 
-    def _region_np(self, ends, lo, hi, side_src, side_dst, cap):
-        """Vectorized host frontier traversal (same masks as the jitted
-        device loop, minus the dispatch). Returns None once past ``cap``."""
-        g = self.g
-        n, n1 = g.n_nodes, g.node_cap + 1
-        eligible = np.zeros(n1, bool)
-        eligible[:n] = (self._core[:n] >= lo) & (self._core[:n] <= hi)
-        visited = np.zeros(n1, bool)
-        visited[ends] = True
-        frontier = visited.copy()
-        width_iota = np.arange(g.width)
-        while frontier.any():
-            rows = np.where(frontier)[0]
-            live = width_iota[None, :] < g._deg[rows][:, None]
-            nxt = np.zeros(n1, bool)
-            nxt[g._nbr[rows][live]] = True
-            if len(side_src):
-                sm = frontier[side_src]
-                if sm.any():
-                    nxt[side_dst[sm]] = True
-            frontier = nxt & eligible & ~visited
-            visited |= frontier
-            if int(visited.sum()) > cap:
-                return None
-        return np.where(visited[:n])[0].astype(np.int64)
-
-    def _region_device(self, ends, lo, hi, side_src, side_dst, cap):
+    def _region_device(self, ends, lo, hi, side_src, side_dst):
         """Jitted frontier traversal over the device ELL mirror + side table.
 
         Under a ShardPlan the mirror arrives row-sharded (and row-padded);
@@ -736,68 +621,23 @@ class IncrementalCore:
         hubs[: len(big)] = big
         plan = g.plan
         rep = jnp.asarray if plan is None else plan.replicate
-        visited, count = _region_fixpoint(
+        visited = _region_fixpoint(
             ell.neighbours, ell.degrees, rep(hubs), rep(core),
             rep(ends_mask), rep(ss), rep(sd),
-            rep(sv), lo, hi, cap,
+            rep(sv), lo, hi,
         )
-        if int(count) > cap:
-            return None
         return np.where(np.asarray(visited)[:n])[0].astype(np.int64)
 
     # ------------------------------------------------------------ repairs
 
-    def _repeel_shell(self, old: np.ndarray, hi: Optional[int]):
-        """Shell-incremental re-peel: recompute only levels ``<= hi``.
-
-        Upper shells are frozen and enter the peel as boundary degrees
-        (``core_numbers_shell_peel``); a ceiling hit disproves the freeze
-        and widens ``hi`` geometrically until certified (reaching the top
-        level degenerates to the full rounds peel — still exact, just no
-        longer incremental). Returns ``(cores, impl_tag, work)`` where
-        ``work`` is the arc/node mass actually peeled (the policy's re-peel
-        cost unit).
-        """
+    def _repeel(self, old: np.ndarray, m_ins: int, *,
+                host: bool = False) -> int:
+        """Exact re-peel of the whole graph: the fused descent seeded over
+        all nodes, or the snapshot peel for ``impl="ref"`` and ``host``."""
         n = self.g.n_nodes
-        src, dst = self.g.arc_arrays()
-        max_core = int(old.max(initial=0))
-        widen = max(self.margin0, 1)
-        hi = max_core if hi is None else int(hi)
-        deg = None
-        while hi < max_core:
-            if deg is None:
-                deg = np.bincount(src, minlength=n)
-            peel = old <= hi
-            inner = peel[src] & peel[dst]
-            core_s, ok = core_numbers_shell_peel(
-                n, src[inner], dst[inner], peel, deg, hi
-            )
-            if ok:
-                new = old.copy()
-                new[peel] = core_s[peel]
-                self.shell_repeels += 1
-                self._shell_depths.append((int(hi), int(peel.sum()), n))
-                metrics().counter("repair_shell_repeels_total").inc()
-                return new, "shell", int(inner.sum()) + int(peel.sum())
-            self.shell_widens += 1
-            hi += widen
-            widen *= 2
-        # the window reached the top level: nothing left to freeze
-        return core_numbers_rounds(n, src, dst), "rounds", len(src)
-
-    def _repeel(self, old: np.ndarray, m_ins: int,
-                hi: Optional[int] = None) -> int:
-        """Exact re-peel fallback: shell-incremental from the repair
-        window's top off-TPU (full rounds peel when the window covers every
-        level), fused descent over all nodes on TPU, the legacy snapshot
-        peel for ``impl="ref"``."""
-        n = self.g.n_nodes
-        mode = self._repeel_mode()
+        mode = "peel" if host or not self._device() else "descend"
         with obs.timed("repair.fallback") as sp:
-            work = 2 * self.g.n_edges
-            if mode == "shell":
-                oracle, mode, work = self._repeel_shell(old, hi)
-            elif mode == "descend":
+            if mode == "descend":
                 deg = self.g.degrees_of(np.arange(n))
                 seed = np.maximum(
                     np.minimum(deg.astype(np.int64),
@@ -833,15 +673,12 @@ class IncrementalCore:
                     # the dispatch may tier-reorder rows: scatter back by id
                     oracle = np.zeros(n, np.int32)
                     oracle[pending["cand"]] = res[0]
-            elif mode == "rounds":
-                src, dst = self.g.arc_arrays()
-                oracle = core_numbers_rounds(n, src, dst)
-                work = len(src)
             else:
                 oracle = core_numbers_host(self.g.snapshot())
             sp.set(impl=mode)
         self._tick("fallback", mode, sp.t0, sp.t1)
-        self.policy.observe("repeel", max(work, 1), sp.t1 - sp.t0)
+        self.policy.observe("repeel", max(2 * self.g.n_edges, 1),
+                            sp.t1 - sp.t0)
         changed = oracle != self._core[:n]
         self.promoted += int((oracle > self._core[:n]).sum())
         self.demoted += int((oracle < self._core[:n]).sum())
@@ -928,7 +765,8 @@ class IncrementalCore:
         # tier's rows are split across the mesh: each shard sweeps its own
         # rows and the frozen-boundary estimate stays replicated. A Pallas
         # kernel cannot be partitioned, so its descent runs on one device.
-        plan = g.plan if self._kernel_mode() in ("count", "ref") else None
+        impl = kops.h_index_impl()
+        plan = g.plan if impl in ("count", "ref") else None
         row = jnp.asarray if plan is None else plan.place_rows
         rep = jnp.asarray if plan is None else plan.replicate
         args = (
@@ -938,9 +776,10 @@ class IncrementalCore:
         )
         exe = self._compiled(
             _fused_descent, args,
-            impl=self._kernel_mode(), max_sweeps=self.max_sweeps,
+            impl=impl, max_sweeps=self.max_sweeps,
         )
-        return {"exe": exe, "args": args, "n_rows": n_rows, "cells": cells,
+        return {"exe": exe, "args": args, "impl": impl,
+                "n_rows": n_rows, "cells": cells,
                 "arcs": int(cand_deg.sum()), "tiers": len(tiers),
                 "cand": cand, "keep": keep}
 
@@ -969,7 +808,7 @@ class IncrementalCore:
         Returns ``(new, max_gain, max_loss, ceiling_hit, floor_hit)`` or
         None when the sweep cap truncated the descent.
         """
-        impl = f"fused[{self._kernel_mode()}]"
+        impl = f"fused[{pending['impl']}]"
         attrs = {k: pending[k] for k in ("cells", "arcs", "tiers")}
         attrs.update(impl=impl, rows=pending["n_rows"])
         if full_interval:
@@ -1022,8 +861,9 @@ class IncrementalCore:
                 return new
             est[cand] = new
 
-    def _finish_repeel(self, ctx: dict, hi: int) -> _RepairTicket:
-        changed = self._repeel(ctx["old"], ctx["m_ins"], hi=hi)
+    def _finish_repeel(self, ctx: dict, *,
+                       host: bool = False) -> _RepairTicket:
+        changed = self._repeel(ctx["old"], ctx["m_ins"], host=host)
         self.repairs += 1
         return _RepairTicket(changed=changed)
 
@@ -1044,20 +884,15 @@ class IncrementalCore:
                     raise
                 time.sleep(self.retry_backoff * (2 ** attempt))
 
-    def _recover_ref(self, ctx: dict, hi: int) -> _RepairTicket:
-        """Device repair kept failing: force the exact host ``"peel"`` path
+    def _recover_ref(self, ctx: dict) -> _RepairTicket:
+        """Device repair kept failing: force the exact host snapshot peel
         for this block so cores stay exact while the device path recovers.
 
         InjectedCrash is a BaseException and never lands here — a simulated
         process death must not be absorbed into a host fallback."""
         self.dispatch_recoveries += 1
         metrics().counter("repair_dispatch_recoveries_total").inc()
-        saved = self.repeel_impl
-        self.repeel_impl = "peel"
-        try:
-            return self._finish_repeel(ctx, hi)
-        finally:
-            self.repeel_impl = saved
+        return self._finish_repeel(ctx, host=True)
 
     def _commit(self, ctx: dict, cand, new) -> _RepairTicket:
         old = ctx["old"]
@@ -1067,11 +902,11 @@ class IncrementalCore:
         self.demoted += int((new < old[cand]).sum())
         return _RepairTicket(changed=int((new != old[cand]).sum()))
 
-    def _resolve(self, ctx: dict, margin: int, lo: int, hi: int, cand,
+    def _resolve(self, ctx: dict, margin: int, lo: int, cand,
                  res) -> _RepairTicket:
         """Validate one descent result against the window; commit or widen."""
         if res is None:  # sweep cap hit: recover via exact recompute
-            return self._finish_repeel(ctx, hi)
+            return self._finish_repeel(ctx)
         new, max_gain, max_loss, ceil_hit, floor_hit = res
         ceiling_hit = bool(ctx["m_ins"]) and ceil_hit
         floor_hit = bool(ctx["m_del"] and lo > 0) and floor_hit
@@ -1098,45 +933,26 @@ class IncrementalCore:
         widens. With ``pipeline=True`` a device fused descent is returned
         in-flight (live ticket) instead of read back here.
         """
-        m_ins, m_del, m = ctx["m_ins"], ctx["m_del"], ctx["m"]
+        m_ins, m_del = ctx["m_ins"], ctx["m_del"]
         old, n = ctx["old"], ctx["n"]
-        mode = self.policy.mode
-        adaptive = self._device() and mode == "adaptive"
-        # legacy static trigger caps discovery at repeel_frac * n; the
-        # adaptive policy never aborts on size — it decides *after* seeing
-        # the region, from measured cost, so eager-trigger full re-peels
-        # can't starve the fused descent. The ref impl (PR 2 oracle) keeps
-        # the legacy cap.
-        cap = n if adaptive else int(max(256, self.repeel_frac * n))
-        region_mode = self._region_mode()
+        device = self._device()
+        where = "jit" if device else "ref"
         lo = max(0, ctx["k_min"] - (margin if m_del else 0))
         hi = ctx["k_max"] + (margin if m_ins else 0)
-        if mode == "fallback":
-            return self._finish_repeel(ctx, hi)
 
-        with obs.timed("repair.region", impl=region_mode) as sp:
-            if region_mode == "ref":
+        with obs.timed("repair.region", impl=where) as sp:
+            if device:
+                cand = self._region_device(
+                    ctx["ends"], lo, hi, ctx["side_src"], ctx["side_dst"]
+                )
+            else:
                 cand = np.asarray(
                     self._region(ctx["ends"], lo, hi, ctx["removed"]), np.int64
                 )
-                if len(cand) > cap:
-                    cand = None
-            elif region_mode == "jit":
-                cand = self._region_device(
-                    ctx["ends"], lo, hi, ctx["side_src"], ctx["side_dst"], cap
-                )
-            else:
-                cand = self._region_np(
-                    ctx["ends"], lo, hi, ctx["side_src"], ctx["side_dst"], cap
-                )
-        self._tick("region", region_mode, sp.t0, sp.t1)
-        if cand is not None:
-            metrics().histogram(
-                "repair_region_nodes", buckets=_COUNT_BUCKETS
-            ).observe(len(cand))
-
-        if cand is None:  # legacy trigger fired (region/ref modes only)
-            return self._finish_repeel(ctx, hi)
+        self._tick("region", where, sp.t0, sp.t1)
+        metrics().histogram(
+            "repair_region_nodes", buckets=_COUNT_BUCKETS
+        ).observe(len(cand))
 
         with obs.timed("repair.candidates", impl="gather",
                        rows=len(cand)) as sp:
@@ -1145,43 +961,37 @@ class IncrementalCore:
                 cand_deg.astype(np.int64), old[cand].astype(np.int64) + m_ins
             )
             seed = np.maximum(seed, 0).astype(np.int32)
-            if self._device():
+            if device:
                 cells = self._tier_plan(cand_deg)[2]
                 sp.set(cells=cells)
         self._tick("candidates", "gather", sp.t0, sp.t1)
 
-        if self._device():
-            budget = self.descend_budget if not _on_tpu() else 1 << 62
-            if adaptive:
-                deg = self.g.degrees()
-                repeel_work = int(deg[old[:n] <= hi].sum()) + n
-                if self.policy.choose(
-                    cells=cells, repeel_work=repeel_work, budget=budget
-                ) == "repeel":
-                    return self._finish_repeel(ctx, hi)
-            elif cells > budget:
-                # legacy static trigger: a huge candidate matrix costs
-                # more to sweep than one exact vectorized re-peel
-                return self._finish_repeel(ctx, hi)
+        if device:
+            deg = self.g.degrees()
+            repeel_work = int(deg[old[:n] <= hi].sum()) + n
+            if self.policy.choose(
+                cells=cells, repeel_work=repeel_work
+            ) == "repeel":
+                return self._finish_repeel(ctx)
             staged = self._stage(
                 cand, seed, old[cand], lo, hi, cand_deg=cand_deg
             )
             try:
                 pending = self._dispatch_with_retry(staged)
             except Exception:
-                return self._recover_ref(ctx, hi)
+                return self._recover_ref(ctx)
             # the dispatch may tier-reorder the rows: resolve/commit against
             # the ordering the result actually maps to
             if pipeline:
                 return _RepairTicket(pending=pending, ctx=ctx,
-                                     margin=margin, lo=lo, hi=hi,
+                                     margin=margin, lo=lo,
                                      cand=pending["cand"])
             try:
                 res = self._descend_read(pending)
             except Exception:
                 metrics().counter("repair_dispatch_failures_total").inc()
-                return self._recover_ref(ctx, hi)
-            return self._resolve(ctx, margin, lo, hi, pending["cand"], res)
+                return self._recover_ref(ctx)
+            return self._resolve(ctx, margin, lo, pending["cand"], res)
 
         with obs.timed("repair.descend", impl="host", rows=len(cand),
                        arcs=int(cand_deg.sum()), tiers=1) as sp:
@@ -1196,7 +1006,7 @@ class IncrementalCore:
             floor_hit = bool(((new < lo) & (new < old[cand])).any())
         self._tick("descend", "host", sp.t0, sp.t1)
         return self._resolve(
-            ctx, margin, lo, hi, cand,
+            ctx, margin, lo, cand,
             (new, max_gain, max_loss, ceil_hit, floor_hit),
         )
 
@@ -1240,7 +1050,7 @@ class IncrementalCore:
             "ends": np.unique(touched.reshape(-1)),
             "k_min": int(k_edge.min()), "k_max": int(k_edge.max()),
         }
-        if self._region_mode() != "ref":
+        if self._device():
             # side table: removed block edges (both arcs) + overflow arcs the
             # table/mirror cannot carry — built once, reused across widenings
             ov_src, ov_dst = self.g.overflow_arc_arrays()
@@ -1278,9 +1088,9 @@ class IncrementalCore:
             # the in-flight device result is unreadable (device error
             # surfaced at the sync point): recover with the exact host peel
             metrics().counter("repair_dispatch_failures_total").inc()
-            return self._recover_ref(ticket.ctx, ticket.hi).changed
+            return self._recover_ref(ticket.ctx).changed
         return self._resolve(
-            ticket.ctx, ticket.margin, ticket.lo, ticket.hi, ticket.cand,
+            ticket.ctx, ticket.margin, ticket.lo, ticket.cand,
             res,
         ).changed
 

@@ -308,7 +308,6 @@ def capture_state(svc, wal_seq: int) -> Tuple[Dict[str, np.ndarray], dict]:
     arrays["core"] = svc.cores._core.copy()
     arrays["baseline"] = svc.cores._baseline.copy()
     cores = svc.cores
-    pol = cores.policy
     st = svc.stats
     manifest = {
         "wal_seq": int(wal_seq),
@@ -319,20 +318,11 @@ def capture_state(svc, wal_seq: int) -> Tuple[Dict[str, np.ndarray], dict]:
             "k0": None if svc.k0 is None else int(svc.k0),
             "retrain_threshold": svc.retrain_threshold,
             "impl": svc.impl,
-            "pipeline": bool(svc.pipeline),
         },
         "cores": {
-            "repeel_frac": cores.repeel_frac,
             "margin0": cores.margin0,
             "impl": cores.impl,
-            "region_impl": cores.region_impl,
-            "kernel_impl": cores.kernel_impl,
-            "repeel_impl": cores.repeel_impl,
-            "descend_budget": cores.descend_budget,
             "max_sweeps": cores.max_sweeps,
-            "repair_policy": pol.mode,
-            "crossover_margin": pol.crossover_margin,
-            "cold_cells_per_arc": pol.cold_cells_per_arc,
         },
         "stats": {k: int(getattr(st, k)) for k in _STATS_FIELDS},
     }
@@ -343,7 +333,11 @@ def restore_service(
     arrays: Dict[str, np.ndarray], manifest: dict, *, plan=None
 ):
     """Snapshot payload -> a fresh ``EmbeddingService``, bit-identical to
-    the one :func:`capture_state` saw."""
+    the one :func:`capture_state` saw.
+
+    Manifests written before the repair layer had one path also carry its
+    retired options (``repair_policy``, ``pipeline`` and the like); they are
+    ignored, and their ``impl="auto"`` is the device path."""
     from .kcore_inc import IncrementalCore
     from .service import EmbeddingService
     from .store import EmbeddingStore
@@ -357,17 +351,9 @@ def restore_service(
     cores = IncrementalCore(
         graph,
         np.asarray(arrays["core"], np.int32),
-        repeel_frac=ccfg["repeel_frac"],
         margin0=ccfg["margin0"],
-        impl=ccfg["impl"],
-        region_impl=ccfg["region_impl"],
-        kernel_impl=ccfg["kernel_impl"],
-        repeel_impl=ccfg["repeel_impl"],
-        descend_budget=ccfg["descend_budget"],
+        impl="ref" if ccfg["impl"] == "ref" else "device",
         max_sweeps=ccfg["max_sweeps"],
-        repair_policy=ccfg["repair_policy"],
-        crossover_margin=ccfg["crossover_margin"],
-        cold_cells_per_arc=ccfg["cold_cells_per_arc"],
     )
     cores._baseline = np.asarray(arrays["baseline"], np.int32).copy()
     scfg = manifest["service"]
@@ -379,7 +365,6 @@ def restore_service(
         k0=scfg["k0"],
         retrain_threshold=scfg["retrain_threshold"],
         impl=scfg["impl"],
-        pipeline=scfg["pipeline"],
     )
     for k, v in manifest.get("stats", {}).items():
         if hasattr(svc.stats, k):

@@ -18,10 +18,10 @@ Per flush:
    into a cascade as traffic touches successive shells.
 
 The service also owns ingestion policy: streamed edges arrive in **blocks**
-through ``ingest_block`` (``DynamicGraph.add_edges`` + one
-``IncrementalCore.on_edge_block`` repair for the whole block) and are
-retracted through ``retract_block`` (``remove_edges`` + ``on_remove``), with
-periodic double-buffered compaction. ``retrain_pressure`` (k0-core membership
+through ``ingest_block`` (``DynamicGraph.add_edges`` + one block repair,
+begun with ``IncrementalCore.begin_update`` and landed at the next sync
+point) and are retracted through ``retract_block`` (``remove_edges`` + the
+same repair), with periodic double-buffered compaction. ``retrain_pressure`` (k0-core membership
 drift since the last refresh — arrivals *and* deletion-driven departures)
 gates when retraining is actually needed, and ``maybe_retrain`` acts on it:
 with a :class:`~repro.serve.retrain.Retrainer` attached (``set_retrainer``),
@@ -111,7 +111,6 @@ class EmbeddingService:
         k0: Optional[int] = None,
         retrain_threshold: float = 0.1,
         impl: str = "auto",
-        pipeline: bool = True,
         hang_timeout: Optional[float] = None,
         flush_retries: int = 1,
         retry_backoff: float = 0.05,
@@ -128,9 +127,7 @@ class EmbeddingService:
         self.impl = impl
         # pipelined ingest: stage block N+1 (host dedup/canonicalise) while
         # block N's jitted descent dispatch is still in flight, then land the
-        # repair + deferred per-block tail at the next sync point. Results
-        # are bit-identical to the serial path (pipeline=False).
-        self.pipeline = bool(pipeline)
+        # repair + deferred per-block tail at the next sync point
         self._tail_due = False
         self.stats = ServiceStats()
         # retraining loop: a Retrainer (serve.retrain) attached via
@@ -293,12 +290,12 @@ class EmbeddingService:
     def _sync_ingest(self) -> None:
         """Land the in-flight repair and run the deferred per-block tail.
 
-        Pipelined ingest defers the post-repair tail (compaction check, auto
-        retrain) to the next sync point. Running it here — after the repair
-        landed and *before* any new mutation — keeps the graph state at tail
-        time identical to the serial path, which is what makes pipelining
-        bit-exact. The flag flips before the tail runs so a retrain-triggered
-        flush re-entering this method is a no-op.
+        Ingest defers the post-repair tail (compaction check, auto retrain)
+        to the next sync point. Running it here — after the repair landed
+        and *before* any new mutation — runs the tail on the graph state of
+        a synchronous repair (``IncrementalCore.on_update``). The flag flips
+        before the tail runs so a retrain-triggered flush re-entering this
+        method is a no-op.
         """
         self.cores.finish_update()
         if self._tail_due:
@@ -315,10 +312,10 @@ class EmbeddingService:
         """Stream an edge block: one staged insert + one block core repair.
 
         Returns the (m', 2) edges accepted (self-loops, duplicates, and
-        edges already present are dropped by the graph). With ``pipeline``
-        on, this block's canonicalisation overlaps the previous block's
-        in-flight descent dispatch, and the repair readback + per-block tail
-        are deferred to the next ingest/retract/flush/``sync()``.
+        edges already present are dropped by the graph). This block's
+        canonicalisation overlaps the previous block's in-flight descent
+        dispatch, and the repair readback + per-block tail are deferred to
+        the next ingest/retract/flush/``sync()``.
         """
         edges = self._validate_block(edges)
         t_slo = time.perf_counter() if self._slo is not None else 0.0
@@ -326,26 +323,17 @@ class EmbeddingService:
             if self._recovery is not None:  # durable *before* any mutation
                 self._recovery.log_block(KIND_INGEST, edges)
             faults.check("ingest_apply")
-            if self.pipeline:
-                # host-only staging overlaps block N-1's device dispatch
-                staged = self.graph.stage_block(edges)
-                self._sync_ingest()
-                accepted = self.graph.add_edges(staged, staged=True)
-                if len(accepted):
-                    self.cores.begin_update(added=accepted)
-                self._tail_due = True
-            else:
-                accepted = self.graph.add_edges(edges)
-                if len(accepted):
-                    self.cores.on_edge_block(accepted)
+            # host-only staging overlaps block N-1's device dispatch
+            staged = self.graph.stage_block(edges)
+            self._sync_ingest()
+            accepted = self.graph.add_edges(staged, staged=True)
+            if len(accepted):
+                self.cores.begin_update(added=accepted)
+            self._tail_due = True
             sp.set(accepted=len(accepted))
             self.stats.edges_ingested += len(accepted)
             self.stats.ingest_blocks += 1
             metrics().counter("serve_edges_ingested_total").inc(len(accepted))
-            if not self.pipeline:
-                self._maybe_compact()
-                if self.auto_retrain:
-                    self.maybe_retrain()
         if self._slo is not None and len(accepted):
             # pipelined blocks measure staging + the previous block's sync —
             # the rate traffic actually experiences at this boundary
@@ -369,24 +357,15 @@ class EmbeddingService:
             if self._recovery is not None:  # durable *before* any mutation
                 self._recovery.log_block(KIND_RETRACT, edges)
             faults.check("ingest_apply")
-            if self.pipeline:
-                staged = self.graph.stage_block(edges)
-                self._sync_ingest()
-                removed = self.graph.remove_edges(staged, staged=True)
-                if len(removed):
-                    self.cores.begin_update(removed=removed)
-                self._tail_due = True
-            else:
-                removed = self.graph.remove_edges(edges)
-                if len(removed):
-                    self.cores.on_remove(removed)
+            staged = self.graph.stage_block(edges)
+            self._sync_ingest()
+            removed = self.graph.remove_edges(staged, staged=True)
+            if len(removed):
+                self.cores.begin_update(removed=removed)
+            self._tail_due = True
             sp.set(removed=len(removed))
             self.stats.edges_removed += len(removed)
             metrics().counter("serve_edges_removed_total").inc(len(removed))
-            if not self.pipeline:
-                self._maybe_compact()
-                if self.auto_retrain:
-                    self.maybe_retrain()
         if self._recovery is not None:
             self._recovery.after_block()
         return len(removed)

@@ -45,3 +45,23 @@ def stream_case():
         return g, edges, dyn, inc
 
     return make
+
+
+@pytest.fixture(scope="session")
+def forced_policy():
+    """Factory of repair-policy test doubles: ``forced_policy(path)`` is an
+    ``IncrementalCore(policy=...)`` that sends every block repair down
+    ``path`` (``"descend"`` or ``"repeel"``), counting its decisions; ``None``
+    gives the measured policy itself."""
+    from repro.serve.kcore_inc import RepairPolicy
+
+    class Forced(RepairPolicy):
+        def __init__(self, path):
+            super().__init__()
+            self.path = path
+
+        def choose(self, *, cells, repeel_work):
+            self.decisions[self.path] += 1
+            return self.path
+
+    return lambda path: RepairPolicy() if path is None else Forced(path)

@@ -78,3 +78,16 @@ def test_h_index_bounds():
     assert np.all(got >= 0)
     assert np.all(got <= np.asarray(est))
     assert np.all(got <= np.asarray(valid).sum(axis=1))
+
+
+@pytest.mark.parametrize("tpu,want", [(True, "pallas"), (False, "count")])
+def test_auto_impl_is_the_backends_kernel(monkeypatch, tpu, want):
+    """``impl="auto"`` is resolved in one place for every caller: the
+    Pallas kernel on TPU, the counting search elsewhere."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: tpu)
+    assert ops.h_index_impl() == want
+    if not tpu:
+        vals, valid, est = _inputs(17, 130, seed=3)
+        np.testing.assert_array_equal(
+            ops.h_index_sweep(vals, valid, est, impl="auto"),
+            ops.h_index_sweep(vals, valid, est, impl="count"))

@@ -53,10 +53,11 @@ def test_mirror_parity_under_mixed_blocks_and_compaction(plan8):
     assert d8.compactions >= 2
 
 
-@pytest.mark.parametrize("region_impl", ["np", "jit"])
-def test_block_repair_parity_insert_delete_churn(plan8, region_impl):
-    """Sharded repair (host and jitted sharded region traversal) matches the
-    unsharded stack and the peeling oracle on the same churny stream."""
+@pytest.mark.parametrize("churn", [False, True], ids=["insert", "churn"])
+def test_block_repair_parity_insert_delete_churn(plan8, churn):
+    """Sharded repair (the jitted sharded region traversal and fused
+    descent) matches the unsharded stack and the peeling oracle, on an
+    insert-only stream and on the same stream with churny retractions."""
     g = generators.barabasi_albert_varying(130, 4.0, seed=5)
     edges = g.edge_list()
     rng = np.random.default_rng(6)
@@ -64,7 +65,7 @@ def test_block_repair_parity_insert_delete_churn(plan8, region_impl):
     d1 = DynamicGraph(g.n_nodes, width=3)
     d8 = DynamicGraph(g.n_nodes, width=3, plan=plan8)
     i1 = IncrementalCore(d1)
-    i8 = IncrementalCore(d8, region_impl=region_impl)
+    i8 = IncrementalCore(d8)
     live = []
     for step, start in enumerate(range(0, len(edges), 32)):
         block = edges[start : start + 32]
@@ -72,7 +73,7 @@ def test_block_repair_parity_insert_delete_churn(plan8, region_impl):
         i1.on_edge_block(a1)
         i8.on_edge_block(a8)
         live.extend(map(tuple, a1))
-        if step % 2 and len(live) > 8:
+        if churn and step % 2 and len(live) > 8:
             pick = rng.choice(len(live), size=6, replace=False)
             rm = np.array([live[i] for i in pick])
             i1.on_remove(d1.remove_edges(rm))
@@ -86,17 +87,17 @@ def test_block_repair_parity_insert_delete_churn(plan8, region_impl):
         np.testing.assert_array_equal(
             i8.core, core_numbers_host(d8.snapshot())
         )
-    assert i8.promoted > 0 and i8.demoted > 0
+    assert i8.promoted > 0 and (i8.demoted > 0) == churn
     assert i8.descends > 0  # the sharded fused descent actually ran
     assert i8.resync() == 0
 
 
-def test_sharded_fallback_repeel_stays_exact(plan8):
-    """A graph-sized block on the sharded stack trips the bounded re-peel
-    (rounds on host) and still lands on the oracle."""
+def test_sharded_fallback_repeel_stays_exact(plan8, forced_policy):
+    """A graph-sized block on the sharded stack sent to the re-peel (the
+    full-graph fused descent, row-sharded) still lands on the oracle."""
     g = generators.barabasi_albert_varying(400, 5.0, seed=7)
     d8 = DynamicGraph(g.n_nodes, width=4, plan=plan8)
-    i8 = IncrementalCore(d8, repeel_frac=0.05, repair_policy="region")
+    i8 = IncrementalCore(d8, policy=forced_policy("repeel"))
     i8.on_edge_block(d8.add_edges(g.edge_list()))
     assert i8.repeels >= 1
     np.testing.assert_array_equal(

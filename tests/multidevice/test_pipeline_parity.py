@@ -1,70 +1,86 @@
-"""Pipelined block ingest == serial ingest, bit-for-bit, at 1 and 8 shards.
+"""Pipelined block ingest == a synchronous ``impl="ref"`` oracle, at 1 and 8
+shards.
 
-``EmbeddingService(pipeline=True)`` stages block N+1's host dedup while
-block N's fused-descent dispatch is in flight and defers the per-block tail
-to the next sync point. That overlap must be pure scheduling: twin services
-driven with identical seeded streams (ingest blocks, churny retractions,
-interleaved queries — queries force a mid-stream settle) must expose exactly
-the same cores, embeddings, stats, and store state as ``pipeline=False``.
+``EmbeddingService`` stages block N+1's host dedup while block N's
+fused-descent dispatch is in flight and defers the per-block tail to the
+next sync point. That overlap must be pure scheduling: a synchronous
+``IncrementalCore(impl="ref")`` on its own copy of the graph, fed every block
+the service's repair begins (ingest blocks, churny retractions, interleaved
+queries that force a mid-stream settle), must hold exactly the service's
+cores at every step.
 """
 import numpy as np
 import pytest
 
+from repro.core.kcore import core_numbers_host
 from repro.graph import generators
 from repro.launch.serve_embed import build_service
+from repro.serve import DynamicGraph, IncrementalCore
 
 
-def _pair(shards, *, seed=21, n=300):
+def _with_ref_twin(shards, *, seed=21, n=300):
+    """A pipelined service, and a synchronous ``impl="ref"`` maintainer fed
+    every block that the service's repair begins."""
     g = generators.barabasi_albert_varying(n, 5.0, seed=seed)
-    kw = dict(seed=seed, batch=32, compact_every=128, shards=shards)
-    svc_p, stream_p, core_p, _ = build_service(g, pipeline=True, **kw)
-    svc_s, stream_s, core_s, _ = build_service(g, pipeline=False, **kw)
-    np.testing.assert_array_equal(stream_p, stream_s)
-    np.testing.assert_array_equal(core_p, core_s)
-    assert svc_p.pipeline and not svc_s.pipeline
-    return svc_p, svc_s, stream_p
+    svc, stream, core, _ = build_service(
+        g, seed=seed, batch=32, compact_every=128, shards=shards
+    )
+    twin = DynamicGraph(svc.graph.n_nodes, svc.graph.snapshot().edge_list(),
+                        width=16)
+    ref = IncrementalCore(twin, core, impl="ref")
+    begin = svc.cores.begin_update
+
+    def mirrored(added=None, removed=None):
+        if added is not None:
+            np.testing.assert_array_equal(twin.add_edges(added), added)
+            ref.on_edge_block(added)
+        if removed is not None:
+            np.testing.assert_array_equal(twin.remove_edges(removed), removed)
+            ref.on_remove(removed)
+        return begin(added=added, removed=removed)
+
+    svc.cores.begin_update = mirrored
+    return svc, ref, stream
+
+
+def _assert_same_cores(svc, ref):
+    np.testing.assert_array_equal(svc.cores.core, ref.core)
+    assert svc.cores.resync() == 0 and ref.resync() == 0
 
 
 @pytest.mark.parametrize("shards", [1, 8])
 def test_pipelined_ingest_matches_serial(plan8, shards):
-    svc_p, svc_s, stream = _pair(shards)
+    svc, ref, stream = _with_ref_twin(shards)
     rng_q = np.random.default_rng(22)
-    n_now = svc_p.graph.n_nodes
+    n_now = svc.graph.n_nodes
+    ingested = removed = 0
     for start in range(0, len(stream), 48):
         block = stream[start : start + 48]
-        a_p = svc_p.ingest_block(block)
-        a_s = svc_s.ingest_block(block)
-        np.testing.assert_array_equal(a_p, a_s)
+        ingested += len(svc.ingest_block(block))
         if (start // 48) % 2:
-            rm = block[: len(block) // 3]
-            assert svc_p.retract_block(rm) == svc_s.retract_block(rm)
+            removed += svc.retract_block(block[: len(block) // 3])
         if (start // 48) % 3 == 2:
             # queries settle the in-flight repair mid-stream
-            q = rng_q.integers(0, n_now, size=16)
-            np.testing.assert_array_equal(svc_p.embed(q), svc_s.embed(q))
-    svc_p.sync()
-    svc_s.sync()
-    np.testing.assert_array_equal(svc_p.cores.core, svc_s.cores.core)
-    assert svc_p.cores.resync() == 0 and svc_s.cores.resync() == 0
-    assert svc_p.stats.edges_ingested == svc_s.stats.edges_ingested
-    assert svc_p.stats.edges_removed == svc_s.stats.edges_removed
-    assert svc_p.stats.compactions == svc_s.stats.compactions
-    assert svc_p.stats.cold_starts == svc_s.stats.cold_starts
-    assert svc_p.store.evictions == svc_s.store.evictions
-    assert svc_p.store.version_counts() == svc_s.store.version_counts()
-    assert svc_p.store.staleness(svc_p.cores.core) == svc_s.store.staleness(
-        svc_s.cores.core
-    )
+            svc.embed(rng_q.integers(0, n_now, size=16))
+            assert svc.cores._inflight is None
+            np.testing.assert_array_equal(
+                svc.cores._core[: svc.graph.n_nodes], ref.core)
+    svc.sync()
+    _assert_same_cores(svc, ref)
+    np.testing.assert_array_equal(
+        svc.cores.core, core_numbers_host(svc.graph.snapshot()))
+    assert svc.stats.edges_ingested == ingested > 0
+    assert svc.stats.edges_removed == removed > 0
+    assert svc.stats.compactions > 0
 
 
 def test_pipelined_churn_replay_matches_serial(plan8):
-    """The benchmark's own churny driver, replayed on both modes at 8
-    shards, produces identical result dicts (counts, retrains, drift)."""
-    svc_p, svc_s, stream = _pair(8, seed=23)
-    r_p = svc_p.stream_with_churn(stream, block_size=64, churn=0.2,
-                                  rng=np.random.default_rng(24))
-    r_s = svc_s.stream_with_churn(stream, block_size=64, churn=0.2,
-                                  rng=np.random.default_rng(24))
-    assert r_p == r_s
-    np.testing.assert_array_equal(svc_p.cores.core, svc_s.cores.core)
-    assert svc_p.cores.resync() == 0 and svc_s.cores.resync() == 0
+    """The churny replay driver at 8 shards lands the oracle's cores, and
+    its counts are the service's."""
+    svc, ref, stream = _with_ref_twin(8, seed=23)
+    n_in, n_out = svc.stream_with_churn(stream, block_size=64, churn=0.2,
+                                        rng=np.random.default_rng(24))
+    assert (n_in, n_out) == (svc.stats.edges_ingested,
+                             svc.stats.edges_removed)
+    assert n_in > 0 and n_out > 0
+    _assert_same_cores(svc, ref)

@@ -100,10 +100,10 @@ def test_block_repair_and_deletion_match_peeling(g, block_size, seed):
 )
 @settings(max_examples=15, deadline=None)
 def test_device_region_matches_host_bfs(g, block_size, seed):
-    """Frontier-masked region growing (vectorized host + jitted device ELL
-    traversal with the removed-edge/overflow side table) returns exactly the
-    host BFS ``_region`` node set on random graphs under mixed insert/delete
-    blocks with compaction boundaries."""
+    """Frontier-masked region growing (the jitted device ELL traversal with
+    the removed-edge/overflow side table) returns exactly the host BFS
+    ``_region`` node set on random graphs under mixed insert/delete blocks
+    with compaction boundaries."""
     rng = np.random.default_rng(seed)
     edges = g.edge_list()
     edges = edges[rng.permutation(len(edges))]
@@ -138,11 +138,8 @@ def test_device_region_matches_host_bfs(g, block_size, seed):
         ov_src, ov_dst = dyn.overflow_arc_arrays()
         side_src = np.concatenate([ov_src, removed[:, 0], removed[:, 1]])
         side_dst = np.concatenate([ov_dst, removed[:, 1], removed[:, 0]])
-        cap = 1 << 30  # unbounded: compare complete regions
-        got_np = inc._region_np(ends, lo, hi, side_src, side_dst, cap)
-        got_dev = inc._region_device(ends, lo, hi, side_src, side_dst, cap)
-        np.testing.assert_array_equal(got_np, want)
-        np.testing.assert_array_equal(got_dev, want)
+        got = inc._region_device(ends, lo, hi, side_src, side_dst)
+        np.testing.assert_array_equal(got, want)
 
 
 @given(
@@ -206,21 +203,21 @@ def test_sharded_repair_and_store_are_shard_count_invariant(
 
 @given(
     graphs(max_nodes=35),
-    st.sampled_from(["adaptive", "region", "fallback"]),
+    st.sampled_from([None, "descend", "repeel"]),
     st.integers(1, 40),  # insert block size
     st.integers(0, 2**31 - 1),
 )
 @settings(max_examples=15, deadline=None)
-def test_repair_policy_is_cost_only(g, mode, block_size, seed):
+def test_repair_policy_is_cost_only(forced_policy, g, path, block_size, seed):
     """The repair policy decides *which* exact path runs, never the result:
-    any mode (measured crossover, legacy static trigger, always-fallback)
-    matches the peeling oracle on random mixed insert/delete streams — and
-    the pipelined begin/finish split matches the synchronous entry point."""
+    the measured crossover, always-descend and always-re-peel each match the
+    peeling oracle on random mixed insert/delete streams — and the pipelined
+    begin/finish split matches the synchronous entry point."""
     rng = np.random.default_rng(seed)
     edges = g.edge_list()
     edges = edges[rng.permutation(len(edges))]
     dyn = DynamicGraph(g.n_nodes, width=2)  # tiny width: overflow side arcs
-    inc = IncrementalCore(dyn, repair_policy=mode)
+    inc = IncrementalCore(dyn, policy=forced_policy(path))
     live: list = []
     step = 0
     for start in range(0, len(edges), block_size):

@@ -329,7 +329,7 @@ def test_chrome_export_nests_under_pipelined_ingest(default_tracer, tmp_path):
 
     g = generators.barabasi_albert_varying(240, 4.0, seed=5)
     svc, stream, _, _ = build_service(
-        g, pipeline=True, seed=5, batch=32, compact_every=64)
+        g, seed=5, batch=32, compact_every=64)
     rng = np.random.default_rng(7)
     for start in range(0, len(stream), 48):
         svc.ingest_block(stream[start:start + 48])

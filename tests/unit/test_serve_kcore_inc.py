@@ -143,12 +143,11 @@ def test_isolating_deletion_drops_to_zero():
 
 
 @pytest.mark.slow
-def test_repeel_fallback_is_exact_and_counted(stream_case):
-    """A graph-sized block trips the bounded re-peel fallback, exactly."""
+def test_repeel_fallback_is_exact_and_counted(stream_case, forced_policy):
+    """A graph-sized block sent to the re-peel lands exact, and counted."""
     _, edges, dyn, inc = stream_case(
         lambda: generators.barabasi_albert_varying(400, 5.0, seed=24),
-        shuffle=False, repeel_frac=0.05,  # tiny bound: force fallback
-        repair_policy="region",  # legacy static trigger (adaptive would descend)
+        shuffle=False, policy=forced_policy("repeel"),
     )
     accepted = dyn.add_edges(edges)
     inc.on_edge_block(accepted)
@@ -212,13 +211,14 @@ def test_fused_descent_matches_host_descent_on_blocks(stream_case):
     assert ref.resync() == 0 and dev.resync() == 0
 
 
-def test_kernel_backed_descent_stays_exact(stream_case):
+def test_kernel_backed_descent_stays_exact(stream_case, monkeypatch):
     """End-to-end adoption check: the fused descent driven through the
     Pallas kernel (interpret mode) still matches the peeling oracle."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "h_index_impl", lambda: "pallas_interpret")
     _, edges, dyn, inc = stream_case(
-        lambda: generators.barabasi_albert(60, 3, seed=33),
-        shuffle=False, impl="device", kernel_impl="pallas_interpret",
-        region_impl="jit",
+        lambda: generators.barabasi_albert(60, 3, seed=33), shuffle=False,
     )
     for start in range(0, len(edges), 40):
         accepted = dyn.add_edges(edges[start : start + 40])
@@ -226,18 +226,32 @@ def test_kernel_backed_descent_stays_exact(stream_case):
     oracle = core_numbers_host(dyn.snapshot())
     np.testing.assert_array_equal(inc.core, oracle)
     assert inc.descends > 0
+    report = inc.phase_report()
+    assert report["descend"]["impl"] == "fused[pallas_interpret]"
+    assert report["region"]["impl"] == "jit"
+
+
+def _failing_launch(staged):
+    raise RuntimeError("device dispatch failed")
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("repeel_impl", ["rounds", "descend", "shell"])
-def test_repeel_fallback_impls_are_exact(stream_case, repeel_impl):
-    """Both device-path fallbacks (vectorized rounds peel, full-graph fused
-    descent) recompute the exact core numbers, insertions and deletions."""
+@pytest.mark.parametrize("repeel_impl", ["rounds", "descend", "peel"])
+def test_repeel_fallback_impls_are_exact(stream_case, forced_policy,
+                                         repeel_impl):
+    """Every re-peel recomputes the exact core numbers, insertions and
+    deletions: the full-graph fused descent, the rounds peel that recovers
+    when the sweep cap truncates it, and the host snapshot peel that
+    repairs a block whose device dispatch keeps failing."""
     _, edges, dyn, inc = stream_case(
         lambda: generators.barabasi_albert_varying(300, 5.0, seed=34),
-        shuffle=False, repeel_frac=0.05, repeel_impl=repeel_impl,
-        repair_policy="region",  # legacy static trigger (adaptive would descend)
+        shuffle=False,
+        policy=forced_policy("descend" if repeel_impl == "peel" else "repeel"),
+        max_sweeps=2 if repeel_impl == "rounds" else 512,
     )
+    if repeel_impl == "peel":
+        inc.retry_backoff = 0.0
+        inc._launch = _failing_launch
     inc.on_edge_block(dyn.add_edges(edges))
     assert inc.repeels >= 1
     np.testing.assert_array_equal(inc.core, core_numbers_host(dyn.snapshot()))
@@ -245,21 +259,24 @@ def test_repeel_fallback_impls_are_exact(stream_case, repeel_impl):
     rm = dyn.remove_edges(edges[rng.permutation(len(edges))[: len(edges) // 2]])
     inc.on_remove(rm)
     np.testing.assert_array_equal(inc.core, core_numbers_host(dyn.snapshot()))
+    assert inc.phase_report()["fallback"]["impl"] == repeel_impl
+    assert (inc.dispatch_recoveries > 0) == (repeel_impl == "peel")
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("repeel_impl", [None, "descend"])
-def test_truncated_descent_falls_back_to_exact(repeel_impl):
+@pytest.mark.parametrize("path", [None, "repeel"])
+def test_truncated_descent_falls_back_to_exact(forced_policy, path):
     """A sweep cap below the cascade depth must never commit non-converged
     estimates: the repair detects the truncation and recovers through an
-    uncapped exact recompute (even when the fallback itself is the capped
-    full-graph descent)."""
+    uncapped exact recompute (the re-peel is itself the capped full-graph
+    descent, so it recovers through the rounds peel)."""
     edges = np.array([[i, i + 1] for i in range(59)], np.int64)  # deep chain
     dyn = DynamicGraph(60, width=4)
-    inc = IncrementalCore(dyn, max_sweeps=5, repeel_impl=repeel_impl)
+    inc = IncrementalCore(dyn, max_sweeps=5, policy=forced_policy(path))
     inc.on_edge_block(dyn.add_edges(edges))
     np.testing.assert_array_equal(inc.core, core_numbers_host(dyn.snapshot()))
     assert inc.repeels >= 1  # the truncation was detected, not ignored
+    assert inc.phase_report()["fallback"]["impl"] == "rounds"
 
 
 def test_phase_report_tracks_repair_phases():
@@ -295,11 +312,10 @@ def test_drift_and_membership_gate():
     np.testing.assert_array_equal(inc.core, oracle)
 
 
-@pytest.mark.parametrize("kw", [
-    {},
-    {"repair_policy": "fallback", "repeel_impl": "descend"},
-], ids=["adaptive", "repeel-by-descent"])
-def test_repair_spans_are_the_phase_intervals(stream_case, kw):
+@pytest.mark.parametrize("path", [None, "repeel"],
+                         ids=["adaptive", "repeel-by-descent"])
+def test_repair_spans_are_the_phase_intervals(stream_case, forced_policy,
+                                              path):
     """phase_report(), repair_phase_seconds, the repair policy and the trace
     are fed by one interval per phase occurrence: that of its span."""
     from repro.obs import MetricsRegistry, metrics, set_metrics
@@ -311,7 +327,7 @@ def test_repair_spans_are_the_phase_intervals(stream_case, kw):
     try:
         g, edges, dyn, inc = stream_case(
             lambda: generators.barabasi_albert(120, 3, seed=5), seed=2,
-            **kw)
+            policy=forced_policy(path))
         seen = []
         observe = inc.policy.observe
         inc.policy.observe = lambda path, work, s: (
@@ -347,12 +363,14 @@ def test_repair_spans_are_the_phase_intervals(stream_case, kw):
         [e["dur"] for e in phases if e["name"] == "repair.descend"])
     assert [s for p, s in seen if p == "repeel"] == pytest.approx(
         [e["dur"] for e in phases if e["name"] == "repair.fallback"])
-    if kw:  # a re-peel's own gather and descent nest in its span
-        assert report.keys() == {"fallback"}
-        nested = [e for e in phases if e["name"] != "repair.fallback"]
+    if path:  # a re-peel's own gather and descent nest in its span
+        assert report.keys() == {"region", "candidates", "fallback"}
+        nested = [e for e in phases if in_repeel(e)]
         assert nested and all(
             by_id[e["parent"]]["name"] == "repair.fallback" for e in nested)
         assert all({"rows", "cells"} <= e["attrs"].keys() for e in nested)
+        assert all(in_repeel(e) for e in phases
+                   if e["name"] == "repair.descend")
 
 
 # ------------------------------------------------- degree-tiered descent
@@ -433,11 +451,10 @@ def _hub_graph(seed):
     return n, e[rng.permutation(len(e))]
 
 
-@pytest.mark.parametrize("kw", [
-    {"repair_policy": "region", "repeel_frac": 1.0},
-    {"repair_policy": "fallback", "repeel_impl": "descend"},
-], ids=["region-descent", "repeel-by-descent"])
-def test_tiered_descent_matches_ref_and_oracle_on_hub_stream(kw):
+@pytest.mark.parametrize("path", ["descend", "repeel"],
+                         ids=["region-descent", "repeel-by-descent"])
+def test_tiered_descent_matches_ref_and_oracle_on_hub_stream(forced_policy,
+                                                             path):
     """Insert and retract blocks on a hub-heavy graph: the ladder's fused
     descent commits the host descent's and the peeling oracle's cores,
     including while candidate rows hold overflow arcs."""
@@ -447,11 +464,11 @@ def test_tiered_descent_matches_ref_and_oracle_on_hub_stream(kw):
     base, stream = edges[: len(edges) * 3 // 4], edges[len(edges) * 3 // 4:]
     dyn_ref = DynamicGraph(n, base, width=64)
     dyn_dev = DynamicGraph(n, base, width=64)
-    ref = IncrementalCore(dyn_ref, impl="ref", repeel_frac=1.0)
+    ref = IncrementalCore(dyn_ref, impl="ref")
     prev = obs.tracer()
     t = obs.enable(exemplar_watch=())
     try:
-        dev = IncrementalCore(dyn_dev, impl="device", **kw)
+        dev = IncrementalCore(dyn_dev, policy=forced_policy(path))
         rng = np.random.default_rng(42)
         overflowed = False
         for step, block in enumerate(np.array_split(stream, 3)):

@@ -385,6 +385,40 @@ def test_crash_recover_resume_matches_uninterrupted_twin(tmp_path):
     mgr2.close()
 
 
+def test_restore_ignores_retired_repair_options():
+    """A manifest written while the repair layer still had options carries
+    them (and ``impl="auto"``): the restored service ignores them, runs the
+    one device path, and replays the rest of the stream to the uninterrupted
+    run's state, its cores oracle-exact."""
+    from repro.core.kcore import core_numbers_host
+    from repro.serve.recovery import restore_service
+
+    svc0, stream = _svc(n=250, seed=4)
+    ops = _ops_from(stream)
+    _apply(svc0, ops)
+    truth = _arrays(svc0)
+
+    svc, _ = _svc(n=250, seed=4)
+    half = len(ops) // 2
+    _apply(svc, ops[:half])
+    arrays, manifest = capture_state(svc, half)
+    manifest["service"]["pipeline"] = True
+    manifest["cores"].update(
+        impl="auto", repeel_frac=0.6, region_impl=None, kernel_impl=None,
+        repeel_impl=None, descend_budget=1 << 20, repair_policy="adaptive",
+        crossover_margin=1.0, cold_cells_per_arc=32.0,
+    )
+    svc2 = restore_service(arrays, manifest)
+    assert svc2.cores.impl == "device"
+    _apply(svc2, ops, start=half)
+    got = _arrays(svc2)
+    bad = [k for k in truth
+           if k not in got or not np.array_equal(truth[k], got[k])]
+    assert bad == [], f"state diverged after restore: {bad}"
+    np.testing.assert_array_equal(
+        svc2.cores.core, core_numbers_host(svc2.graph.snapshot()))
+
+
 def test_recover_requires_a_committed_snapshot(tmp_path):
     with pytest.raises(FileNotFoundError):
         RecoveryManager.recover(str(tmp_path))
@@ -447,12 +481,14 @@ def test_flush_lowering_error_raises_instead_of_degrading():
     assert not svc.degraded and svc.stats.degraded_queries == 0
 
 
-def test_descent_lowering_error_raises_instead_of_host_repeel():
+def test_descent_lowering_error_raises_instead_of_host_repeel(monkeypatch):
     """The fused descent compiles ahead of its retry wrapper: a lowering
     error propagates, and is never absorbed as a failed dispatch that the
     exact host peel then papers over."""
+    from repro.kernels import ops
+
     svc, stream = _svc()
-    svc.cores.kernel_impl = "pallas"
+    monkeypatch.setattr(ops, "h_index_impl", lambda: "pallas")
     with pytest.raises(ValueError, match="interpret mode"):
         svc.ingest_block(stream[:64])
         svc.sync()

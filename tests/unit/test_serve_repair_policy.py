@@ -1,4 +1,4 @@
-"""Adaptive repair policy + shell-incremental re-peel (exactness + decisions).
+"""Adaptive repair policy (exactness + decisions).
 
 The policy only ever picks *which* exact repair path runs, so every test
 here asserts two things: the decision machinery behaves (cold start, EMA
@@ -8,11 +8,7 @@ numbers never deviate from the Matula–Beck oracle no matter what it picks.
 import numpy as np
 import pytest
 
-from repro.core.kcore import (
-    core_numbers_host,
-    core_numbers_rounds,
-    core_numbers_shell_peel,
-)
+from repro.core.kcore import core_numbers_host
 from repro.graph import generators
 from repro.obs.metrics import MetricsRegistry
 from repro.serve import DynamicGraph, IncrementalCore
@@ -22,22 +18,17 @@ from repro.serve.kcore_inc import RepairPolicy
 # ------------------------------------------------------------ RepairPolicy
 
 
-def test_unknown_mode_rejected():
-    with pytest.raises(ValueError, match="unknown repair policy"):
-        RepairPolicy("always-descend")
-
-
 def test_cold_start_heuristic_shapes_first_decision():
     p = RepairPolicy()
     # modest matrix vs real arc mass: descend, counted as a cold decision
-    assert p.choose(cells=4096, repeel_work=4096, budget=1 << 20) == "descend"
+    assert p.choose(cells=4096, repeel_work=4096) == "descend"
     assert p.cold_decisions == 1
     # padded matrix dwarfs the shell arc mass: don't burn time measuring it
-    assert p.choose(cells=1 << 24, repeel_work=128, budget=1 << 30) == "repeel"
-    # over the hard cold budget: repeel regardless of the ratio
-    assert p.choose(cells=1 << 22, repeel_work=1 << 20, budget=1 << 10) \
-        == "repeel"
-    assert p.decisions == {"descend": 1, "repeel": 2}
+    assert p.choose(cells=1 << 24, repeel_work=128) == "repeel"
+    # the arc mass is floored at 64: 32 cells per arc of that still descend
+    assert p.choose(cells=32 * 64, repeel_work=1) == "descend"
+    assert p.choose(cells=32 * 64 + 1, repeel_work=1) == "repeel"
+    assert p.decisions == {"descend": 2, "repeel": 2}
 
 
 def test_ema_observe_predict_and_regime_extrapolation():
@@ -57,10 +48,10 @@ def test_ema_observe_predict_and_regime_extrapolation():
 def test_unmeasured_repeel_is_explored_once():
     p = RepairPolicy()
     p.observe("descend", 4096, 0.001)  # descend measured, repeel never
-    assert p.choose(cells=4096, repeel_work=4096, budget=1 << 20) == "repeel"
+    assert p.choose(cells=4096, repeel_work=4096) == "repeel"
     p.observe("repeel", 4096, 0.010)
     # both measured now: the crossover picks the cheap path
-    assert p.choose(cells=4096, repeel_work=4096, budget=1 << 20) == "descend"
+    assert p.choose(cells=4096, repeel_work=4096) == "descend"
     assert p.cold_decisions == 0
 
 
@@ -69,7 +60,7 @@ def test_stale_loser_is_probed():
     p.observe("descend", 4096, 0.001)
     p.observe("repeel", 4096, 0.100)  # repeel loses the crossover hard
     choices = [
-        p.choose(cells=4096, repeel_work=4096, budget=1 << 20)
+        p.choose(cells=4096, repeel_work=4096)
         for _ in range(5)
     ]
     # the loser goes unmeasured for probe_every decisions, then gets probed
@@ -78,7 +69,7 @@ def test_stale_loser_is_probed():
     assert p.probes == 1
     # measuring the probed path resets its staleness: back to the winner
     p.observe("repeel", 4096, 0.100)
-    assert p.choose(cells=4096, repeel_work=4096, budget=1 << 20) == "descend"
+    assert p.choose(cells=4096, repeel_work=4096) == "descend"
 
 
 def test_registry_prior_warm_starts_predictions():
@@ -100,91 +91,59 @@ def test_report_counts_probes_and_decisions():
     p.observe("descend", 1024, 0.001)
     p.observe("repeel", 1024, 0.1)
     for _ in range(6):
-        p.choose(cells=1024, repeel_work=1024, budget=1 << 20)
+        p.choose(cells=1024, repeel_work=1024)
     rep = p.report()
-    assert rep["mode"] == "adaptive"
     assert rep["probes"] >= 1
     assert sum(rep["decisions"].values()) == 6
     assert rep["regimes"]  # learned EMA cells are exported
 
 
-# ------------------------------------------------- shell-incremental peel
+# ------------------------------------------------------ forced paths
 
 
-def _arc_arrays(g):
-    e = g.edge_list()
-    return (
-        np.concatenate([e[:, 0], e[:, 1]]),
-        np.concatenate([e[:, 1], e[:, 0]]),
-    )
+def test_unknown_impl_rejected():
+    """The maintainer has two implementations, the device path and its
+    host oracle; anything else is refused, not silently mapped."""
+    for impl in ("auto", "pallas", ""):
+        with pytest.raises(ValueError, match="unknown impl"):
+            IncrementalCore(DynamicGraph(3), impl=impl)
 
 
-def test_shell_peel_exact_against_frozen_upper_shells():
-    g = generators.barabasi_albert_varying(300, 5.0, seed=40)
-    src, dst = _arc_arrays(g)
-    oracle = core_numbers_rounds(g.n_nodes, src, dst)
-    deg = np.bincount(src, minlength=g.n_nodes)
-    for hi in (1, int(np.median(oracle)), int(oracle.max()) - 1):
-        peel = oracle <= hi
-        inner = peel[src] & peel[dst]
-        core, ok = core_numbers_shell_peel(
-            g.n_nodes, src[inner], dst[inner], peel, deg, hi
-        )
-        assert ok
-        np.testing.assert_array_equal(core[peel], oracle[peel])
-
-
-def test_shell_peel_detects_ceiling_violation():
-    g = generators.barabasi_albert_varying(200, 5.0, seed=41)
-    src, dst = _arc_arrays(g)
-    oracle = core_numbers_rounds(g.n_nodes, src, dst)
-    assert oracle.max() > 1
-    # lie: claim every node sits at level <= 1 and peel the whole graph.
-    # Survivors need k > hi, so the freeze must be disproved, not trusted.
-    peel = np.ones(g.n_nodes, bool)
-    deg = np.bincount(src, minlength=g.n_nodes)
-    _, ok = core_numbers_shell_peel(g.n_nodes, src, dst, peel, deg, hi=1)
-    assert not ok
-
-
-def test_fallback_policy_stays_shell_incremental_and_exact():
-    """repair_policy="fallback" re-peels every block through the shell path;
-    mixed inserts/deletes down to an empty graph (shell 0) stay oracle-exact."""
+def test_fallback_policy_stays_shell_incremental_and_exact(forced_policy):
+    """A policy that always re-peels: mixed inserts/deletes down to an empty
+    graph (shell 0) stay oracle-exact, each block one re-peel."""
     g = generators.barabasi_albert_varying(250, 4.0, seed=42)
     edges = g.edge_list()
     rng = np.random.default_rng(43)
     edges = edges[rng.permutation(len(edges))]
     dyn = DynamicGraph(g.n_nodes, width=4)
-    inc = IncrementalCore(dyn, repair_policy="fallback")
+    inc = IncrementalCore(dyn, policy=forced_policy("repeel"))
     for start in range(0, len(edges), 48):
         inc.on_edge_block(dyn.add_edges(edges[start : start + 48]))
         np.testing.assert_array_equal(
             inc.core, core_numbers_host(dyn.snapshot())
         )
-    assert inc.repeels > 0 and inc.descends == 0
-    # drain the graph: deletion blocks drive every node to shell 0. With no
-    # insertions levels only fall, so the peel window always certifies —
-    # this leg is where the fallback stays genuinely shell-incremental
-    # (insert blocks can push hi past the top level, degenerating to the
-    # full rounds peel).
+    # drain the graph: deletion blocks drive every node to shell 0
     while dyn.n_edges:
         live = dyn.snapshot().edge_list()
         inc.on_remove(dyn.remove_edges(live[:64]))
         np.testing.assert_array_equal(
             inc.core, core_numbers_host(dyn.snapshot())
         )
-    assert inc.shell_repeels > 0  # the fallback stayed incremental
+    assert inc.repeels == inc.repairs == inc.policy.decisions["repeel"] > 0
+    assert inc.phase_report()["fallback"]["impl"] == "descend"
     assert not inc.core.any()  # everyone drifted to shell 0
     assert inc.resync() == 0
 
 
-def test_shell_peel_widens_on_ceiling_hit():
-    """A block that vaults low-shell nodes past the frozen ceiling must be
-    caught (ok=False inside), widened geometrically, and still land exact."""
+def test_shell_peel_widens_on_ceiling_hit(forced_policy):
+    """A clique block on the periphery vaults its nodes past the repair
+    window's ceiling: the descent through the device path must catch it,
+    re-run in a wider window, and still land exact."""
     g = generators.barabasi_albert_varying(300, 5.0, seed=44)
     dyn = DynamicGraph(g.n_nodes, width=16)
-    # margin0=1: the peel window hugs the block's levels, so a big jump hits
-    inc = IncrementalCore(dyn, repair_policy="fallback", margin0=1)
+    # margin0=1: the window hugs the block's levels, so a big jump hits
+    inc = IncrementalCore(dyn, policy=forced_policy("descend"), margin0=1)
     inc.on_edge_block(dyn.add_edges(g.edge_list()))
     base = inc.core.copy()
     assert base.max() >= 6  # enough frozen levels above the periphery
@@ -195,9 +154,10 @@ def test_shell_peel_widens_on_ceiling_hit():
         [[low[i], low[j]] for i in range(8) for j in range(i + 1, 8)],
         np.int64,
     )
-    widens0 = inc.shell_widens
+    attempts0 = inc.policy.decisions["descend"]
     inc.on_edge_block(dyn.add_edges(block))
-    assert inc.shell_widens > widens0
+    # one policy decision per window attempt: the window widened
+    assert inc.policy.decisions["descend"] - attempts0 >= 2
     np.testing.assert_array_equal(inc.core, core_numbers_host(dyn.snapshot()))
     assert inc.resync() == 0
 
@@ -205,8 +165,8 @@ def test_shell_peel_widens_on_ceiling_hit():
 # ------------------------------------------------------ adaptive == exact
 
 
-def test_adaptive_policy_never_changes_results():
-    """Three maintainers (adaptive / legacy region trigger / always-fallback)
+def test_adaptive_policy_never_changes_results(forced_policy):
+    """Three maintainers (measured policy / always descend / always re-peel)
     driven with the same mixed stream agree with each other and the oracle at
     every step — the policy is cost-only."""
     g = generators.barabasi_albert_varying(180, 4.0, seed=45)
@@ -214,11 +174,11 @@ def test_adaptive_policy_never_changes_results():
     rng = np.random.default_rng(46)
     edges = edges[rng.permutation(len(edges))]
     stacks = [
-        (DynamicGraph(g.n_nodes, width=4), mode)
-        for mode in ("adaptive", "region", "fallback")
+        (DynamicGraph(g.n_nodes, width=4), path)
+        for path in (None, "descend", "repeel")
     ]
     incs = [
-        IncrementalCore(d, repair_policy=mode) for d, mode in stacks
+        IncrementalCore(d, policy=forced_policy(path)) for d, path in stacks
     ]
     live: list = []
     for step, start in enumerate(range(0, len(edges), 40)):
